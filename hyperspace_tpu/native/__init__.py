@@ -1,8 +1,9 @@
 """ctypes bindings for the native host kernels (native/hs_native.cpp).
 
-Loads a prebuilt libhs_native.so next to this package, or builds it once
-with the system compiler on first use; every entry point has a numpy
-fallback so the framework works without a toolchain. Hash outputs are
+Builds libhs_native-<hash>.so next to this package once with the system
+compiler, keyed on a hash of the committed source and the build flags, so
+a library left on disk from other sources is never loaded; every entry
+point has a numpy fallback so the framework works without a toolchain. Hash outputs are
 bit-identical to ops/hashing.py (covered by a parity test) — bucket layout
 is an on-disk contract.
 """
@@ -10,6 +11,7 @@ is an on-disk contract.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -20,7 +22,6 @@ from ..staticcheck.concurrency import TrackedLock
 
 logger = logging.getLogger(__name__)
 
-_LIB_NAME = "libhs_native.so"
 _ABI_VERSION = 4
 
 # named so the one-time compile/load critical section participates in the
@@ -36,14 +37,26 @@ def _source_path() -> str:
     return os.path.join(repo_root, "native", "hs_native.cpp")
 
 
-def _lib_path() -> str:
-    return os.path.join(os.path.dirname(os.path.abspath(__file__)), _LIB_NAME)
-
-
 # the exact flags the .so was (or would be) built with — bench artifacts
 # record these so host-tier numbers are reproducible
 BUILD_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC"]
 COMPILER = "g++"
+
+
+def _lib_path() -> str:
+    """The library built from the current source and flags: its name
+    carries their hash, so an edit to either builds a new file."""
+    h = hashlib.sha256()
+    try:
+        with open(_source_path(), "rb") as f:
+            h.update(f.read())
+    except OSError:
+        pass  # hslint: HS402 — no source: the name matches no build, and _build() reports it
+    h.update(" ".join([COMPILER, *BUILD_FLAGS]).encode())
+    return os.path.join(
+        os.path.dirname(os.path.abspath(__file__)),
+        f"libhs_native-{h.hexdigest()[:16]}.so",
+    )
 
 
 def build_facts() -> dict:
@@ -68,12 +81,20 @@ def _build() -> bool:
     if not os.path.exists(src):
         return False
     out = _lib_path()
-    cmd = [COMPILER, *BUILD_FLAGS, src, "-o", out]
+    # concurrent processes (test workers) may build at once: each writes
+    # its own file and renames it into place atomically
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = [COMPILER, *BUILD_FLAGS, src, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, out)
         return True
     except Exception as e:  # missing compiler, sandbox, ... -> numpy fallback
         logger.info("native build skipped (%s); using numpy fallbacks", e)
+        try:
+            os.unlink(tmp)
+        except OSError:
+            pass  # hslint: HS402 — nothing was written
         return False
 
 
@@ -89,7 +110,7 @@ def _load() -> ctypes.CDLL | None:
         try:
             lib = ctypes.CDLL(path)
             if lib.hs_native_abi_version() != _ABI_VERSION:
-                logger.warning("stale %s (ABI mismatch); rebuilding", _LIB_NAME)
+                logger.warning("stale %s (ABI mismatch); rebuilding", path)
                 os.unlink(path)
                 if not _build():
                     return None

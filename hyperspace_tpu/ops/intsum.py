@@ -1,4 +1,4 @@
-"""Exact integer summation on a 32-bit device.
+"""Exact integer summation on a 32-bit device, and blocked float sums.
 
 The device disables x64, so a naive int sum accumulates in int32 (wraps) or
 f32 (rounds past 2^24). Instead v decomposes as
@@ -32,6 +32,32 @@ def int_chunk_sums(v, seg=None, num_segments: int = 0):
     return tuple(
         jax.ops.segment_sum(c, seg, num_segments=num_segments) for c in chunks
     )
+
+
+# most rows x groups the partials of blocked_segment_sum may hold (4 MB f32)
+_BLOCK_PARTIALS = 1 << 20
+
+
+def blocked_segment_sum(v, seg, num_segments: int):
+    """Per-segment float sums accumulated in up to 1024 row blocks whose
+    partials then reduce as a tree. One running segment sum of millions of
+    f32 rows rounds at every add once a group's total passes 2^24 (on the
+    CPU and the TPU alike: 1e-4 relative at 6M rows); a block of n/1024
+    rows stays far below that."""
+    n = v.shape[0]
+    blocks = 1
+    while (
+        blocks < 1024
+        and n % (2 * blocks) == 0
+        and 2 * blocks * num_segments <= _BLOCK_PARTIALS
+    ):
+        blocks *= 2
+    if blocks == 1:
+        return jax.ops.segment_sum(v, seg, num_segments=num_segments)
+    partials = jax.vmap(
+        lambda vb, sb: jax.ops.segment_sum(vb, sb, num_segments=num_segments)
+    )(v.reshape(blocks, -1), seg.reshape(blocks, -1))
+    return partials.sum(axis=0)
 
 
 def combine_int_chunks(parts) -> np.ndarray:

@@ -16,10 +16,11 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import mesh_row_axes, shard_map
-from ..ops.intsum import int_chunk_sums
+from .mesh import mesh_row_axes
+from ..ops.intsum import blocked_segment_sum, int_chunk_sums
 
 
 def _row_axis(mesh: Mesh, axis):
@@ -143,7 +144,7 @@ def build_distributed_grouped_kernel(
                     )
                 else:
                     out.append(
-                        jax.lax.psum(jax.ops.segment_sum(vals, g, num_segments=seg_pad), axis)
+                        jax.lax.psum(blocked_segment_sum(vals, g, seg_pad), axis)
                     )
             elif kind == "min":
                 out.append(
@@ -153,8 +154,8 @@ def build_distributed_grouped_kernel(
                 out.append(
                     jax.lax.pmax(jax.ops.segment_max(vals, g, num_segments=seg_pad), axis)
                 )
-            elif kind == "avg":
-                if int_vals:  # exact chunked sums; the host divides
+            elif kind == "avg":  # the sum only: the host divides
+                if int_vals:  # exact chunked sums
                     out.append(
                         tuple(
                             jax.lax.psum(c, axis)
@@ -162,8 +163,9 @@ def build_distributed_grouped_kernel(
                         )
                     )
                 else:
-                    s = jax.lax.psum(jax.ops.segment_sum(vals, g, num_segments=seg_pad), axis)
-                    out.append(s / jnp.maximum(counts, 1))
+                    out.append(
+                        jax.lax.psum(blocked_segment_sum(vals, g, seg_pad), axis)
+                    )
         return counts, first_masked, tuple(out)
 
     def wrapper(cols, gids, mask):

@@ -23,9 +23,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import SHARD_AXIS, shard_map
+from .mesh import SHARD_AXIS
 from ..plan.kernel_cache import MESH_CACHE, mesh_probe_fingerprint
 
 # alias kept for tests/tools poking cache state directly

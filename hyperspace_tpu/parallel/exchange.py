@@ -24,9 +24,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import SHARD_AXIS, shard_map
+from .mesh import SHARD_AXIS
 
 
 def _exchange_body(axis: str, n_dest: int, capacity: int, cols, dest):

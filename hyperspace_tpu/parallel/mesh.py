@@ -13,26 +13,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level with a `check_vma` kwarg
-    from jax import shard_map as _shard_map_impl
-
-    _SHARD_MAP_KWARG = "check_vma"
-except ImportError:  # older jax: experimental module, `check_rep` spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_KWARG = "check_rep"
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-    """Version-guarded ``jax.shard_map``. Callers write the current
-    (top-level, ``check_vma``) API; this shim translates for jax releases
-    that only ship ``jax.experimental.shard_map.shard_map(check_rep=...)``."""
-    kwargs = {_SHARD_MAP_KWARG: check_vma}
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
 SHARD_AXIS = "shards"
 
 # Hierarchical (multi-slice) axis names: "ici" is the fast intra-slice
@@ -112,37 +92,31 @@ def num_shards(mesh: Mesh, axis: "str | tuple[str, ...] | None" = None) -> int:
 
 
 def visible_devices(cap: int = 0) -> list:
-    """The device list scale-out placement may target, resolved through the
-    watchdog-guarded probe (``utils.backend.safe_device_count``) so a hung
-    backend yields ``[]`` instead of freezing the caller. ``cap`` > 0 clamps
+    """The device list scale-out placement may target. ``cap`` > 0 clamps
     the list (``HYPERSPACE_MESH_DEVICES``); the order is ``jax.devices()``
     order, which is stable for a process lifetime — placement determinism
     leans on that."""
-    from ..utils.backend import safe_device_count
-
-    n = safe_device_count()
-    if n <= 0:
-        return []
-    devices = jax.devices()[:n]
-    if cap > 0:
-        devices = devices[:cap]
-    return list(devices)
+    devices = jax.devices()
+    return list(devices[:cap] if cap > 0 else devices)
 
 
 def active_mesh(session) -> Mesh | None:
-    """The execution mesh requested by `hyperspace.tpu.exec.meshDevices`
-    when that many devices actually exist; None otherwise. Device discovery
-    goes through the watchdog-guarded probe so a hung backend degrades to
-    the host/single-device path instead of freezing the caller."""
+    """The execution mesh requested by `hyperspace.tpu.exec.meshDevices`;
+    None when the conf asks for at most one device. Raises ValueError when
+    the conf asks for more devices than exist — a mesh the user configured
+    never silently drops to one device."""
     if session is None:
         return None
     n = session.conf.exec_mesh_devices
     if n <= 1:
         return None
-    from ..utils.backend import safe_device_count
+    from ..utils.backend import device_count
 
-    if safe_device_count() < n:
-        return None
+    if device_count() < n:
+        raise ValueError(
+            f"hyperspace.tpu.exec.meshDevices={n} but only {device_count()} "
+            "devices are visible"
+        )
     slices = session.conf.exec_mesh_slices
     if slices > 1:
         return hierarchical_mesh(slices, n // slices)

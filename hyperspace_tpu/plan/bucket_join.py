@@ -308,6 +308,14 @@ def try_bucketed_merge_join(
         # uniform index-usage event + pipeline counters for EVERY execution
         # path (satellite: the device paths used to emit nothing)
         _log_join_exec(session, left, right, path)
+        from ..telemetry import plan_stats
+
+        # the whole-join device paths route "device"; the per-bucket loop
+        # (host merge, or per-bucket device kernels) stays "bucketed"
+        plan_stats.note_route(
+            (agg_plan or plan).plan_id,
+            "bucketed" if path == "per_bucket" else "device",
+        )
         if path != "per_bucket":
             REGISTRY.counter("pipeline.join.queries").inc()
             REGISTRY.histogram("pipeline.join.query_ms").observe(
@@ -338,9 +346,9 @@ def try_bucketed_merge_join(
         session, agg_plan, left, right, lkeys, rkeys, residual
     ):
         # fused join+aggregate with band-stacked device dispatches + ONE
-        # fetch (plan.device_join.try_stacked_join_agg) — remote backends
-        # price every fetch at a tunnel round trip, so the whole join pays
-        # 1 blocking RPC, not num_buckets. Buckets load RAW (side filters
+        # fetch (plan.device_join.try_stacked_join_agg) — every fetch is a
+        # blocking device->host round trip, so the whole join pays 1, not
+        # num_buckets. Buckets load RAW (side filters
         # evaluate IN-KERNEL over stable index-chunk buffers, so
         # steady-state repeats upload nothing) and STREAM: a band wave
         # dispatches while later pairs still decode. The plan screen above
@@ -810,7 +818,7 @@ def _fused_device_possible(session, left, right, lkeys, rkeys) -> bool:
     under the device ledger); only the barrier mode — or a disabled
     device ledger — still declines oversized builds to the per-bucket
     flow, the pre-adaptive behavior."""
-    from ..utils.backend import device_healthy, safe_backend
+    from ..utils.backend import device_healthy
 
     if session is None or not session.conf.exec_tpu_enabled:
         return False
@@ -831,7 +839,7 @@ def _fused_device_possible(session, left, right, lkeys, rkeys) -> bool:
 
         if not (_join_pipeline_enabled() and device_budget().max_bytes > 0):
             return False
-    return device_healthy() and safe_backend() is not None
+    return device_healthy()
 
 
 def _empty_join_output(lb: ColumnBatch, rb: ColumnBatch) -> ColumnBatch:
@@ -860,7 +868,7 @@ def _try_device_join_paths(
     later pairs still decode; HYPERSPACE_PIPELINE=0 keeps the barrier +
     one-global-wave behavior."""
     from ..parallel.mesh import active_mesh
-    from ..utils.backend import device_healthy, safe_backend
+    from ..utils.backend import device_healthy
 
     if _plain_join_plan_screen(left, right, lkeys, rkeys, session) is None:
         return None, None, None
@@ -874,8 +882,6 @@ def _try_device_join_paths(
         # design (same rationale as the build exchange) — on a hierarchical
         # mesh fall through to the single-device / host tiers
         mesh = None
-    if mesh is None and safe_backend() is None:
-        return None, None, None
     from .device_join import try_batched_plain_join
 
     if mesh is not None or not _join_pipeline_enabled():

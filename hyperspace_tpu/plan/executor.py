@@ -487,11 +487,8 @@ def _exec_join(plan: Join, session) -> ColumnBatch:
     # hash table or shuffle
     from .bucket_join import try_bucketed_merge_join
 
-    bucketed = try_bucketed_merge_join(plan, session)
+    bucketed = try_bucketed_merge_join(plan, session)  # notes its own route
     if bucketed is not None:
-        from ..telemetry import plan_stats
-
-        plan_stats.note_route(plan.plan_id, "bucketed")
         return bucketed
     plan.schema  # raises on ambiguous output columns before any work runs
     left = execute_plan(plan.left, session)
@@ -554,9 +551,8 @@ def _exec_aggregate(plan: Aggregate, session) -> ColumnBatch:
     if isinstance(plan.child, Join):
         from .bucket_join import try_bucketed_join_aggregate
 
-        fused = try_bucketed_join_aggregate(plan, session)
+        fused = try_bucketed_join_aggregate(plan, session)  # notes its route
         if fused is not None:
-            plan_stats.note_route(plan.plan_id, "bucketed")
             return fused
     elif plan.group_exprs and not isinstance(plan.child, InMemoryScan):
         from .bucket_join import try_bucketed_scan_aggregate

@@ -1,7 +1,7 @@
 """Cross-query compiled-kernel cache.
 
 Device kernels are jitted closures built from a plan fragment; tracing one
-costs tens of milliseconds on CPU and seconds on a remote TPU — easily the
+costs tens of milliseconds on CPU and up to seconds on a TPU — easily the
 whole budget of a warm sub-second query. This module owns ONE process-wide
 cache per kernel family, keyed by a canonical plan fingerprint:
 

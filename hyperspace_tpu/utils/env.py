@@ -406,12 +406,6 @@ _register(
 
 # backend / device tier (utils/backend.py)
 _register(
-    "HYPERSPACE_BACKEND_TIMEOUT", "float", 30,
-    "Seconds the backend probe waits for a device grant before the host "
-    "tier takes over.",
-    "utils/backend.py",
-)
-_register(
     "HYPERSPACE_BREAKER_COOLDOWN", "float", 30,
     "Seconds the device breaker stays open after a transient device "
     "failure before a half-open recovery probe is allowed (doubles per "

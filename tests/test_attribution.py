@@ -425,7 +425,7 @@ class TestExporter:
         # a transient device failure (the PR 7 injected flavor) opens the
         # breaker: the health plane must flip to degraded/503
         backend.record_device_failure(
-            faults.InjectedIOError("injected: tunnel dropped")
+            faults.InjectedIOError("injected: device lost")
         )
         assert backend.breaker_state() == "open"
         code, body = _get(exp.url + "/healthz")

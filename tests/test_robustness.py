@@ -286,7 +286,7 @@ class TestBreaker:
     def test_transient_opens_then_probe_recovers(self):
         t = self._clock()
         assert backend.breaker_state() == backend.CLOSED
-        backend.record_device_failure(OSError("tunnel dropped"))
+        backend.record_device_failure(OSError("device lost"))
         assert backend.breaker_state() == backend.OPEN
         assert not backend.device_healthy()  # cooldown running
         t["now"] += 10.5  # past cooldown: exactly one probe admitted

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Diff two bench.py JSON artifacts section by section.
 
-    python tools/bench_compare.py BENCH_r05.json BENCH_r06.json
+    python tools/bench_compare.py BENCH_before.json BENCH_after.json
     python tools/bench_compare.py A.json B.json --threshold 5
 
 Walks the per-query sections plus the hybrid-refresh / bloom-skipping /

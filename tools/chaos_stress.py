@@ -7,7 +7,7 @@ Two sweeps, one contract ("bit-identical or typed error, never wrong
 answers" — docs/robustness.md):
 
 1. **Query sweep** — each armed ``HYPERSPACE_FAULTS`` spec (transient IO
-   errors, OOMs, device/tunnel failures, compile failures; nth-hit and
+   errors, OOMs, device failures, compile failures; nth-hit and
    seeded-probabilistic triggers) runs the full TPC-H query set against a
    warmed indexed warehouse. Every single run must either match the clean
    reference at ``float.hex()`` bit precision (retries / the device

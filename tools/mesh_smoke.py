@@ -46,7 +46,7 @@ def main() -> int:
     from hyperspace_tpu.benchmark import TPCH_QUERIES, generate_tpch
     from hyperspace_tpu.serve import budget as serve_budget
     from hyperspace_tpu.telemetry.metrics import REGISTRY
-    from hyperspace_tpu.utils.backend import safe_device_count
+    from hyperspace_tpu.utils.backend import device_count
 
     rows = int(os.environ.get("SMOKE_ROWS", 120_000))
     ws = tempfile.mkdtemp(prefix="hs_mesh_smoke_")
@@ -86,7 +86,7 @@ def main() -> int:
     )
 
     join_queries = ("q3", "q10", "q17")
-    devices_visible = safe_device_count()
+    devices_visible = device_count()
 
     def run(mesh: str) -> dict:
         os.environ["HYPERSPACE_MESH"] = mesh
