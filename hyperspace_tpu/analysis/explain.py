@@ -323,11 +323,11 @@ def tenant_state_string() -> str:
 
 
 def _phase_cell(record: dict) -> str:
-    """Compact ``plan/io/up/disp/fetch/fold/park`` ms breakdown for one
-    query record (phases the query never entered are omitted)."""
-    short = {"plan": "plan", "io": "io", "upload": "up",
-             "dispatch": "disp", "fetch": "fetch", "fold": "fold",
-             "park": "park"}
+    """Compact ``plan/io/iow/up/enq/dwait/fetch/fold/park/comp/host`` ms
+    breakdown for one query record (phases the query never entered are
+    omitted)."""
+    short = {"io_wait": "iow", "upload": "up", "enqueue": "enq",
+             "device_wait": "dwait", "compile": "comp"}
     parts = [
         f"{short.get(p, p)}={ms:.0f}"
         for p, ms in record.get("phases_ms", {}).items()

@@ -505,8 +505,9 @@ def _pmap_ordered(fn, items):
     REGISTRY.counter("io.parallel_reads").inc(len(items))
     # per-file work (decode, retry, cache counters) runs on pool threads:
     # carry the submitting thread's attribution target along so a serving
-    # query's charges don't escape its ledger entry
-    with io_pool(width) as pool:
+    # query's charges don't escape its ledger entry; the caller waits for
+    # the pool (io_wait)
+    with _attr.phase("io_wait"), io_pool(width) as pool:
         return list(pool.map(_attr.bound(fn), items))
 
 
@@ -607,12 +608,13 @@ def iter_chunks(
     def _decode(group: list[str]):
         t0 = time.perf_counter()
         try:
-            batch = read_parquet(group, columns, cache=cache, row_groups=row_groups)
+            with _attr.phase("io"):
+                batch = read_parquet(group, columns, cache=cache,
+                                     row_groups=row_groups)
         except Exception as e:  # noqa: BLE001 - wrapped for the executor
             raise ChunkReadError(f"chunk decode failed for {group}: {e}") from e
         dt = time.perf_counter() - t0
         REGISTRY.histogram("io.chunk_decode_ms").observe(dt * 1000)
-        _attr.charge_phase("io", dt)
         return batch, dt
 
     def _emit(i: int, batch: ColumnBatch, dt: float) -> StreamChunk:
@@ -661,7 +663,9 @@ def iter_chunks(
         _pump()
         for i in range(len(groups)):
             _serve_ctx.check_cancelled()
-            batch, dt = futures.pop(i).result()
+            # the consumer (the query thread) waits for the pool: io_wait
+            with _attr.phase("io_wait"):
+                batch, dt = futures.pop(i).result()
             bstream.release(ests[i])
             _pump()
             yield _emit(i, batch, dt)

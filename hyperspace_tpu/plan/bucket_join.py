@@ -681,7 +681,7 @@ def _load_all_bucket_pairs(left, right, appended_parts, session, raw=False):
         rb = _load_side_bucket(right, b, appended_parts[1], session, raw=raw)
         return lb, rb, l_sorted, r_sorted
 
-    with io_pool(io_worker_count(n), "hs-join") as pool:
+    with _attr.phase("io_wait"), io_pool(io_worker_count(n), "hs-join") as pool:
         return list(pool.map(_attr.bound(load), range(n)))
 
 
@@ -706,16 +706,13 @@ def _iter_bucket_pairs(left, right, appended_parts, session, raw=False,
     n = left.spec.num_buckets
 
     def load(b):
-        import time as _time
-
-        t0 = _time.perf_counter()
         l_sorted = appended_parts[0] is None and len(left.files_for_bucket(b)) <= 1
         r_sorted = appended_parts[1] is None and len(right.files_for_bucket(b)) <= 1
-        lb = _load_side_bucket(left, b, appended_parts[0], session, raw=raw)
-        rb = _load_side_bucket(right, b, appended_parts[1], session, raw=raw)
         # pair decode is the join's io phase (pool-thread time charged to
         # the submitting query's attribution target via bound())
-        _attr.charge_phase("io", _time.perf_counter() - t0)
+        with _attr.phase("io"):
+            lb = _load_side_bucket(left, b, appended_parts[0], session, raw=raw)
+            rb = _load_side_bucket(right, b, appended_parts[1], session, raw=raw)
         return lb, rb, l_sorted, r_sorted
 
     width = io_worker_count(n)
@@ -772,7 +769,9 @@ def _iter_bucket_pairs(left, right, appended_parts, session, raw=False,
         for b in range(n):
             serve_ctx.check_cancelled()
             with trace.span("join:load", bucket=b) as sp:
-                out = futures.pop(b).result()
+                # the query thread waits for the pool's decode: io_wait
+                with _attr.phase("io_wait"):
+                    out = futures.pop(b).result()
                 sp.set_attr("rows_l", 0 if out[0] is None else out[0].num_rows)
                 sp.set_attr("rows_r", 0 if out[1] is None else out[1].num_rows)
             bstream.release(ests[b])

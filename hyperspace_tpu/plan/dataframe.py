@@ -195,7 +195,7 @@ class DataFrame:
         # pruned scans or covering indexes are wrongly rejected
         from ..telemetry import attribution
 
-        with trace.span("plan"), attribution.phase("plan"):
+        with trace.span("plan", annotate=False), attribution.phase("plan"):
             plan = pre_rewrite_plan(self.plan)
             for rule in self.session.extra_optimizations:
                 plan = rule(plan)

@@ -537,7 +537,8 @@ def _prepare_join_agg_inner(
     from ..utils.rpc_meter import METER as _METER
 
     _METER.record_dispatch()
-    tree = kernel(dev_in)  # dispatched async; caller batches the fetch
+    with _attr.phase("enqueue"):
+        tree = kernel(dev_in)  # dispatched async; caller batches the fetch
 
     def assemble(fetched) -> ColumnBatch:
         # host-side output (one row per surviving right key); runs OUTSIDE
@@ -997,7 +998,8 @@ def _stacked_join_agg_impl(
             "join_stacked_agg",
         )
         METER.record_dispatch()
-        return kernel(lk_d, rk_d, n_l, n_r, lcols_d, rcols_d)
+        with _attr.phase("enqueue"):
+            return kernel(lk_d, rk_d, n_l, n_r, lcols_d, rcols_d)
 
     def _est_agg(pads, items):
         # one wave's device footprint: stacked 32-bit uploads (keys +
@@ -1155,12 +1157,12 @@ def _stacked_join_agg_impl(
                 with trace.span(
                     "mesh:gather", waves=len(pending),
                     devices=len({w.ordinal for w in pending}),
-                ), trace.span("join:fold", waves=len(pending)), \
-                        _attr.phase("fold"):
+                ), trace.span("join:fold", annotate=False,
+                              waves=len(pending)), _attr.phase("fold"):
                     fetched = device_get([w.rec for w in pending])
             else:
-                with trace.span("join:fold", waves=len(pending)), \
-                        _attr.phase("fold"):
+                with trace.span("join:fold", annotate=False,
+                                waves=len(pending)), _attr.phase("fold"):
                     fetched = device_get([w.rec for w in pending])
             for w, f in zip(pending, fetched):
                 w.done = f
@@ -1470,7 +1472,8 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
             "join_stacked_probe",
         )
         METER.record_dispatch()
-        return kernel(lk_d, rk_d, n_r, n_l)
+        with _attr.phase("enqueue"):
+            return kernel(lk_d, rk_d, n_r, n_l)
 
     def _expansion_plan(wave, totals_np, ok_np):
         """Validate one wave's probe totals and dispatch its run
@@ -1500,7 +1503,9 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
             "join_expand",
         )
         METER.record_dispatch()
-        return totals, True, kernel(lo_d, offs_d, jnp.asarray(totals_arr))
+        with _attr.phase("enqueue"):
+            tree = kernel(lo_d, offs_d, jnp.asarray(totals_arr))
+        return totals, True, tree
 
     def _est_probe(pads, items):
         # stacked key uploads + the probe's per-left-slot int32 outputs
@@ -1581,8 +1586,8 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
                     devices=len({w.ordinal for w in pending}),
                 ):
                     pass
-            with trace.span("join:probe", waves=len(pending)), \
-                    _attr.phase("fold"):
+            with trace.span("join:probe", annotate=False,
+                            waves=len(pending)), _attr.phase("fold"):
                 fetched = device_get(
                     [(w.rec[2], w.rec[3]) for w in pending]
                 )
@@ -1592,8 +1597,8 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
                 for w, (totals_np, ok_np) in zip(pending, fetched)
             ]
             pair_trees = [tree for _t, has, tree in plans if has]
-            with trace.span("join:fold", waves=len(pair_trees)), \
-                    _attr.phase("fold"):
+            with trace.span("join:fold", annotate=False,
+                            waves=len(pair_trees)), _attr.phase("fold"):
                 fetched_pairs = device_get(pair_trees) if pair_trees else []
             pair_idx = 0
             for w, (totals, has_pairs, _tree) in zip(pending, plans):
@@ -1755,7 +1760,9 @@ def _device_plain_join_inner(
     from ..utils.rpc_meter import METER as _METER, device_get as _metered_get
 
     _METER.record_dispatch()
-    lo_d, cnt_d = _metered_get(kernel(lk_d, rk_d, jnp.int32(n_r)))
+    with _attr.phase("enqueue"):
+        out = kernel(lk_d, rk_d, jnp.int32(n_r))
+    lo_d, cnt_d = _metered_get(out)
     starts = np.asarray(lo_d)[:n_l].astype(np.int64)
     counts = np.asarray(cnt_d)[:n_l].astype(np.int64)
 

@@ -64,6 +64,9 @@ def execute_plan(plan: LogicalPlan, session=None) -> ColumnBatch:
     check_cancelled()
     col = plan_stats.current()
     if col is None and not trace.enabled():
+        if trace.recording():  # a profiler session: the annotation only
+            with trace.span(f"exec:{plan.kind}"):
+                return _execute_node(plan, session)
         return _execute_node(plan, session)
     t0 = time.perf_counter() if col is not None else 0.0
     if not trace.enabled():

@@ -22,7 +22,11 @@ of the fingerprint instead.
 Observability: `cache.kernel.{hits,misses,evictions}` counters in the
 metrics registry, a `kernel.retrace` counter, and a `compile:<kind>` span
 around every build — a warm query's trace carries no compile span at all,
-which is the bench's "zero retraces" check.
+which is the bench's "zero retraces" check. The span marks a kernel-cache
+build, which only wraps the closure in ``jax.jit``: the XLA compile runs at
+the kernel's first call (and again for each new input shape, which never
+reaches this cache), and is charged to the calling query's ``compile``
+attribution phase from JAX's own compile event (telemetry/attribution.py).
 """
 
 from __future__ import annotations
@@ -86,8 +90,9 @@ class KernelCache:
             self._count("evictions", evicted)
 
     def get_or_build(self, key, builder: Callable, kind: str):
-        """The cached kernel for ``key``, building (and tracing) on miss
-        under a ``compile:<kind>`` span. Single-flight: concurrent misses
+        """The cached kernel for ``key``, building on miss under a
+        ``compile:<kind>`` span (the build marker; the XLA compile itself
+        runs at the kernel's first call). Single-flight: concurrent misses
         on one fingerprint trace ONCE — the first thread builds while the
         key is marked in-flight, the rest wait on its event and read the
         cached result (a failed build wakes them to take over). The build

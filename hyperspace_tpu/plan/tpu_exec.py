@@ -48,17 +48,6 @@ from ..telemetry import trace
 from ..telemetry.metrics import REGISTRY
 from ..utils import env
 
-
-def _observe_dispatch(kernel_name: str, t0: float) -> None:
-    """Per-kernel dispatch-latency histograms (always on; two clock reads
-    against milliseconds-scale device work). Doubles as the serving
-    query's "dispatch" phase chokepoint."""
-    dt = time.perf_counter() - t0
-    ms = dt * 1000
-    REGISTRY.histogram("kernel.dispatch_ms").observe(ms)
-    REGISTRY.histogram(f"kernel.{kernel_name}.dispatch_ms").observe(ms)
-    _attr.charge_phase("dispatch", dt)
-
 # ---------------------------------------------------------------------------
 # Expr -> jnp tracing
 # ---------------------------------------------------------------------------
@@ -1028,9 +1017,9 @@ def _try_execute_tpu_inner(
         from ..utils.rpc_meter import METER, device_get as metered_get
 
         METER.record_dispatch()
-        t0 = time.perf_counter()
-        matched, results = metered_get(kernel(dev_cols, mask))
-        _observe_dispatch("fused_agg", t0)
+        with _attr.phase("enqueue"):
+            out = kernel(dev_cols, mask)
+        matched, results = metered_get(out)
     matched = int(matched)
     scalar_values = [
         _combine_chunks_maybe_avg(v, kind, matched)
@@ -1228,11 +1217,9 @@ def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[Colu
         from ..utils.rpc_meter import METER, device_get as metered_get
 
         METER.record_dispatch()
-        t0 = time.perf_counter()
-        counts_dev, first_masked, results = metered_get(
-            kernel(dev_cols, gids_d, mask)
-        )
-        _observe_dispatch("grouped_agg", t0)
+        with _attr.phase("enqueue"):
+            out = kernel(dev_cols, gids_d, mask)
+        counts_dev, first_masked, results = metered_get(out)
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]
     results = [
@@ -1432,7 +1419,7 @@ def _stream_global_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch]
     accs: list = [None] * len(agg_list)
 
     def fold(res) -> None:
-        with trace.span("pipeline:fetch"), _attr.phase("fold"):
+        with trace.span("pipeline:fetch", annotate=False), _attr.phase("fold"):
             matched, results = metered_get(res)
         state["matched"] += int(matched)
         for i, (v, (kind, _c)) in enumerate(zip(results, agg_list)):
@@ -1488,7 +1475,8 @@ def _stream_global_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch]
                 "fused_agg",
             )
             METER.record_dispatch()
-            pending.append(kernel(dev_cols, mask))
+            with _attr.phase("enqueue"):
+                pending.append(kernel(dev_cols, mask))
             REGISTRY.counter("pipeline.chunks").inc()
         while len(pending) > depth:
             fold(pending.popleft())
@@ -1568,7 +1556,7 @@ def _stream_grouped_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch
     def fold(entry) -> None:
         nonlocal counts_g, first_g
         gmap, num_l, offset, res = entry
-        with trace.span("pipeline:fetch"), _attr.phase("fold"):
+        with trace.span("pipeline:fetch", annotate=False), _attr.phase("fold"):
             counts_l, first_l, results = metered_get(res)
         size = len(key_index)
         counts_g = _grown(counts_g, size, 0, np.int64)
@@ -1683,7 +1671,9 @@ def _stream_grouped_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch
                 "grouped_agg",
             )
             METER.record_dispatch()
-            pending.append((gmap, num_l, row_offset, kernel(dev_cols, gids_d, mask)))
+            with _attr.phase("enqueue"):
+                out = kernel(dev_cols, gids_d, mask)
+            pending.append((gmap, num_l, row_offset, out))
             REGISTRY.counter("pipeline.chunks").inc()
         row_offset += n
         while len(pending) > depth:
@@ -1838,9 +1828,9 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
                 "fused_agg",
             )
             METER.record_dispatch()
-            t0 = time.perf_counter()
-            matched, results = metered_get(kernel(dev_cols, mask))
-            _observe_dispatch("fused_agg", t0)
+            with _attr.phase("enqueue"):
+                out = kernel(dev_cols, mask)
+            matched, results = metered_get(out)
         matched = int(matched)
         scalar_values = [
             _combine_chunks_maybe_avg(v, kind, matched)
@@ -1872,11 +1862,9 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
             "grouped_agg",
         )
         METER.record_dispatch()
-        t0 = time.perf_counter()
-        counts_dev, first_masked, results = metered_get(
-            kernel(dev_cols, gids_d, mask)
-        )
-        _observe_dispatch("grouped_agg", t0)
+        with _attr.phase("enqueue"):
+            out = kernel(dev_cols, gids_d, mask)
+        counts_dev, first_masked, results = metered_get(out)
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]
     results = [
@@ -1955,10 +1943,12 @@ def try_device_topk(sort_plan, k: int, batch: ColumnBatch, session) -> Optional[
                 key, lambda: _build_topk_kernel(int(k), bool(asc), padded),
                 "topk",
             )
+            from ..utils.rpc_meter import device_get as metered_get
+
             _M.record_dispatch()
-            t0 = time.perf_counter()
-            idx = np.asarray(kernel(jnp.asarray(arr), jnp.int32(n)))
-            _observe_dispatch("topk", t0)
+            with _attr.phase("enqueue"):
+                out = kernel(jnp.asarray(arr), jnp.int32(n))
+            idx = metered_get(out)
     except Exception as e:  # device failure: host top-k takes over
         record_device_failure(e)
         return None
@@ -2099,9 +2089,9 @@ def try_device_sort(sort_plan, batch: ColumnBatch, session) -> Optional[ColumnBa
             from ..utils.rpc_meter import METER, device_get as metered_get
 
             METER.record_dispatch()
-            t0 = time.perf_counter()
-            perm = np.asarray(metered_get(kernel(*ops)))[:n]
-            _observe_dispatch("sort", t0)
+            with _attr.phase("enqueue"):
+                out = kernel(*ops)
+            perm = np.asarray(metered_get(out))[:n]
     except Exception as e:  # device failure: host sort takes over
         record_device_failure(e)
         return None
@@ -2202,11 +2192,9 @@ def _execute_on_mesh(frag: _Fragment, batch: ColumnBatch, plan, session, mesh) -
         "kernel:mesh_agg", rows=n, shards=d, groups=num_groups
     ):
         METER.record_dispatch()
-        t0 = time.perf_counter()
-        counts_dev, first_masked, results = metered_get(
-            kernel(dev_cols, gids_d, mask_d)
-        )
-        _observe_dispatch("mesh_agg", t0)
+        with _attr.phase("enqueue"):
+            out = kernel(dev_cols, gids_d, mask_d)
+        counts_dev, first_masked, results = metered_get(out)
     info = getattr(frag.scan, "index_info", None)
     if info is not None:
         from ..rules.rule_utils import log_index_usage

@@ -456,7 +456,9 @@ class QueryScheduler:
         )
         # open the per-query attribution entry and install it for the whole
         # execution: every counter/histogram write on this thread — and on
-        # IO-pool tasks bound via attribution.bound() — charges this query
+        # IO-pool tasks bound via attribution.bound() — charges this query.
+        # begin() records this worker as the query's thread, where phases
+        # are exclusive and finish() charges the rest as `host`
         stats = attribution.LEDGER.begin(h.ctx, queue_wait_s=h.queue_wait_s)
         if h.ctx.approx_fraction is not None:
             # stamp the admission-time degrade decision on the query-log

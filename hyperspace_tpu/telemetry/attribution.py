@@ -25,18 +25,32 @@ Phase accounting: spans need tracing enabled, but the serving query log
 must work on an untraced server, so the engine's phase chokepoints charge
 wall time directly via ``phase(name)`` / ``charge_phase``:
 
-    plan      optimizer + index rewrite       (plan/dataframe.py)
-    io        chunk / bucket-pair decode      (columnar/io.py, bucket_join)
-    upload    host->device transfers          (device_cache, tpu_exec)
-    dispatch  device kernel dispatch          (tpu_exec._observe_dispatch)
-    fetch     blocking device_get round trips (utils/rpc_meter.device_get)
-    fold      host folds of fetched partials  (tpu_exec, device_join)
-    park      device-ledger admission waits   (plan/join_memory.DeviceLedger)
+    plan         optimizer + index rewrite     (plan/dataframe.py)
+    io           chunk / bucket-pair decode    (columnar/io.py, bucket_join)
+    io_wait      the query thread waiting for a decode on the IO pool
+                                               (columnar/io.py, bucket_join)
+    upload       host->device transfers        (device_cache)
+    enqueue      asynchronous kernel enqueue   (tpu_exec, device_join)
+    device_wait  waiting for the device to finish what a fetch reads
+                                               (utils/rpc_meter.device_get)
+    fetch        device->host copy of ready arrays (rpc_meter.device_get)
+    fold         host folds of fetched partials (tpu_exec, device_join)
+    park         device-ledger admission waits (plan/join_memory.DeviceLedger)
+    compile      XLA compiles and persistent-cache loads, from JAX's
+                 ``backend_compile_duration`` event
+    host         the rest of the query thread's run: its self time
 
-Phases are *resource* times: io runs on pool threads concurrently with
-dispatch, so phases can overlap and need not sum to wall time. When
-tracing IS enabled the same breakdown is recoverable from the query's
-``serve:query`` span tree (tools/trace_report.py --query).
+On the thread that runs the query (``QueryStats.thread``, recorded by
+``LEDGER.begin``) phases are exclusive: a phase opened or charged inside
+another pauses the outer one, and ``host`` is the run time from ``begin``
+to ``finish`` less every phase charged on that thread, so the phases
+charged there and ``host`` sum to the run time. Charges from other
+threads (IO-pool tasks through ``bound``) are *resource* times that
+overlap the query thread's: ``io`` counts decode time on the pool, and the
+query thread's wait for it is ``io_wait``. When tracing IS enabled the
+same breakdown is recoverable from the query's ``serve:query`` span tree
+(tools/trace_report.py --query); while a profiler session records, each
+``phase`` block is a ``hs:phase:<name>`` annotation (telemetry/trace.py).
 
 Every finished query (done / failed / cancelled — including cancelled
 while still queued) appends a structured record to a rolling in-memory
@@ -60,12 +74,16 @@ import threading
 import time
 from typing import Optional
 
+import jax
+
 from ..staticcheck.concurrency import TrackedLock
 from ..staticcheck.lifecycle import release_resource, tracked_resource
 from ..utils import env
+from . import trace
 from .metrics import _attr_target
 
-PHASES = ("plan", "io", "upload", "dispatch", "fetch", "fold", "park")
+PHASES = ("plan", "io", "io_wait", "upload", "enqueue", "device_wait",
+          "fetch", "fold", "park", "compile", "host")
 
 # global-counter names surfaced as first-class query-record fields
 _BYTES_DECODED = "io.bytes_decoded"
@@ -77,11 +95,14 @@ class QueryStats:
     phase times charged while the query's target is installed. Charged
     from several threads at once (the query worker plus bound IO-pool
     tasks), so all mutation sits under one plain leaf lock — like the
-    per-metric value locks, nothing is ever acquired while holding it."""
+    per-metric value locks, nothing is ever acquired while holding it.
+    The open-phase stack and the exclusive on-thread times are touched
+    only by the query's own thread (``thread``), so they need no lock."""
 
     __slots__ = (
         "query_id", "label", "priority", "tenant", "seq", "started_s",
         "finished_s", "outcome", "error", "queue_wait_s", "duration_s",
+        "thread", "_run_t0", "_open", "_own",
         "_lock", "_counters", "_hists", "_phases", "_wl", "_approx",
     )
 
@@ -99,6 +120,12 @@ class QueryStats:
         self.error: Optional[str] = None
         self.queue_wait_s = queue_wait_s
         self.duration_s = 0.0
+        # the thread running the query (None: a record that never ran)
+        self.thread: Optional[int] = None
+        self._run_t0 = 0.0
+        # per open on-thread phase: seconds charged to phases nested in it
+        self._open: list = []
+        self._own: dict[str, float] = {}  # exclusive on-thread seconds
         self._lock = threading.Lock()
         self._counters: dict[str, float] = {}
         self._hists: dict[str, tuple] = {}  # name -> (count, sum)
@@ -120,6 +147,56 @@ class QueryStats:
     def charge_phase(self, name: str, seconds: float) -> None:
         with self._lock:
             self._phases[name] = self._phases.get(name, 0.0) + seconds
+
+    # --- exclusive phases on the query thread ------------------------------
+
+    def run_here(self) -> None:
+        """Mark the calling thread as the one running the query and start
+        its run clock (``host`` is measured from here to ``finish``)."""
+        self.thread = threading.get_ident()
+        self._run_t0 = time.perf_counter()
+
+    def _own_time(self, name: str, elapsed: float, nested: float) -> None:
+        # a phase that ran on the query thread: the open phase around it
+        # (if any) pauses for it; its own time excludes its nested phases
+        if self._open:
+            self._open[-1] += elapsed
+        self._own[name] = self._own.get(name, 0.0) + elapsed - nested
+
+    def charge_on_thread(self, name: str, seconds: float) -> None:
+        """Charge a phase timed after the fact, exclusive on the query
+        thread and resource time on any other."""
+        if self.thread == threading.get_ident():
+            self._own_time(name, seconds, 0.0)
+        self.charge_phase(name, seconds)
+
+    def open_phase(self) -> bool:
+        """Push an open phase if called on the query thread."""
+        if self.thread != threading.get_ident():
+            return False
+        self._open.append(0.0)
+        return True
+
+    def close_phase(self, name: str, seconds: float, on_thread: bool) -> None:
+        """Charge a closed ``phase`` block: on the query thread, its time
+        less that of the phases nested in it."""
+        if on_thread:
+            nested = self._open.pop()
+            self._own_time(name, seconds, nested)
+            seconds -= nested
+        self.charge_phase(name, seconds)
+
+    def charge_host(self) -> None:
+        """Charge ``host``: the run time on the query thread less every
+        phase charged there. Called once, by ``finish``."""
+        if self.thread is not None:
+            run_s = time.perf_counter() - self._run_t0
+            self.charge_phase("host", run_s - sum(self._own.values()))
+
+    def on_thread_s(self) -> dict:
+        """Exclusive seconds per phase charged on the query thread (with
+        ``host``, they sum to its run time); read after ``finish``."""
+        return dict(self._own)
 
     def note_workload(self, key: str, item, cap: int = 64) -> None:
         """Append one workload-plane note (telemetry/workload.py chokepoints:
@@ -273,37 +350,59 @@ def bound(fn):
 
 
 def charge_phase(name: str, seconds: float) -> None:
-    """Charge ``seconds`` of ``name`` phase time to the running query, if
-    any. One contextvar read when idle — cheap enough for per-chunk and
-    per-dispatch chokepoints."""
+    """Charge ``seconds`` of ``name`` phase time, timed after the fact, to
+    the running query, if any (exclusive on its thread). One contextvar
+    read when idle — cheap enough for per-chunk and per-dispatch
+    chokepoints. Such a charge is not annotated on the profiler's trace:
+    prefer a ``phase`` block where the timing allows."""
     stats = _attr_target.get()
     if stats is not None:
-        stats.charge_phase(name, seconds)
+        stats.charge_on_thread(name, seconds)
 
 
 class phase:
-    """Context manager charging the block's wall time to a phase. The
-    clock is only read when a query is actually being attributed."""
+    """Context manager charging the block's wall time to a phase, and
+    annotating it ``hs:phase:<name>`` while a profiler session records.
+    The clock is only read when a query is actually being attributed."""
 
-    __slots__ = ("_name", "_stats", "_t0")
+    __slots__ = ("_name", "_stats", "_t0", "_on_thread", "_ann")
 
     def __init__(self, name: str):
         self._name = name
         self._stats = None
         self._t0 = 0.0
+        self._on_thread = False
+        self._ann = None
 
     def __enter__(self) -> "phase":
+        self._ann = trace.annotation("hs:phase:" + self._name)
         self._stats = _attr_target.get()
         if self._stats is not None:
+            self._on_thread = self._stats.open_phase()
             self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         if self._stats is not None:
-            self._stats.charge_phase(
-                self._name, time.perf_counter() - self._t0
+            self._stats.close_phase(
+                self._name, time.perf_counter() - self._t0, self._on_thread
             )
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile(event: str, seconds: float, **_) -> None:
+    # XLA compiles (and persistent-cache loads) run synchronously on the
+    # thread whose call needed them, under its attribution target
+    if event == _COMPILE_EVENT:
+        charge_phase("compile", seconds)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 # ---------------------------------------------------------------------------
@@ -331,8 +430,11 @@ class QueryStatsLedger:
 
     # --- lifecycle (scheduler integration) --------------------------------
 
-    def begin(self, ctx, queue_wait_s: float = 0.0) -> QueryStats:
-        """Open the ledger entry for an admitted query (its QueryContext)."""
+    def begin(self, ctx, queue_wait_s: float = 0.0,
+              runs: bool = True) -> QueryStats:
+        """Open the ledger entry for an admitted query (its QueryContext),
+        on the thread that runs it (``runs=False``: a record of a query
+        that never ran)."""
         stats = QueryStats(
             ctx.query_id, label=ctx.label, priority=ctx.priority,
             queue_wait_s=queue_wait_s,
@@ -341,15 +443,19 @@ class QueryStatsLedger:
         with self._lock:
             stats.seq = next(self._seq)
             self._active[stats.query_id] = stats
+        if runs:
+            stats.run_here()
         return stats
 
     def finish(self, stats: QueryStats, outcome: str,
                error: Optional[BaseException] = None) -> dict:
-        """Move a query to the recent window and emit its rollup metrics.
-        Call AFTER the attribution scope exited, so the rollups themselves
-        are not charged back to the query."""
+        """Move a query to the recent window, charge its ``host`` phase and
+        emit its rollup metrics. Call on the query's thread AFTER the
+        attribution scope exited, so the rollups themselves are not
+        charged back to the query."""
         stats.outcome = outcome
         stats.finished_s = time.time()
+        stats.charge_host()
         stats.duration_s = max(0.0, stats.finished_s - stats.started_s)
         if error is not None:
             stats.error = repr(error)
@@ -391,7 +497,7 @@ class QueryStatsLedger:
                      queue_wait_s: float = 0.0) -> dict:
         """Query-log completeness for queries that never ran (cancelled
         while queued): zero-charge entry straight to the recent window."""
-        stats = self.begin(ctx, queue_wait_s=queue_wait_s)
+        stats = self.begin(ctx, queue_wait_s=queue_wait_s, runs=False)
         return self.finish(stats, outcome)
 
     # --- reads ------------------------------------------------------------

@@ -38,10 +38,10 @@ Canonical metric names (see docs/observability.md for the full catalog):
     pruning.bytes_skipped                          index bytes never decoded
     pruning.verified                               PRUNE=verify passes
     cache.rowgroup_stats.{hits,misses,evictions}   parquet footer-stats cache
-    kernel.dispatch_ms                             device kernel latencies
     rpc.upload_bytes / rpc.fetch_bytes             transfer volume
     io.bytes_decoded / io.rows_decoded             decoded scan volume
     serve.query.*                                  per-query ledger rollups
+    serve.query.phase.<p>_ms                       per-query attribution phases
     exporter.*                                     /metrics endpoint activity
 
 Attributed write path: when a serving query is executing, the scheduler

@@ -16,7 +16,9 @@ Span taxonomy (see docs/observability.md):
       exec:<op>           one host-executor node (Filter, Join, Aggregate, ...)
         kernel:<name>     one device kernel dispatch (fused_agg, sort, ...)
           upload / fetch  host<->device transfers inside the kernel
-          compile:<kind>  a kernel-cache miss tracing a new executable
+          compile:<kind>  a kernel-cache build (wraps the closure in
+                          jax.jit; the XLA compile runs at the first call
+                          and is charged to the ``compile`` phase)
         pipeline:<route>  one streamed fragment (partial | concat)
           pipeline:chunk  one chunk's upload + dispatch (decode_ms attr)
           pipeline:fetch  one in-order partial fold (carries RPC deltas)
@@ -42,6 +44,15 @@ Force-enable from the environment (used by the verify flow to run the whole
 tier-1 suite traced): ``HYPERSPACE_TRACE=1`` enables at import;
 ``HYPERSPACE_TRACE_FILE=/path/trace.jsonl`` additionally attaches a JSONL
 sink.
+
+Profiler bridge: while a JAX profiler session records, every span (traced
+or not) and every attribution phase (telemetry/attribution.py) also opens a
+``jax.profiler.TraceAnnotation`` — ``hs:<span name>`` and
+``hs:phase:<phase>`` — carrying the running query's ``query_id``, so the
+program's timing lands on the profiler's host plane and clock beside the
+device ops. With no session recording, a site pays one flag check and
+builds nothing. A span opened together with a phase of the same extent
+passes ``annotate=False``, so each site emits one annotation.
 """
 
 from __future__ import annotations
@@ -53,9 +64,12 @@ import threading
 import time
 from typing import Any, Optional
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from ..staticcheck.concurrency import TrackedLock, guarded_by
 from ..utils import env
 from ..utils.rpc_meter import METER, RpcMeter
+from .metrics import _attr_target
 
 _RPC_ZERO = {
     "dispatches": 0,
@@ -80,6 +94,23 @@ def enabled() -> bool:
     return _ENABLED
 
 
+# the profiler's own flag: true while a jax.profiler session records
+recording = _Annotation.is_enabled
+
+
+def annotation(label: str):
+    """An entered ``TraceAnnotation`` named ``label``, carrying the running
+    query's id, while a profiler session records; else None (nothing is
+    built). The caller closes it with ``__exit__``."""
+    if not recording():
+        return None
+    stats = _attr_target.get()
+    ann = (_Annotation(label) if stats is None
+           else _Annotation(label, query_id=stats.query_id))
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """One completed or in-flight span. Use via ``with trace.span(...)``."""
 
@@ -94,9 +125,11 @@ class Span:
         "rpc",
         "_t0",
         "_rpc0",
+        "_ann",
     )
 
-    def __init__(self, name: str, attrs: dict, parent_id: Optional[int]):
+    def __init__(self, name: str, attrs: dict, parent_id: Optional[int],
+                 annotate: bool = True):
         self.span_id = next(_ids)
         self.parent_id = parent_id
         self.name = name
@@ -107,17 +140,21 @@ class Span:
         self.rpc = dict(_RPC_ZERO)
         self._t0 = 0.0
         self._rpc0: dict = {}
+        self._ann = annotate
 
     # --- context manager ---
     def __enter__(self) -> "Span":
         stack = _stack()
         stack.append(self)
+        self._ann = annotation("hs:" + self.name) if self._ann else None
         self._rpc0 = METER.snapshot()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.duration_s = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self.rpc = RpcMeter.delta(self._rpc0, METER.snapshot())
         stack = _stack()
         # tolerate a corrupted stack (an instrumented site that leaked a
@@ -184,6 +221,25 @@ class _NoOpSpan:
 NOOP_SPAN = _NoOpSpan()
 
 
+class _AnnotatedSpan(_NoOpSpan):
+    """An untraced span while the profiler records: the annotation only."""
+
+    __slots__ = ("_label", "_ann")
+
+    def __init__(self, name: str):
+        self._label = "hs:" + name
+        self._ann = None
+
+    def __enter__(self) -> "_AnnotatedSpan":
+        self._ann = annotation(self._label)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        return False
+
+
 def _stack() -> list:
     stack = getattr(_local, "stack", None)
     if stack is None:
@@ -191,13 +247,17 @@ def _stack() -> list:
     return stack
 
 
-def span(name: str, **attrs):
-    """Open a span (context manager). Near-free no-op when disabled."""
+def span(name: str, *, annotate: bool = True, **attrs):
+    """Open a span (context manager). Near-free no-op when disabled and no
+    profiler session records; ``annotate=False`` leaves the profiler
+    annotation to a phase opened with it."""
     if not _ENABLED:
+        if annotate and recording():
+            return _AnnotatedSpan(name)
         return NOOP_SPAN
     stack = _stack()
     parent = stack[-1] if stack else None
-    return Span(name, attrs, parent.span_id if parent else None)
+    return Span(name, attrs, parent.span_id if parent else None, annotate)
 
 
 def current_span() -> Optional[Span]:

@@ -139,7 +139,7 @@ class DeviceArrayCache:
         from ..telemetry import attribution, trace
         from .rpc_meter import METER
 
-        with trace.span("upload", key=str(key_extra)), \
+        with trace.span("upload", annotate=False, key=str(key_extra)), \
                 attribution.phase("upload"):
             value = builder()
             nbytes = _tree_nbytes(value)

@@ -28,7 +28,7 @@ def _tree_nbytes(value) -> int:
 class RpcMeter:
     def __init__(self) -> None:
         self._lock = TrackedLock("rpc_meter")
-        self.dispatches = 0  # jitted kernel calls (async dispatch RPCs)
+        self.dispatches = 0  # jitted kernel calls (async enqueue RPCs)
         self.fetches = 0  # blocking device_get round trips
         self.uploads = 0  # host->device array transfers
         self.upload_bytes = 0
@@ -125,19 +125,20 @@ def device_get(tree):
     paths instead of calling jax directly so every blocking round trip
     lands in the meter (and, when tracing is on, in a `fetch` span). The
     one funnel every blocking fetch passes through, so it is also the
-    serving query's "fetch" phase chokepoint."""
-    import time
-
+    serving query's chokepoint for two phases: ``device_wait`` (the device
+    finishing what the fetch reads) and ``fetch`` (the copy of ready
+    arrays)."""
     import jax
 
     from ..telemetry import attribution, trace
     from . import faults
 
-    with trace.span("fetch"):
+    with trace.span("fetch", annotate=False):
         faults.fire("device.fetch")
-        t0 = time.perf_counter()
-        out = jax.device_get(tree)
-        attribution.charge_phase("fetch", time.perf_counter() - t0)
+        with attribution.phase("device_wait"):
+            jax.block_until_ready(tree)
+        with attribution.phase("fetch"):
+            out = jax.device_get(tree)
         nbytes = _tree_nbytes(out)
         METER.record_fetch(nbytes)
         trace.add_attr("nbytes", nbytes)

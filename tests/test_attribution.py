@@ -140,11 +140,11 @@ class TestAttributedWrites:
         with attribution.scope(s):
             with attribution.phase("io"):
                 pass
-            attribution.charge_phase("dispatch", 0.25)
+            attribution.charge_phase("enqueue", 0.25)
         attribution.charge_phase("fetch", 9.0)  # no scope: dropped
         phases = s.phases_s()
         assert phases["io"] >= 0.0
-        assert phases["dispatch"] == pytest.approx(0.25)
+        assert phases["enqueue"] == pytest.approx(0.25)
         assert "fetch" not in phases
         assert set(phases) <= set(attribution.PHASES)
 
@@ -255,14 +255,14 @@ class TestQueryLedger:
     def test_phase_percentiles(self):
         recs = [
             {"total_ms": 10.0, "queue_wait_ms": 1.0,
-             "phases_ms": {"io": 4.0, "dispatch": 2.0}},
+             "phases_ms": {"io": 4.0, "enqueue": 2.0}},
             {"total_ms": 20.0, "queue_wait_ms": 3.0,
              "phases_ms": {"io": 8.0}},
         ]
         out = phase_percentiles(recs)
         assert out["total"] == {"count": 2, "mean_ms": 15.0, "p99_ms": 20.0}
         assert out["io"]["mean_ms"] == pytest.approx(6.0)
-        assert out["dispatch"]["count"] == 1
+        assert out["enqueue"]["count"] == 1
         assert out["queue"]["count"] == 2
         assert phase_percentiles([]) == {}
 

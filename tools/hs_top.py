@@ -32,9 +32,10 @@ import time
 import urllib.request
 
 _PHASE_SHORT = (
-    ("plan", "plan"), ("io", "io"), ("upload", "up"),
-    ("dispatch", "disp"), ("fetch", "fetch"), ("fold", "fold"),
-    ("park", "park"),
+    ("plan", "plan"), ("io", "io"), ("io_wait", "iow"), ("upload", "up"),
+    ("enqueue", "enq"), ("device_wait", "dwait"), ("fetch", "fetch"),
+    ("fold", "fold"), ("park", "park"), ("compile", "comp"),
+    ("host", "host"),
 )
 
 
