@@ -5,7 +5,9 @@ arranges by swapping both sides for equally-bucketed indexes — Spark then
 runs SMJ with no Exchange (covering/JoinIndexRule.scala:635-687). On TPU,
 bucket b of both indexes lives on shard b, so the join is embarrassingly
 parallel per shard; within a shard both sides are sorted by key, and the
-match structure comes from two searchsorted passes.
+match structure comes from one probe of the left keys into the right keys
+(``probe_ranks``): a sort-merge rank, or a binary search where the left
+side is much smaller than the right.
 
 XLA's static shapes make "materialize all match pairs" awkward (dynamic
 output), so the primitives here favor the patterns index-accelerated queries
@@ -21,6 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 
 def exact_key32(a: np.ndarray):
@@ -40,11 +43,58 @@ def exact_key32(a: np.ndarray):
     return None
 
 
-def merge_match_counts(left_keys_sorted, right_keys_sorted):
-    """For each left row: number of right matches. Both inputs sorted asc."""
-    lo = jnp.searchsorted(right_keys_sorted, left_keys_sorted, side="left")
-    hi = jnp.searchsorted(right_keys_sorted, left_keys_sorted, side="right")
-    return lo, hi - lo
+# The merge form sorts pad_l + pad_r keys twice; the search form runs
+# ceil(log2 pad_r) + 1 dependent gather rounds over pad_l probes. On one
+# v5e the merge was faster up to a right side 128 times the left and
+# slower from 256 times (PERF.md section 6, PR 24), so the search form
+# stays only past this ratio.
+_SEARCH_RATIO = 128
+
+
+def merge_probe(pad_l: int, pad_r: int) -> bool:
+    """Whether ``probe_ranks`` takes the merge form at these static pads."""
+    return pad_r <= _SEARCH_RATIO * pad_l
+
+
+def probe_temp_bytes(pad_l: int, pad_r: int) -> int:
+    """Device temporaries of one merge-form probe: six int32 words a row
+    of the merged sequence (key and position through the first sort; the
+    run scans; position, lo and hi through the compaction sort)."""
+    return 24 * (pad_l + pad_r) if merge_probe(pad_l, pad_r) else 0
+
+
+def probe_ranks(rk, lk, n_r):
+    """(lo, hi) for every left key: its left and right insertion ranks in
+    the sorted 1-D right keys ``rk`` (np.searchsorted's two sides), each
+    clamped to ``n_r``, the real right rows ahead of the pads. ``lk``
+    need not be sorted. The form follows the static pads (``merge_probe``):
+    both give the same integers."""
+    if merge_probe(lk.shape[0], rk.shape[0]):
+        lo, hi = _merge_ranks(rk, lk)
+    else:
+        lo = jnp.searchsorted(rk, lk, side="left")
+        hi = jnp.searchsorted(rk, lk, side="right")
+    return jnp.minimum(lo, n_r), jnp.minimum(hi, n_r)
+
+
+def _merge_ranks(rk, lk):
+    """Ranks by merging: one stable sort of the right keys followed by the
+    left keys (so a right row precedes a left row of the same key), scans
+    over the merged order, and a sort by position that returns the left
+    rows to their own order. No gather and no loop. Float keys need no
+    canonical zero: the sort's comparator and the run test both take
+    -0.0 for 0.0, as np.searchsorted does."""
+    n_r = rk.shape[0]
+    keys = jnp.concatenate([rk, lk])
+    pos = lax.iota(jnp.int32, keys.shape[0])
+    keys, pos = lax.sort((keys, pos), num_keys=1, is_stable=True)
+    is_r = (pos < n_r).astype(jnp.int32)
+    hi = jnp.cumsum(is_r)  # right rows at or before: all keys <= this one
+    run = jnp.concatenate([jnp.ones(1, bool), keys[1:] != keys[:-1]])
+    # right rows before the run of this key: all keys < this one
+    lo = lax.cummax(jnp.where(run, hi - is_r, 0))
+    _, lo, hi = lax.sort((pos, lo, hi), num_keys=1)
+    return lo[n_r:], hi[n_r:]
 
 
 def segment_sum_by_sorted_key(keys_sorted, values, unique_keys):

@@ -11,10 +11,10 @@ the join embarrassingly shard-local (the ICI stays idle by design, unlike
 the raw-table join whose all_to_all it replaces).
 
 The probe phase (per-left-row lower bound + match count over the sorted
-right keys) is static-shaped and runs on device; run expansion to pair
-indices is dynamic-sized and stays on the host, exactly like the
-single-device plain join (plan/device_join.py), so results are
-bit-identical to the host merge join.
+right keys, ops/join.probe_ranks) is static-shaped and runs on device;
+run expansion to pair indices is dynamic-sized and stays on the host,
+exactly like the single-device plain join (plan/device_join.py), so
+results are bit-identical to the host merge join.
 """
 
 from __future__ import annotations
@@ -27,6 +27,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import SHARD_AXIS
+from ..ops.join import probe_ranks
 from ..plan.kernel_cache import MESH_CACHE, mesh_probe_fingerprint
 
 # alias kept for tests/tools poking cache state directly
@@ -36,11 +37,7 @@ _PROBE_CACHE = MESH_CACHE
 def _build_probe(mesh: Mesh, axis: str):
     def body(lk, rk, n_r):
         # [1, padL] / [1, padR] / [1] per shard — purely local, no psum
-        lo = jnp.searchsorted(rk[0], lk[0], side="left")
-        hi = jnp.searchsorted(rk[0], lk[0], side="right")
-        n = n_r[0]
-        lo = jnp.minimum(lo, n)
-        hi = jnp.minimum(hi, n)
+        lo, hi = probe_ranks(rk[0], lk[0], n_r[0])
         return lo[None, :].astype(jnp.int32), (hi - lo)[None, :].astype(jnp.int32)
 
     fn = shard_map(
@@ -73,6 +70,7 @@ def mesh_join_probe(
         key, lambda: _build_probe(mesh, axis), "mesh_probe"
     )
     shard = NamedSharding(mesh, P(axis))
+    from ..plan.device_join import note_probe
     from ..telemetry import trace
     from ..utils.rpc_meter import METER, device_get as metered_get
 
@@ -80,6 +78,7 @@ def mesh_join_probe(
         "kernel:mesh_join_probe", buckets=int(lk_stack.shape[0])
     ):
         METER.record_upload(lk_stack.nbytes + rk_stack.nbytes + n_r.nbytes, n=3)
+        note_probe(lk_stack.shape[1], rk_stack.shape[1], lk_stack.size)
         METER.record_dispatch()
         lo, cnt = metered_get(
             fn(

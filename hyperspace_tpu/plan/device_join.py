@@ -4,7 +4,8 @@ The physical payoff of JoinIndexRule on TPU (ref: the Exchange-free
 sort-merge join arranged by covering/JoinIndexRule.scala:635-720 and executed
 by BucketUnionExec.scala:52-121): per bucket, the right side arrives sorted
 by the join key from the index file, every left row probes it with one
-device searchsorted, right attributes gather back per left row, and the
+device rank probe (ops/join.probe_ranks: a sort-merge, or a binary search
+for a small left side), right attributes gather back per left row, and the
 aggregate reduces per right key with segment reductions — the join output
 NEVER materializes. Only [n_right_keys]-sized aggregate vectors return to
 the host (the Q3 hot shape: revenue per order over a lineitem x orders
@@ -37,6 +38,7 @@ from . import expr as X
 from .expr import Alias, Expr, expr_output_name
 from .kernel_cache import JOIN_CACHE, join_fingerprint
 from ..columnar.table import Column, ColumnBatch, STRING
+from ..ops.join import merge_probe, probe_ranks, probe_temp_bytes
 from ..telemetry import attribution as _attr
 from ..telemetry import trace
 from ..telemetry.metrics import REGISTRY
@@ -74,6 +76,15 @@ _JOIN_WAVE = 8
 def _band_pads(n_l: int, n_r: int) -> tuple:
     """The power-of-2 size band a bucket pair belongs to: its vmap pads."""
     return _pow2(n_l), _pow2(n_r)
+
+
+def note_probe(pad_l: int, pad_r: int, rows: int) -> None:
+    """Count the ``rows`` probe slots (pads included) of one dispatched
+    kernel under the form ``probe_ranks`` takes at these pads."""
+    if merge_probe(pad_l, pad_r):
+        REGISTRY.counter("join.probe.merge").inc(rows)
+    else:
+        REGISTRY.counter("join.probe.search").inc(rows)
 
 
 class _JoinDeclined(Exception):
@@ -536,6 +547,7 @@ def _prepare_join_agg_inner(
     )
     from ..utils.rpc_meter import METER as _METER
 
+    note_probe(pad_l, pad_r, pad_l)
     _METER.record_dispatch()
     with _attr.phase("enqueue"):
         tree = kernel(dev_in)  # dispatched async; caller batches the fetch
@@ -718,8 +730,7 @@ def _build_stacked_kernel(
         rmask = jnp.arange(pad_r) < n_r
         for f in rfilters:
             rmask = rmask & compile_expr(f, rcols)
-        lo = jnp.minimum(jnp.searchsorted(rk, lk, side="left"), n_r)
-        hi = jnp.minimum(jnp.searchsorted(rk, lk, side="right"), n_r)
+        lo, hi = probe_ranks(rk, lk, n_r)
         posc = jnp.clip(lo, 0, pad_r - 1)
         if rfilters:
             # e[i] = #right rows passing the filter before position i:
@@ -997,22 +1008,27 @@ def _stacked_join_agg_impl(
             ),
             "join_stacked_agg",
         )
+        note_probe(pad_l, pad_r, B * pad_l)
         METER.record_dispatch()
         with _attr.phase("enqueue"):
             return kernel(lk_d, rk_d, n_l, n_r, lcols_d, rcols_d)
 
     def _est_agg(pads, items):
         # one wave's device footprint: stacked 32-bit uploads (keys +
-        # shipped columns) plus the kernel's per-bucket output vectors
+        # shipped columns), the kernel's per-bucket output vectors, and
+        # the probe's temporaries
         elig = state["elig"]
         if elig is None:
             return 0
         (_gc, agg_specs, left_names, _rg, _rf, right_names) = elig
         pad_l, pad_r = pads
-        return 4 * len(items) * (
-            pad_l * (1 + len(left_names))
-            + pad_r * (1 + len(right_names))
-            + pad_r * (1 + len(agg_specs))
+        return len(items) * (
+            4 * (
+                pad_l * (1 + len(left_names))
+                + pad_r * (1 + len(right_names))
+                + pad_r * (1 + len(agg_specs))
+            )
+            + probe_temp_bytes(pad_l, pad_r)
         )
 
     def _retire_agg(wave):
@@ -1268,10 +1284,7 @@ def _build_plain_probe_kernel():
     jax.jit internally."""
 
     def kernel(lk, rk, n_r):
-        lo = jnp.searchsorted(rk, lk, side="left")
-        hi = jnp.searchsorted(rk, lk, side="right")
-        lo = jnp.minimum(lo, n_r)
-        hi = jnp.minimum(hi, n_r)
+        lo, hi = probe_ranks(rk, lk, n_r)
         return lo, hi - lo
 
     return jax.jit(kernel)  # hslint: HS201 — builder runs via JOIN_CACHE.get_or_build
@@ -1287,8 +1300,7 @@ def _build_stacked_probe_kernel(pad_l: int, pad_r: int):
 
     def body(lk, rk, n_r, n_l):
         idx = jnp.arange(pad_l, dtype=jnp.int32)
-        lo = jnp.minimum(jnp.searchsorted(rk, lk, side="left"), n_r)
-        hi = jnp.minimum(jnp.searchsorted(rk, lk, side="right"), n_r)
+        lo, hi = probe_ranks(rk, lk, n_r)
         cnt = jnp.where(idx < n_l, hi - lo, 0)
         ends = jnp.cumsum(cnt)
         ok = jnp.all(jnp.diff(ends) >= 0) & (ends[-1] >= 0)
@@ -1300,9 +1312,10 @@ def _build_stacked_probe_kernel(pad_l: int, pad_r: int):
 def _build_stacked_expand_kernel(out_pad: int):
     """Per-bucket run expansion vmapped over the bucket axis: pair j of
     bucket i maps to left row li = the run whose [offs[li], offs[li]+cnt)
-    interval contains j (searchsorted side='right' then -1; empty runs share
-    their start offset with the next run, and walking back from a shared
-    boundary lands on the non-empty one for j < total), and right row
+    interval contains j (the right rank of j in the nondecreasing offs,
+    minus 1; empty runs share their start offset with the next run, and
+    walking back from a shared boundary lands on the non-empty one for
+    j < total), and right row
     lo[li] + (j - offs[li]). Emitting (li, ri) directly means the host
     fetches ~2 * pairs int32 instead of 2 * pad_l — readback proportional to
     the JOIN OUTPUT, not the probe domain. out_pad is the max bucket's
@@ -1310,8 +1323,8 @@ def _build_stacked_expand_kernel(out_pad: int):
 
     def body(lo, offs, total):
         j = jnp.arange(out_pad, dtype=jnp.int32)
-        i = jnp.searchsorted(offs, j, side="right").astype(jnp.int32) - 1
-        i = jnp.clip(i, 0, lo.shape[0] - 1)
+        _, i = probe_ranks(offs, j, offs.shape[0])
+        i = jnp.clip(i - 1, 0, lo.shape[0] - 1)
         li = i
         ri = lo[i] + (j - offs[i])
         valid = j < total
@@ -1471,6 +1484,7 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
             lambda: _build_stacked_probe_kernel(pad_l, pad_r),
             "join_stacked_probe",
         )
+        note_probe(pad_l, pad_r, len(items) * pad_l)
         METER.record_dispatch()
         with _attr.phase("enqueue"):
             return kernel(lk_d, rk_d, n_r, n_l)
@@ -1502,16 +1516,19 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
             lambda out_pad=out_pad: _build_stacked_expand_kernel(out_pad),
             "join_expand",
         )
+        note_probe(out_pad, wave.pads[0], len(wave.items) * out_pad)
         METER.record_dispatch()
         with _attr.phase("enqueue"):
             tree = kernel(lo_d, offs_d, jnp.asarray(totals_arr))
         return totals, True, tree
 
     def _est_probe(pads, items):
-        # stacked key uploads + the probe's per-left-slot int32 outputs
+        # stacked key uploads, the probe's per-left-slot int32 outputs, and
+        # its temporaries
         dt = state["dt"]
         isz = dt.itemsize if dt is not None else 4
-        return len(items) * ((pads[0] + pads[1]) * isz + 2 * pads[0] * 4)
+        return len(items) * ((pads[0] + pads[1]) * isz + 2 * pads[0] * 4
+                             + probe_temp_bytes(*pads))
 
     def _retire_probe(wave):
         # the spill fetch for one parked admission: probe totals + run
@@ -1759,6 +1776,7 @@ def _device_plain_join_inner(
     )
     from ..utils.rpc_meter import METER as _METER, device_get as _metered_get
 
+    note_probe(pad_l, pad_r, pad_l)
     _METER.record_dispatch()
     with _attr.phase("enqueue"):
         out = kernel(lk_d, rk_d, jnp.int32(n_r))
@@ -1998,16 +2016,15 @@ def _build_kernel(agg_specs, residual, left_names, right_names, pad_r, dup=False
 
     def kernel(dev_in):
         lk, rk, mask, n_r = dev_in["lk"], dev_in["rk"], dev_in["mask"], dev_in["n_r"]
-        pos = jnp.searchsorted(rk, lk, side="left")
-        posc = jnp.clip(pos, 0, pad_r - 1)
-        found = mask & (posc < n_r) & (rk[posc] == lk)
+        lo, hi = probe_ranks(rk, lk, n_r)
+        posc = jnp.clip(lo, 0, pad_r - 1)
+        found = mask & (hi > lo)
         env = {c: dev_in["l_" + c] for c in left_names}
         env.update({c: dev_in["r_" + c][posc] for c in right_names})
         for r in residual:
             found = found & compile_expr(r, env)
         if dup:
-            hi = jnp.minimum(jnp.searchsorted(rk, lk, side="right"), n_r)
-            w = jnp.where(found, hi - jnp.minimum(pos, n_r), 0).astype(jnp.int32)
+            w = jnp.where(found, hi - lo, 0).astype(jnp.int32)
         else:
             w = found.astype(jnp.int32)
         seg = jnp.where(found, posc, pad_r)

@@ -114,17 +114,38 @@ def test_q6_fused_kernel_compiles(topo, one_chip, mosaic):
     assert "tpu_custom_call" in text
 
 
+# Q3 at SF10: 60M lineitem rows over 8 buckets probe 15M orders keys,
+# one band of 8 buckets in one dispatch
+_SF10_BAND = (8, 1 << 23, 1 << 21)
+
+
 def test_stacked_join_probe_compiles(topo, one_chip):
+    from hyperspace_tpu.ops.join import merge_probe
     from hyperspace_tpu.plan.device_join import _build_stacked_probe_kernel
 
-    # Q3 at SF10: 60M lineitem rows over 8 buckets probe 15M orders keys,
-    # one band of 8 buckets in one dispatch
-    buckets, pad_l, pad_r = 8, 1 << 23, 1 << 21
+    buckets, pad_l, pad_r = _SF10_BAND
+    assert merge_probe(pad_l, pad_r)
     kernel = _build_stacked_probe_kernel(pad_l, pad_r)
     mat = lambda pad: jax.ShapeDtypeStruct((buckets, pad), jnp.int32, sharding=one_chip)
     vec = jax.ShapeDtypeStruct((buckets,), jnp.int32, sharding=one_chip)
     compiled = kernel.lower(mat(pad_l), mat(pad_r), vec, vec).compile()
     assert compiled.memory_analysis() is not None
+    assert " sort(" in compiled.as_text()  # the merge form
+
+
+def test_stacked_join_expand_compiles(topo, one_chip):
+    from hyperspace_tpu.ops.join import merge_probe
+    from hyperspace_tpu.plan.device_join import _build_stacked_expand_kernel
+
+    # the band's run expansion at one pair per left row
+    buckets, pad_l, _ = _SF10_BAND
+    assert merge_probe(pad_l, pad_l)
+    kernel = _build_stacked_expand_kernel(pad_l)
+    mat = jax.ShapeDtypeStruct((buckets, pad_l), jnp.int32, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((buckets,), jnp.int32, sharding=one_chip)
+    compiled = kernel.lower(mat, mat, vec).compile()
+    assert " sort(" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
 
 
 def test_bucket_exchange_compiles_on_four_chips(topo):

@@ -123,10 +123,13 @@ class TestStreamedBandedBitIdentity:
         session, tmp_path = skew_env
         pairs0 = REGISTRY.counter("pipeline.join.pairs").value
         bands0 = REGISTRY.counter("pipeline.join.bands").value
+        merge0 = REGISTRY.counter("join.probe.merge").value
         serial, pipelined = _run_modes(session, tmp_path, _plain_q, monkeypatch)
         assert hex_rows(pipelined) == hex_rows(serial)
         assert REGISTRY.counter("pipeline.join.pairs").value > pairs0
         assert REGISTRY.counter("pipeline.join.bands").value > bands0
+        # comparable pads: every band probed in the merge form
+        assert REGISTRY.counter("join.probe.merge").value > merge0
 
     def test_plain_join_split_buckets(self, skew_env, monkeypatch):
         session, tmp_path = skew_env
@@ -342,6 +345,46 @@ class TestWorkerHelper:
     def test_io_reader_delegates(self, monkeypatch):
         monkeypatch.setenv("HYPERSPACE_IO_THREADS", "5")
         assert cio.io_threads() == 5
+
+
+def _hlo_ops(kernel, *shapes) -> str:
+    """CPU-compiled HLO text of a kernel at int32 argument shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    args = [jax.ShapeDtypeStruct(s, jnp.int32) for s in shapes]
+    return kernel.lower(*args).compile().as_text()
+
+
+class TestProbeForm:
+    """The band kernels probe by sort-merge at comparable pads and keep
+    the binary search only for a small left side into a large right one:
+    a silent return to per-row search loops at Q3's pads fails here."""
+
+    @pytest.mark.parametrize("kernel", ["probe", "expand"])
+    def test_q3_pads_merge_without_loops(self, kernel):
+        from hyperspace_tpu.plan import device_join as DJ
+
+        b, pad_l, pad_r = 8, 1 << 18, 1 << 17
+        if kernel == "probe":
+            text = _hlo_ops(DJ._build_stacked_probe_kernel(pad_l, pad_r),
+                            (b, pad_l), (b, pad_r), (b,), (b,))
+        else:
+            text = _hlo_ops(DJ._build_stacked_expand_kernel(pad_l),
+                            (b, pad_l), (b, pad_l), (b,))
+        assert " while(" not in text
+        assert " sort(" in text
+
+    def test_small_left_large_right_keeps_search(self):
+        from hyperspace_tpu.ops.join import merge_probe
+        from hyperspace_tpu.plan import device_join as DJ
+
+        b, pad_l, pad_r = 8, 1 << 10, 1 << 20
+        assert not merge_probe(pad_l, pad_r)
+        text = _hlo_ops(DJ._build_stacked_probe_kernel(pad_l, pad_r),
+                        (b, pad_l), (b, pad_r), (b,), (b,))
+        assert " while(" in text
+        assert " sort(" not in text
 
 
 class TestSerialModeStreams:

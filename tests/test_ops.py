@@ -184,12 +184,116 @@ class TestZOrder:
             Z.interleave_bits([(np.zeros(1, np.uint64), 40), (np.zeros(1, np.uint64), 40)])
 
 
+_I32 = np.iinfo(np.int32)
+
+
+def _probe_case(name):
+    """(right keys padded as the kernels pad them, real right rows, left
+    keys) of one probe_ranks case."""
+    i32 = lambda *a: np.array(a, np.int32)  # noqa: E731
+    rng = np.random.default_rng(7)
+    if name == "merge_match_counts":
+        rk, n_r, lk = i32(2, 2, 3, 5, 5, 5), 6, i32(1, 2, 2, 5)
+    elif name == "sorted_zero_tail":
+        rk, n_r = i32(-3, 0, 0, 4, 9), 5
+        lk = i32(-5, -3, 0, 4, 4, 8, 9, 12, 0, 0, 0)
+    elif name == "unsorted_zero_tail":
+        rk, n_r = np.sort(rng.integers(-20, 20, 40)).astype(np.int32), 40
+        lk = np.concatenate([rng.integers(-25, 25, 50), np.zeros(14)]).astype(np.int32)
+    elif name == "disjoint":
+        rk, n_r = np.arange(100, 140, dtype=np.int32), 40
+        lk = np.concatenate([np.arange(0, 20), np.arange(200, 220)]).astype(np.int32)
+    elif name == "int32_extremes":
+        # real keys at both ends of int32 beside the dtype-max pads, which
+        # the clamp to n_r keeps out of every range
+        rk, n_r = i32(_I32.min, _I32.min, 0, _I32.max - 1), 4
+        lk = i32(_I32.max, _I32.max - 1, _I32.min, 0, _I32.max - 1, _I32.min)
+    elif name == "float_signed_zero":
+        rk = np.array([-1.5, -0.0, 0.0, 0.0, 2.0], np.float32)
+        n_r = 5
+        lk = np.array([0.0, -0.0, 2.0, -1.5, np.inf, -np.inf, 1.0], np.float32)
+    elif name == "single_left":
+        rk, n_r, lk = i32(1, 3, 3, 3, 8), 5, i32(3)
+    elif name == "single_right":
+        rk, n_r, lk = i32(3), 1, i32(0, 3, 3, 7, 3)
+    elif name == "mixed_widths":  # int16 left keys into int32 right keys
+        rk, n_r = i32(-40000, -7, -7, 0, 300, 70000), 6
+        lk = np.array([-7, 0, 299, 300, 32767, -32768, 0], np.int16)
+    else:  # random: duplicates on both sides
+        rk = np.sort(rng.integers(0, 60, 300)).astype(np.int32)
+        n_r, lk = 300, rng.integers(-5, 65, 257).astype(np.int32)
+    pad = np.inf if rk.dtype.kind == "f" else _I32.max
+    rk = np.concatenate([rk, np.full(3, pad, rk.dtype)])
+    return rk, n_r, lk
+
+
+def _expected_ranks(rk, n_r, lk):
+    return (np.minimum(np.searchsorted(rk, lk, "left"), n_r),
+            np.minimum(np.searchsorted(rk, lk, "right"), n_r))
+
+
+@pytest.fixture(params=["merge", "search"])
+def probe_form(request, monkeypatch):
+    """Force probe_ranks' form: every pad ratio merges, or none does."""
+    monkeypatch.setattr(J, "_SEARCH_RATIO", 1 << 40 if request.param == "merge" else 0)
+    return request.param
+
+
 class TestJoinPrims:
-    def test_merge_match_counts(self):
-        left = jnp.asarray(np.array([1, 2, 2, 5], dtype=np.int32))
-        right = jnp.asarray(np.array([2, 2, 3, 5, 5, 5], dtype=np.int32))
-        lo, counts = J.merge_match_counts(left, right)
-        assert np.asarray(counts).tolist() == [0, 2, 2, 3]
+    @pytest.mark.parametrize("vmapped", [False, True], ids=["flat", "vmap"])
+    @pytest.mark.parametrize(
+        "case",
+        ["merge_match_counts", "sorted_zero_tail", "unsorted_zero_tail",
+         "disjoint", "int32_extremes", "float_signed_zero", "single_left",
+         "single_right", "mixed_widths", "random"],
+    )
+    def test_probe_ranks_matches_searchsorted(self, probe_form, case, vmapped):
+        rk, n_r, lk = _probe_case(case)
+        assert J.merge_probe(len(lk), len(rk)) == (probe_form == "merge")
+        if not vmapped:
+            lo, hi = jax.jit(J.probe_ranks)(jnp.asarray(rk), jnp.asarray(lk), n_r)
+            exp_lo, exp_hi = _expected_ranks(rk, n_r, lk)
+            assert np.asarray(lo).tolist() == exp_lo.tolist()
+            assert np.asarray(hi).tolist() == exp_hi.tolist()
+            return
+        # two rows: the case, and its left keys reversed against fewer
+        # real right rows
+        lks = np.stack([lk, lk[::-1]])
+        rks = np.stack([rk, rk])
+        n_rs = np.array([n_r, max(n_r - 1, 0)], np.int32)
+        lo, hi = jax.jit(jax.vmap(J.probe_ranks))(
+            jnp.asarray(rks), jnp.asarray(lks), jnp.asarray(n_rs)
+        )
+        for b in range(2):
+            exp_lo, exp_hi = _expected_ranks(rks[b], n_rs[b], lks[b])
+            assert np.asarray(lo[b]).tolist() == exp_lo.tolist()
+            assert np.asarray(hi[b]).tolist() == exp_hi.tolist()
+
+    def test_expand_kernel_matches_expand_runs(self, probe_form):
+        from hyperspace_tpu.plan.device_join import _build_stacked_expand_kernel
+
+        rng = np.random.default_rng(3)
+        pad_l, out_pad = 64, 256
+        starts = np.zeros((2, pad_l), np.int64)
+        counts = np.zeros((2, pad_l), np.int64)
+        for b, n_l in enumerate((50, 9)):
+            # empty runs among the real rows, and none past n_l
+            counts[b, :n_l] = rng.integers(0, 6, n_l) * (rng.random(n_l) < 0.7)
+            starts[b, :n_l] = rng.integers(0, 40, n_l)
+        offs = np.cumsum(counts, axis=1) - counts
+        totals = counts.sum(axis=1)
+        assert totals.max() <= out_pad
+        li, ri = _build_stacked_expand_kernel(out_pad)(
+            jnp.asarray(starts, jnp.int32), jnp.asarray(offs, jnp.int32),
+            jnp.asarray(totals, jnp.int32),
+        )
+        for b in range(2):
+            t = int(totals[b])
+            assert np.asarray(ri[b, :t]).tolist() == J.expand_runs(
+                starts[b], counts[b]).tolist()
+            assert np.asarray(li[b, :t]).tolist() == np.repeat(
+                np.arange(pad_l), counts[b]).tolist()
+            assert not np.asarray(li[b, t:]).any() and not np.asarray(ri[b, t:]).any()
 
     def test_segment_sum_by_sorted_key(self):
         keys = jnp.asarray(np.array([1, 1, 2, 2, 2, 7], dtype=np.int32))
