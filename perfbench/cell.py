@@ -5,12 +5,17 @@
 - ``perfbench/traffic/<traffic>.json`` is the traffic mix;
 - ``perfbench/cells/<workload>.json`` holds the limits of the numbers that
   decide ``correct``;
-- ``perfbench/queries/<query>.py`` is one query shape: parameters, the
-  program's DataFrame, the plain reference, the comparison and the bytes
-  it must read;
-- ``perfbench/metrics/<metric>.py`` reads one per-layer metric; a metric
-  split by cell (``plan_ms.join``, ``plan_ms.scan_agg``) is read by the file
-  of its base name, the part before the first dot.
+- ``perfbench/queries/<query>.py`` is one query shape: ``draw`` and
+  ``in_range`` (its parameters, from and against the traffic file's
+  ``ranges`` and the configuration's domain), ``dataframe`` (the program's
+  query), ``reference`` and ``compare`` (the plain answer and the
+  comparison) and ``must_read_bytes``;
+- ``perfbench/metrics/<metric>.py`` reads one per-layer metric from what
+  ``perfbench/run.py``'s ``Measured`` gives it (the window's deltas of the
+  program's histograms and counters, its RPC meter, the trace's
+  reduction); a metric split by cell (``plan_ms.join``,
+  ``plan_ms.scan_agg``) is read by the file of its base name, the part
+  before the first dot.
 
 A later PR adds a cell, a mix, a query or a metric by adding files and
 entries; nothing here changes.

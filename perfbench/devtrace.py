@@ -11,8 +11,10 @@ what the host was doing in each.
 - The window is the host span named ``WINDOW`` that the benchmark opens
   around its measured window.
 - An idle gap is a stretch of the window in which a device runs no
-  operation. It is named after the benchmark's host span (``bench:*``)
-  open at its midpoint that started last, or ``none``.
+  operation. It is named after the program's innermost annotation
+  (``hs:*``, the one that started last) open at its midpoint; where none
+  is open, after the benchmark's host span (``bench:*``) that started
+  last, or ``none``. A name keeps no metadata (``#query_id=..#``).
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ import os
 
 WINDOW = "bench:window"
 SPAN_PREFIX = "bench:"
+PROGRAM_PREFIX = "hs:"
 OPS_LINES = ("XLA Ops", "Async XLA Ops")
 TOP = 10
 
@@ -66,9 +69,10 @@ def reduce(profile) -> dict | None:
                         for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans.extend((e.start_ns, e.end_ns, e.name)
+                spans.extend((e.start_ns, e.end_ns, e.name.split("#", 1)[0])
                              for e in line.events
-                             if e.name.startswith(SPAN_PREFIX))
+                             if e.name.startswith((SPAN_PREFIX,
+                                                   PROGRAM_PREFIX)))
     windows = [(s, e) for s, e, n in spans if n == WINDOW]
     if not windows:
         return None
@@ -93,8 +97,12 @@ def reduce(profile) -> dict | None:
     inner = [(s, e, n) for s, e, n in spans if n != WINDOW]
 
     def host_at(t: float) -> str:
-        open_ = [(s, n) for s, e, n in inner if s <= t < e]
-        return max(open_)[1] if open_ else "none"
+        for prefix in (PROGRAM_PREFIX, SPAN_PREFIX):
+            open_ = [(s, n) for s, e, n in inner
+                     if s <= t < e and n.startswith(prefix)]
+            if open_:
+                return max(open_)[1]
+        return "none"
 
     gaps.sort(key=lambda g: g[1] - g[0], reverse=True)
     return {

@@ -26,6 +26,10 @@ def draw(rng, ranges, domain) -> dict:
                                       ranges["delta"][1] + 1))}
 
 
+def in_range(p, ranges, domain) -> bool:
+    return ranges["delta"][0] <= p["delta"] <= ranges["delta"][1]
+
+
 def _date(p):
     return days(1998, 12, 1) - p["delta"]
 

@@ -25,6 +25,11 @@ def draw(rng, ranges, domain) -> dict:
             "container": int(rng.integers(0, len(domain["containers"])))}
 
 
+def in_range(p, ranges, domain) -> bool:
+    return (0 <= p["brand"] < len(domain["brands"])
+            and 0 <= p["container"] < len(domain["containers"]))
+
+
 def dataframe(session, root, p):
     from hyperspace_tpu.plan import Avg, Sum, col, lit
 

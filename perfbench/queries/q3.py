@@ -28,6 +28,11 @@ def draw(rng, ranges, domain) -> dict:
             "day": int(rng.integers(ranges["day"][0], ranges["day"][1] + 1))}
 
 
+def in_range(p, ranges, domain) -> bool:
+    return (ranges["day"][0] <= p["day"] <= ranges["day"][1]
+            and 0 <= p["segment"] < len(domain["segments"]))
+
+
 def _date(p):
     return days(1995, 3, p["day"])
 

@@ -27,6 +27,11 @@ def draw(rng, ranges, domain) -> dict:
     }
 
 
+def in_range(p, ranges, domain) -> bool:
+    return all(ranges[k][0] <= p[k] <= ranges[k][1]
+               for k in ("year", "cents", "quantity"))
+
+
 def _bounds(p):
     # (c -+ 1) / 100 is the very float64 the generator's round(x, 2) makes
     return (days(p["year"], 1, 1), days(p["year"] + 1, 1, 1),

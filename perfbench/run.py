@@ -223,12 +223,12 @@ def warm_up(sched, cell, session, ws, pool) -> list:
 
 class Measured:
     """What a per-layer reader reads: the window's query count, deltas of
-    the program's histograms and RPC meter, the trace's reduction, the
-    bytes the queries must read and the chip's peaks."""
+    the program's histograms, counters and RPC meter, the trace's
+    reduction, the bytes the queries must read and the chip's peaks."""
 
-    def __init__(self, n, hists0, hists1, meter, trace, must_read, kind):
+    def __init__(self, n, totals0, totals1, meter, trace, must_read, kind):
         self.n = n
-        self._h0, self._h1 = hists0, hists1
+        self._t0, self._t1 = totals0, totals1
         self.meter = meter
         self.trace = trace
         self.must_read_bytes = must_read
@@ -238,15 +238,29 @@ class Measured:
     def peaks(self) -> dict:
         return cells.peaks(self._kind)
 
+    def _delta(self, kind: str, name: str) -> float:
+        return float(self._t1[kind].get(name, 0.0)
+                     - self._t0[kind].get(name, 0.0))
+
     def hist_sum(self, name: str) -> float:
-        return (self._h1.get(name, 0.0) - self._h0.get(name, 0.0))
+        """The window's sum of a histogram; 0 for one never registered."""
+        return self._delta("histogram", name)
+
+    def counter(self, name: str) -> float:
+        """The window's increase of a counter; 0 for one never registered."""
+        return self._delta("counter", name)
 
 
-def _hist_sums() -> dict:
+def _totals() -> dict:
+    """``{"histogram": {name: sum}, "counter": {name: value}}`` of the
+    program's metrics registry, taken on each side of the window."""
     from hyperspace_tpu.telemetry.metrics import REGISTRY
 
-    return {name: v["sum"] for name, kind, v in REGISTRY.export()
-            if kind == "histogram"}
+    out: dict = {"histogram": {}, "counter": {}}
+    for name, kind, v in REGISTRY.export():
+        if kind in out:
+            out[kind][name] = v["sum"] if kind == "histogram" else v
+    return out
 
 
 def memory_peak(devices) -> int | None:
@@ -286,7 +300,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
             gc.collect()
             setup_s = time.perf_counter() - _T0
             degrades0 = REGISTRY.counter("device.degrades").value
-            hists0, meter0, c0 = _hist_sums(), METER.snapshot(), compiles.n
+            totals0, meter0, c0 = _totals(), METER.snapshot(), compiles.n
             g0 = len(gc_pauses.pauses)
             trace_dir = os.path.join(tmp, "trace")
             if trace:
@@ -303,7 +317,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
             in_window = compiles.n - c0
             gcs = gc_pauses.pauses[g0:]
             meter = METER.delta_since(meter0)
-            hists1 = _hist_sums()
+            totals1 = _totals()
             degrades = REGISTRY.counter("device.degrades").value - degrades0
         finally:
             sched.shutdown()
@@ -328,8 +342,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
     if trace:
         must_read = sum(cell.queries[a.query].must_read_bytes(data, a.params)
                         for a in ok)
-        m = Measured(len(answers), hists0, hists1, meter, reduced, must_read,
-                     dev.device_kind)
+        m = Measured(len(answers), totals0, totals1, meter, reduced,
+                     must_read, dev.device_kind)
         for metric in cell.per_layer:
             v = cell.reader(metric["name"])(m)
             if v is not None:
@@ -364,9 +378,11 @@ def run(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
         mine = sorted(a.latency_s * 1000.0 for a in answers
                       if a.query == query)
         if mine:
+            med = statistics.median(mine)
+            stalls = sum(1 for x in mine if x > 3 * med)
             print(f"perfbench: {query}: {len(mine)} queries, median "
-                  f"{statistics.median(mine):.3f} ms, max {mine[-1]:.3f} ms",
-                  file=sys.stderr)
+                  f"{med:.3f} ms, max {mine[-1]:.3f} ms, {stalls} over 3x "
+                  f"the median", file=sys.stderr)
     out["checks"] = checks
     return out
 

@@ -87,6 +87,29 @@ def test_the_result_line_keeps_its_schema(tiny_root, workload, trace):
     json.dumps(out)
 
 
+def test_a_metric_file_reads_program_counters_of_the_window(tiny_root,
+                                                            tmp_path):
+    """A per-layer metric added by files alone reads the program's counters
+    through ``Measured.counter``: the window hits the kernel cache, and
+    builds no kernel that set-up did not."""
+    root = tmp_path / "checkout"
+    shutil.copytree(tiny_root, root)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for name, counter in (("kernel_hits", "cache.kernel.hits"),
+                          ("kernel_builds", "kernel.retrace")):
+        (root / "perfbench" / "metrics" / f"{name}.py").write_text(
+            f"def read(run):\n    return run.counter({counter!r})\n")
+        bench["per_layer"].append({
+            "name": f"{name}.scan_agg", "unit": "kernels", "better": "lower",
+            "source": "program_counter", "layer": "kernels",
+            "moves": "p50_ms.scan_agg", "workloads": ["sf1_scan_agg"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    out = _run(str(root), "sf1_scan_agg", trace=True)
+    assert out["correct"] is True, out["checks"]
+    assert out["metrics"]["kernel_hits.scan_agg"]["value"] > 0
+    assert out["metrics"]["kernel_builds.scan_agg"]["value"] == 0
+
+
 def _stale(monkeypatch):
     """The scheduler hands every query the previous query's answer."""
     from hyperspace_tpu.serve.scheduler import QueryHandle

@@ -26,24 +26,9 @@ def _cell(name):
     return cells.Cell(name)
 
 
-def _in_range(query, p, domain):
-    if query == "q6":
-        return (1993 <= p["year"] <= 1997 and 2 <= p["cents"] <= 9
-                and 24 <= p["quantity"] <= 25)
-    if query == "q1":
-        return 60 <= p["delta"] <= 120
-    if query == "q3":
-        return 1 <= p["day"] <= 31 and 0 <= p["segment"] < 5
-    if query == "q17":
-        return 0 <= p["brand"] < 25 and 0 <= p["container"] < 40
-    raise AssertionError(query)
-
-
-@pytest.mark.parametrize("seed", [0, 2**31 + 17, 3 * 2**32 + 5])
-@pytest.mark.parametrize("workload", WORKLOADS)
-def test_pools_are_drawn_from_the_seed_distinct_and_within_the_spec(
-        workload, seed):
-    c = _cell(workload)
+def check_pools(c, seed):
+    """The run's parameter sets: fixed by the seed, distinct, as many as
+    the traffic file asks, and each within its query's ranges."""
     dom = tpch_data.domain(c.config)
     a = traffic.pools(c.traffic, c.queries, dom, seed)
     assert a == traffic.pools(c.traffic, c.queries, dom, seed)
@@ -51,14 +36,36 @@ def test_pools_are_drawn_from_the_seed_distinct_and_within_the_spec(
     for q, pool in a.items():
         assert len(pool) == c.traffic["distinct"][q]
         assert len({json.dumps(p, sort_keys=True) for p in pool}) == len(pool)
-        assert all(_in_range(q, p, dom) for p in pool)
+        ranges = c.traffic["ranges"].get(q, {})
+        assert all(c.queries[q].in_range(p, ranges, dom) for p in pool), q
 
 
-@pytest.mark.parametrize("seed", [0, 7, 2**31 + 17, 3 * 2**32 + 5, -9])
+@pytest.mark.parametrize("seed", [0, 2**31 + 17, 3 * 2**32 + 5])
 @pytest.mark.parametrize("workload", WORKLOADS)
-def test_client_sequences_are_seeded_and_give_every_seed_the_same_work(
+def test_pools_are_drawn_from_the_seed_distinct_and_within_the_spec(
         workload, seed):
-    c = _cell(workload)
+    check_pools(_cell(workload), seed)
+
+
+def test_a_query_judges_its_own_ranges():
+    c = _cell("sf1_scan_agg")
+    dom = tpch_data.domain(c.config)
+    q6, q1 = c.queries["q6"], c.queries["q1"]
+    ranges = c.traffic["ranges"]
+    assert q6.in_range({"year": 1997, "cents": 2, "quantity": 25},
+                       ranges["q6"], dom)
+    assert not q6.in_range({"year": 1998, "cents": 2, "quantity": 25},
+                           ranges["q6"], dom)
+    assert not q1.in_range({"delta": 121}, ranges["q1"], dom)
+    assert q1.in_range({"delta": 121}, {"delta": [60, 121]}, dom)
+    q3, q17 = (_cell("sf1_join").queries[q] for q in ("q3", "q17"))
+    assert not q3.in_range({"day": 10, "segment": 5}, {"day": [1, 31]}, dom)
+    assert not q17.in_range({"brand": 0, "container": 40}, {}, dom)
+
+
+def check_sequences(c, seed):
+    """Each client's sequence: fixed by the seed and the client, and made
+    of whole blocks that keep the weights and every drawn set."""
     pool = traffic.pools(c.traffic, c.queries, tpch_data.domain(c.config),
                          seed)
     block = traffic.block(c.traffic, pool)
@@ -82,6 +89,13 @@ def test_client_sequences_are_seeded_and_give_every_seed_the_same_work(
     assert first != take(seed, 1, 3 * len(block))
     for i in range(0, len(first), len(block)):
         assert key(first[i:i + len(block)]) == key(block)
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31 + 17, 3 * 2**32 + 5, -9])
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_client_sequences_are_seeded_and_give_every_seed_the_same_work(
+        workload, seed):
+    check_sequences(_cell(workload), seed)
 
 
 def test_compare_rows_treats_nulls_and_nan_as_wrong():
@@ -254,6 +268,24 @@ def test_trace_reduction_unions_ops_and_names_gaps_by_host_span():
     assert sum(g[1] for g in r["idle_gaps"]) == pytest.approx(700e-9)
 
 
+def test_idle_gaps_are_named_after_the_programs_innermost_annotation():
+    """Inside the program's ``hs:`` annotations a gap takes the one that
+    started last (its metadata dropped); outside them the benchmark's
+    ``bench:`` span."""
+    prof = _xspace(
+        ops=[("fusion.1", 100, 100), ("fusion.2", 400, 100),
+             ("fusion.3", 700, 100)],
+        spans=[("bench:window", 0, 1000), ("bench:wait", 0, 1000),
+               ("hs:exec:Aggregate#query_id=7#", 180, 240),
+               ("hs:pipeline:concat#query_id=7#", 220, 160),
+               ("hs:phase:io", 500, 190)])
+    r = devtrace.reduce(prof)
+    assert r["idle_gaps"] == [["hs:pipeline:concat", pytest.approx(200e-9)],
+                              ["hs:phase:io", pytest.approx(200e-9)],
+                              ["bench:wait", pytest.approx(200e-9)],
+                              ["bench:wait", pytest.approx(100e-9)]]
+
+
 def test_trace_reduction_counts_async_device_ops_as_busy():
     prof = _xspace(ops=[("fusion.1", 100, 200)],
                    spans=[("bench:window", 50, 1000)],
@@ -337,6 +369,58 @@ def test_every_cell_finds_its_files_and_reports_enough(workload):
         assert callable(c.reader(m["name"]))
 
 
+# A query shape none of the cells has, as a later PR adds it: an
+# order-detail lookup on l_orderkey
+POINT_QUERY = '''"""The lines of one order by l_orderkey."""
+
+import os
+
+import numpy as np
+
+from perfbench.answers import BYTES_PER_VALUE, compare_rows
+from perfbench.tpch_data import order_index
+
+TABLES = {"lineitem": ("l_orderkey", "l_quantity", "l_extendedprice")}
+FLOATS = ("l_extendedprice",)
+
+
+def _key(row):
+    return (row // 8) * 32 + row % 8 + 1
+
+
+def draw(rng, ranges, domain):
+    return {"orderkey": int(_key(int(rng.integers(0, domain["orders"]))))}
+
+
+def in_range(p, ranges, domain):
+    row = int(order_index(p["orderkey"]))
+    return 0 <= row < domain["orders"] and _key(row) == p["orderkey"]
+
+
+def dataframe(session, root, p):
+    from hyperspace_tpu.plan import col
+
+    return (session.read.parquet(os.path.join(root, "lineitem"))
+            .filter(col("l_orderkey") == p["orderkey"])
+            .select(*TABLES["lineitem"]))
+
+
+def reference(data, p):
+    li = data["lineitem"]
+    m = li["l_orderkey"] == p["orderkey"]
+    return {c: li[c][m].tolist() for c in TABLES["lineitem"]}
+
+
+def compare(got, want):
+    return compare_rows(got, want, FLOATS)
+
+
+def must_read_bytes(data, p):
+    lines = np.count_nonzero(data["lineitem"]["l_orderkey"] == p["orderkey"])
+    return int(lines) * len(TABLES["lineitem"]) * BYTES_PER_VALUE
+'''
+
+
 def test_adding_files_adds_a_cell_with_no_edit(tmp_path):
     """A new configuration, traffic mix, query and metric are new files and
     new entries in BENCHMARK.json; the harness finds them by name."""
@@ -353,9 +437,10 @@ def test_adding_files_adds_a_cell_with_no_edit(tmp_path):
                              "reduced": [], "why": "x"})
     (pb / "queries" / "q6_copy.py").write_text(
         (pb / "queries" / "q6.py").read_text())
+    (pb / "queries" / "point.py").write_text(POINT_QUERY)
     (pb / "traffic" / "copy.json").write_text(json.dumps({
-        "clients": 2, "mix": {"q6_copy": 1, "q1": 2},
-        "distinct": {"q6_copy": 2, "q1": 2},
+        "clients": 2, "mix": {"q6_copy": 1, "q1": 2, "point": 3},
+        "distinct": {"q6_copy": 2, "q1": 2, "point": 6},
         "ranges": json.load(open(pb / "traffic" / "scan_agg.json"))[
             "ranges"] | {"q6_copy": {"year": [1993, 1997], "cents": [2, 9],
                                      "quantity": [24, 25]}}}))
@@ -375,7 +460,7 @@ def test_adding_files_adds_a_cell_with_no_edit(tmp_path):
                                "moves": "qps.copy", "workloads": ["tiny_copy"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     c = cells.Cell("tiny_copy", root=str(root))
-    assert sorted(c.queries) == ["q1", "q6_copy"]
+    assert sorted(c.queries) == ["point", "q1", "q6_copy"]
     assert c.config["scale_factor"] == 0.01
     assert [m["name"] for m in c.per_layer] == ["twice_plan.copy"]
     assert [m["name"] for m in c.end_to_end] == ["setup_s", "qps.copy"]
@@ -386,15 +471,19 @@ def test_adding_files_adds_a_cell_with_no_edit(tmp_path):
     assert c.reader("twice_plan.copy")(M) == 42
     assert c.tables()["lineitem"]
     pool = traffic.pools(c.traffic, c.queries, tpch_data.domain(c.config), 4)
-    assert len(pool["q6_copy"]) == 2
+    assert len(pool["q6_copy"]) == 2 and len(pool["point"]) == 6
+    # the checks every cell of BENCHMARK.json gets hold for the new query
+    # shape with no edit to the harness or to them
+    for seed in (0, 2**31 + 17):
+        check_pools(c, seed)
+        check_sequences(c, seed)
+    check_control(c)
 
 
-@pytest.mark.parametrize("workload", WORKLOADS)
-def test_the_bf16_control_fails_the_cell_limit(workload, tmp_path):
+def check_control(c):
     """The reference in the program's place, its float inputs rounded to
     bfloat16, must read above the cell's limit; the reference against
     itself reads nothing."""
-    c = _cell(workload)
     cfg = dict(c.config, scale_factor=0.02, orders_per_file=15_000)
     c.config = cfg
     data = tpch_data.generate(None, cfg, 11, keep=c.tables())
@@ -408,3 +497,22 @@ def test_the_bf16_control_fails_the_cell_limit(workload, tmp_path):
     for q, p in requests:
         assert c.queries[q].compare(check.public(ref(q, p)), ref(q, p)) == (
             0, 0.0)
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_the_bf16_control_fails_the_cell_limit(workload):
+    check_control(_cell(workload))
+
+
+def test_a_counter_reads_its_window_delta_and_0_when_never_registered():
+    from perfbench.run import Measured
+
+    before = {"histogram": {"h": 2.0}, "counter": {"cache.kernel.hits": 5}}
+    after = {"histogram": {"h": 9.5},
+             "counter": {"cache.kernel.hits": 12, "kernel.retrace": 3}}
+    m = Measured(4, before, after, {}, None, 0, "TPU v5 lite")
+    assert m.counter("cache.kernel.hits") == 7.0
+    assert m.counter("kernel.retrace") == 3.0  # registered in the window
+    assert m.counter("never.registered") == 0.0
+    assert m.counter("h") == 0.0  # a histogram is no counter
+    assert m.hist_sum("h") == 7.5
