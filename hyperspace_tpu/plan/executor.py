@@ -90,12 +90,15 @@ def execute_plan(plan: LogicalPlan, session=None) -> ColumnBatch:
 def _execute_node(plan: LogicalPlan, session=None) -> ColumnBatch:
     if (
         session is not None
-        and isinstance(plan, Aggregate)
+        and isinstance(plan, (Aggregate, Filter))
         and session.conf.exec_tpu_enabled
     ):
-        from .tpu_exec import try_execute_tpu
+        from .tpu_exec import try_execute_select, try_execute_tpu
 
-        result = try_execute_tpu(plan, session)
+        if isinstance(plan, Aggregate):
+            result = try_execute_tpu(plan, session)
+        else:
+            result = try_execute_select(plan, session)
         if result is not None:
             return result
     if isinstance(plan, InMemoryScan):

@@ -9,6 +9,12 @@ cache per kernel family, keyed by a canonical plan fingerprint:
      exprs, dtype signature of the device inputs, shape constants baked
      into the kernel body)
 
+The scan kernels (fused, grouped, selection) take their literals as
+arguments: the expressions they are keyed on carry a typed slot in each
+literal's place (``plan/tpu_exec.slot_literals``), so the key holds the
+predicate's shape — literal types and ``In`` list lengths — and never its
+values. A new order key, year or dictionary code reuses the kernel.
+
 so a repeated query template (the TPC-H bench loop, a dashboard refresh)
 skips retrace entirely — across queries, sessions, and both the monolithic
 and the pipelined streaming executors (which share fingerprints by
@@ -200,6 +206,12 @@ def grouped_fingerprint(pallas_route: bool, seg_pad: int, pred_expr,
         tuple((k, repr(c)) for k, c in agg_list),
         dtype_signature(dev_cols),
     )
+
+
+def select_fingerprint(cap: int, pred_expr, dev_cols: dict) -> tuple:
+    """Selection kernel (row-returning scan): the position cap is baked
+    into the body."""
+    return ("select", cap, repr(pred_expr), dtype_signature(dev_cols))
 
 
 def mesh_fingerprint(d: int, topology: tuple, seg_pad: int, pred_expr,

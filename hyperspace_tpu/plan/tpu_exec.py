@@ -11,9 +11,11 @@ Static-shape contract: columns are padded to the next power-of-two chunk and
 masked, so one compiled kernel serves any file/row count of the same size
 class (no recompiles per file).
 
-Supported fragment today — the filter-aggregate pipeline:
+Supported fragments — the filter-aggregate pipeline and the selection:
     Aggregate(no groups | grouped) ← [Project] ← [Filter] ← FileScan
+    Filter ← index FileScan  (rows out: positions on the device, rows on the host)
 with numeric/date columns. Anything else falls back to the host executor.
+Literals are kernel arguments (``slot_literals``): one kernel per shape.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ from .kernel_cache import (
     fused_fingerprint,
     grouped_fingerprint,
     mesh_fingerprint,
+    select_fingerprint,
 )
 from .nodes import Aggregate, FileScan, Filter, LogicalPlan, Project
 from ..columnar.table import Column, ColumnBatch, STRING
@@ -73,10 +76,10 @@ class Wide64:
         self.hi = hi  # int32 (signed high word)
         self.lo_u = lo_u  # uint32 view of the low word
 
-    def compare(self, kind, value: int):
-        v64 = np.int64(value)
-        l_hi = jnp.int32(np.int32(v64 >> np.int64(32)))  # signed high word
-        l_lo = jnp.uint32(np.uint64(v64) & np.uint64(0xFFFFFFFF))
+    def compare(self, kind, words):
+        """Against an int64 literal given as its (hi int32, lo uint32)
+        words (a ``w`` slot)."""
+        l_hi, l_lo = words
         hi_eq = self.hi == l_hi
         if kind is X.Eq:
             return hi_eq & (self.lo_u == l_lo)
@@ -93,9 +96,21 @@ class Wide64:
         raise HyperspaceError(f"Wide64 comparison unsupported: {kind}")
 
 
-def _wide_compare(e: Expr, cols):
-    """Two-word compare when one side is a Wide64 column and the other an
-    int literal; None when the pattern does not apply."""
+def _int64_words(value) -> tuple:
+    """(signed high int32, low uint32) words of an int64 value."""
+    v64 = np.int64(value)
+    return (np.int32(v64 >> np.int64(32)),
+            np.uint32(np.uint64(v64) & np.uint64(0xFFFFFFFF)))
+
+
+def _is_int_value(v) -> bool:
+    return isinstance(v, (int, np.integer)) and not isinstance(v, (bool, np.bool_))
+
+
+def _wide_compare(e: Expr, cols, lits=()):
+    """Two-word compare when one side is a Wide64 column and the other the
+    words of an int literal (a ``w`` slot); None when the pattern does not
+    apply."""
     flipped = {X.Lt: X.Gt, X.Le: X.Ge, X.Gt: X.Lt, X.Ge: X.Le, X.Eq: X.Eq, X.Ne: X.Ne}
     for a, b, kind in (
         (e.left, e.right, type(e)),
@@ -105,18 +120,20 @@ def _wide_compare(e: Expr, cols):
             isinstance(a, X.Col)
             and isinstance(cols.get(a.name), Wide64)
             and isinstance(b, X.Lit)
-            and isinstance(b.value, (int, np.integer))
-            and not isinstance(b.value, bool)
+            and isinstance(b.value, Slot)
+            and b.value.tag == "w"
         ):
-            return cols[a.name].compare(kind, int(b.value))
+            return cols[a.name].compare(kind, _slot_value(lits, b.value))
     return None
 
 
-def compile_expr(e: Expr, cols: dict[str, jnp.ndarray]):
+def compile_expr(e: Expr, cols: dict[str, jnp.ndarray], lits=()):
     """Trace an expression over device column arrays. Caller guarantees the
-    involved columns are non-null numerics (checked in _plan_supported)."""
+    involved columns are non-null numerics (checked in _plan_supported).
+    A literal lowered to a ``Slot`` reads its word of the kernel argument
+    ``lits``; any other literal is a trace constant."""
     if isinstance(e, Alias):
-        return compile_expr(e.child, cols)
+        return compile_expr(e.child, cols, lits)
     if isinstance(e, X.Col):
         v = cols[e.name]
         if isinstance(v, Wide64):
@@ -125,29 +142,210 @@ def compile_expr(e: Expr, cols: dict[str, jnp.ndarray]):
             )
         return v
     if isinstance(e, X.Lit):
-        return e.value
+        return _slot_value(lits, e.value) if isinstance(e.value, Slot) else e.value
     for klass, op in _CMP.items():
         if type(e) is klass:
-            wide = _wide_compare(e, cols)
+            wide = _wide_compare(e, cols, lits)
             if wide is not None:
                 return wide
-            return op(compile_expr(e.left, cols), compile_expr(e.right, cols))
+            return op(compile_expr(e.left, cols, lits),
+                      compile_expr(e.right, cols, lits))
     for klass, op in _ARITH.items():
         if type(e) is klass:
-            return op(compile_expr(e.left, cols), compile_expr(e.right, cols))
+            return op(compile_expr(e.left, cols, lits),
+                      compile_expr(e.right, cols, lits))
     if isinstance(e, X.And):
-        return compile_expr(e.left, cols) & compile_expr(e.right, cols)
+        return compile_expr(e.left, cols, lits) & compile_expr(e.right, cols, lits)
     if isinstance(e, X.Or):
-        return compile_expr(e.left, cols) | compile_expr(e.right, cols)
+        return compile_expr(e.left, cols, lits) | compile_expr(e.right, cols, lits)
     if isinstance(e, X.Not):
-        return ~compile_expr(e.child, cols)
+        return ~compile_expr(e.child, cols, lits)
     if isinstance(e, X.In):
-        c = compile_expr(e.child, cols)
+        c = compile_expr(e.child, cols, lits)
         out = jnp.zeros(c.shape, dtype=bool)
         for v in e.values:
-            out = out | (c == v)
+            out = out | (c == (_slot_value(lits, v) if isinstance(v, Slot) else v))
         return out
     raise HyperspaceError(f"Expression not supported on device: {e!r}")
+
+
+# ---------------------------------------------------------------------------
+# literal slots: literals reach the kernels as arguments
+# ---------------------------------------------------------------------------
+#
+# A kernel closes over the expressions of the query that built it and is
+# cached by their fingerprint (plan/kernel_cache.py). Were literals trace
+# constants, every new order key, year or dictionary code would key, trace
+# and compile a kernel of its own. Instead each literal becomes a Slot: the
+# expression keeps the literal's type and place, and the value travels in
+# the kernel's ``lits`` argument, one int32 vector for all of a kernel's
+# literals (one host-to-device copy a dispatch; a float as its float32 bits).
+# The fingerprint reads the slotted expressions, whose repr is their shape,
+# so one predicate shape builds one kernel whatever its values.
+
+class Slot:
+    """A literal lowered to word ``i`` of a kernel's int32 ``lits`` vector.
+    ``tag`` is ``i``/``f``/``b`` for an int32/float32/bool value (a float
+    held as its bits), ``w`` for the (hi int32, lo uint32) words of an int64
+    compared against a Wide64 column, at ``i`` and ``i + 1``. The value
+    takes the type the literal meets on the device (an int compared with a
+    float32 column is read as float32, as jnp converts a constant), and the
+    tag keeps the literal's own kind besides: ``i>f``. The repr is the tag
+    alone."""
+
+    __slots__ = ("i", "tag")
+
+    def __init__(self, i: int, tag: str):
+        self.i = i
+        self.tag = tag
+
+    def __repr__(self):
+        return "?" + self.tag
+
+
+def _slot_word(value, kind: str) -> int:
+    """The int32 word that carries a literal of device kind ``kind``."""
+    if kind == "f":
+        return int(np.float32(value).view(np.int32))
+    return int(np.int32(value))
+
+
+def _slot_value(lits, slot: Slot):
+    """A slot's traced value, read from the kernel's ``lits`` words."""
+    word = lits[slot.i]
+    kind = slot.tag[-1]
+    if kind == "w":
+        return word, jax.lax.bitcast_convert_type(lits[slot.i + 1], jnp.uint32)
+    if kind == "f":
+        return jax.lax.bitcast_convert_type(word, jnp.float32)
+    if kind == "b":
+        return word != 0
+    return word
+
+
+def _value_kind(v) -> Optional[str]:
+    """``b``/``i``/``f`` of a literal that can be slotted; None keeps it a
+    trace constant (None, strings, ints beyond 32 bits outside a Wide64
+    compare — screened out before any kernel is built)."""
+    if isinstance(v, (bool, np.bool_)):
+        return "b"
+    if _is_int_value(v):
+        return "i" if _int_lit_fits(v) else None
+    if isinstance(v, (float, np.floating)):
+        return "f"
+    return None
+
+
+def _dtype_kind(dt) -> Optional[str]:
+    dt = np.dtype(dt)
+    if dt == np.bool_:
+        return "b"
+    if dt == np.int32:
+        return "i"
+    if dt == np.float32:
+        return "f"
+    return None
+
+
+class _Slotter:
+    """Rewrites expressions with their literals as slots and collects the
+    slot values, in a traversal fixed by the expression's shape."""
+
+    def __init__(self, col_kinds: dict, wide: frozenset):
+        self.kinds = dict(col_kinds)  # column -> device kind, where known
+        self.wide = wide
+        self.words: list[int] = []
+
+    def _new(self, words: list[int], tag: str) -> Slot:
+        self.words.extend(words)
+        return Slot(len(self.words) - len(words), tag)
+
+    def _lit(self, v, context: Optional[str]):
+        own = _value_kind(v)
+        if own is None:
+            return None
+        # an int meeting a float32 operand is read as float32, an int
+        # meeting a bool operand stays int (jnp promotes as for a constant)
+        kind = context if own == "i" and context == "f" else own
+        tag = own if kind == own else f"{own}>{kind}"
+        return self._new([_slot_word(v, kind)], tag)
+
+    def kind(self, e: Expr) -> Optional[str]:
+        """The device kind an expression traces to, where it is plain."""
+        if isinstance(e, Alias):
+            return self.kind(e.child)
+        if isinstance(e, X.Col):
+            return self.kinds.get(e.name)
+        if isinstance(e, X.Lit):
+            return _value_kind(e.value)
+        if isinstance(e, (X.And, X.Or, X.Not, X.In, *_CMP)):
+            return "b"
+        if isinstance(e, X.Div):
+            return "f"
+        if type(e) in _ARITH:
+            a, b = self.kind(e.left), self.kind(e.right)
+            return "f" if "f" in (a, b) else (a if a == b else None)
+        return None
+
+    def slot(self, e: Expr, context: Optional[str] = None) -> Expr:
+        if isinstance(e, X.Lit):
+            s = self._lit(e.value, context)
+            return e if s is None else X.Lit(s)
+        if isinstance(e, Alias):
+            return Alias(self.slot(e.child, context), e.name)
+        if type(e) in _CMP or type(e) in _ARITH:
+            for a, b in ((e.left, e.right), (e.right, e.left)):
+                if (type(e) in _CMP and isinstance(a, X.Col)
+                        and a.name in self.wide and isinstance(b, X.Lit)
+                        and _is_int_value(b.value)):
+                    words = [int(w.view(np.int32)) for w in _int64_words(b.value)]
+                    lit = X.Lit(self._new(words, "w"))
+                    return (type(e)(a, lit) if a is e.left
+                            else type(e)(lit, a))
+            # each operand is typed by the other one, as jnp promotes it
+            left = self.slot(e.left, self.kind(e.right))
+            right = self.slot(e.right, self.kind(e.left))
+            return type(e)(left, right)
+        if isinstance(e, (X.And, X.Or)):
+            return type(e)(self.slot(e.left), self.slot(e.right))
+        if isinstance(e, X.Not):
+            return X.Not(self.slot(e.child))
+        if isinstance(e, X.In):
+            ctx = self.kind(e.child)
+            vals = [self._lit(v, ctx) for v in e.values]
+            if any(v is None for v in vals):
+                return e
+            return X.In(self.slot(e.child), vals)
+        return e
+
+
+def slot_literals(pred_expr, proj_exprs=(), agg_list=(), col_kinds=None,
+                  wide: frozenset = frozenset()):
+    """``(pred, proj_exprs, agg_list, lits)``: the kernel's expressions with
+    every literal as a ``Slot``, and the int32 vector of slot words the
+    kernel takes as its ``lits`` argument. ``col_kinds`` maps a column to the kind
+    of its device array (``dev_cols``); ``wide`` names the Wide64 columns.
+    Projections are typed as they come, so aggregates over their outputs
+    see the same kinds the kernel will."""
+    s = _Slotter(col_kinds or {}, wide)
+    pred = None if pred_expr is None else s.slot(pred_expr)
+    proj = []
+    for name, e in proj_exprs:
+        proj.append((name, s.slot(e)))
+        s.kinds[name] = s.kind(e)
+    aggs = [(kind, None if c is None else s.slot(c)) for kind, c in agg_list]
+    return pred, tuple(proj), aggs, np.asarray(s.words, dtype=np.int32)
+
+
+def _slot_for(dev_cols: dict, pred_expr, proj_exprs=(), agg_list=()):
+    """``slot_literals`` typed by the uploaded columns: each column's device
+    kind, and Wide64 for the columns uploaded as word pairs."""
+    kinds = {
+        n: "i" if isinstance(a, tuple) else _dtype_kind(a.dtype)
+        for n, a in dev_cols.items()
+    }
+    wide = frozenset(n for n, a in dev_cols.items() if isinstance(a, tuple))
+    return slot_literals(pred_expr, proj_exprs, agg_list, kinds, wide)
 
 
 def _expr_device_ok(e: Expr, string_ok: frozenset = frozenset()) -> bool:
@@ -412,9 +610,20 @@ def _wide_predicate_cols(frag: "_Fragment", batch: ColumnBatch) -> frozenset:
     """int64 columns exceeding the 32-bit device range that may still ship
     as word pairs: non-null, referenced ONLY by the filter predicate, and
     there only in comparisons against integer literals."""
-    pred = frag.pred
-    if pred is None:
+    if frag.pred is None:
         return frozenset()
+    pred_orig = frag.filter.condition if frag.filter is not None else None
+    others: set[str] = set()
+    for e in _device_exprs(frag):
+        if e is not pred_orig:
+            others |= e.references()
+    return _wide_cols(frag.pred, batch) - others
+
+
+def _wide_cols(pred: Expr, batch: ColumnBatch) -> frozenset:
+    """Columns of ``pred`` that ship as Wide64 word pairs: non-null int64
+    beyond the 32-bit device range, referenced only in comparisons against
+    integer literals."""
     cand = set()
     for name in pred.references():
         if name not in batch.columns:
@@ -426,13 +635,6 @@ def _wide_predicate_cols(frag: "_Fragment", batch: ColumnBatch) -> frozenset:
             col.data.min() < -(2**31) or col.data.max() >= 2**31
         ):
             cand.add(name)
-    if not cand:
-        return frozenset()
-    pred_orig = frag.filter.condition if frag.filter is not None else None
-    for e in _device_exprs(frag):
-        if e is pred_orig:
-            continue
-        cand -= e.references()
     return frozenset(c for c in cand if _wide_pattern_ok(pred, c))
 
 
@@ -496,6 +698,20 @@ def _device_refs(f: "_Fragment") -> set[str]:
     return refs
 
 
+def _truth_valued(e: Expr, schema) -> bool:
+    """True when ``e`` yields booleans: comparisons and IN, bool columns and
+    literals, joined by And/Or/Not. The device's ``&``, ``|`` and ``~`` of
+    ints are bitwise where the host reads their truth values, so a
+    predicate of any other shape stays on the host."""
+    if isinstance(e, (X.And, X.Or, X.Not)):
+        return all(_truth_valued(c, schema) for c in e.children())
+    if isinstance(e, X.In) or type(e) in _CMP:
+        return True
+    if isinstance(e, X.Col):
+        return e.name in schema.names and schema.field(e.name).dtype == "bool"
+    return isinstance(e, X.Lit) and isinstance(e.value, (bool, np.bool_))
+
+
 def _fragment_supported(f: _Fragment) -> bool:
     """Structural + dtype screen that needs no data read (validity is checked
     after the scan; everything else is knowable from schema + expressions)."""
@@ -517,6 +733,8 @@ def _fragment_supported(f: _Fragment) -> bool:
         fld.name for fld in f.scan.schema if fld.dtype == STRING
     )
     pred = f.filter.condition if f.filter is not None else None
+    if pred is not None and not _truth_valued(pred, f.scan.full_schema):
+        return False
     for e in exprs:
         # the filter predicate may compare string columns against string
         # literals (rewritten to dictionary codes at exec time); aggregates
@@ -688,17 +906,19 @@ def _pallas_shape(pred_expr, proj_exprs, agg_list):
 def _build_pallas_kernel(pred_expr, proj_exprs, agg_list, a_expr, b_expr, sum_pos):
     from ..ops.pallas_kernels import filter_sum, filter_weighted_sum
 
-    def kernel(cols, mask):
+    def kernel(cols, mask, lits):
         cols = _wrap_wide(cols)
-        a = compile_expr(a_expr, cols)
-        b = None if b_expr is None else compile_expr(b_expr, cols)
+        a = compile_expr(a_expr, cols, lits)
+        b = None if b_expr is None else compile_expr(b_expr, cols, lits)
         if jnp.issubdtype(a.dtype, jnp.integer) or (
             b is not None and jnp.issubdtype(b.dtype, jnp.integer)
         ):
             # integer sums need the exact chunked accumulation; the f32
             # Pallas reduction would round — generic body takes over
-            return _generic_agg_compute(pred_expr, proj_exprs, agg_list, cols, mask)
-        pred = mask & compile_expr(pred_expr, cols)
+            return _generic_agg_compute(
+                pred_expr, proj_exprs, agg_list, cols, mask, lits
+            )
+        pred = mask & compile_expr(pred_expr, cols, lits)
         if b is None:
             rev, cnt = filter_sum(pred, a)
         else:
@@ -710,21 +930,21 @@ def _build_pallas_kernel(pred_expr, proj_exprs, agg_list, a_expr, b_expr, sum_po
     return jax.jit(kernel)  # hslint: HS201 — builder runs via KernelCache.get_or_build
 
 
-def _generic_agg_compute(pred_expr, proj_exprs, agg_list, cols, mask):
+def _generic_agg_compute(pred_expr, proj_exprs, agg_list, cols, mask, lits):
     """Traced body of the generic fused kernel (shared so the Pallas kernel
     can fall back to it at trace time for integer-sum exactness)."""
     if pred_expr is not None:
-        mask = mask & compile_expr(pred_expr, cols)
+        mask = mask & compile_expr(pred_expr, cols, lits)
     matched = mask.sum()
     proj_cols = dict(cols)
     for name, e in proj_exprs:
-        proj_cols[name] = compile_expr(e, cols)
+        proj_cols[name] = compile_expr(e, cols, lits)
     out = []
     for kind, child in agg_list:
         if kind == "count":
             out.append(matched)
             continue
-        vals = compile_expr(child, proj_cols)
+        vals = compile_expr(child, proj_cols, lits)
         # fill values stay in the column dtype (no float promotion that
         # would round ints >= 2**24)
         if kind == "sum":
@@ -763,11 +983,24 @@ def _build_kernel(pred_expr, proj_exprs, agg_list):
             a, b, sum_pos, _cnt_pos = shape
             return _build_pallas_kernel(pred_expr, proj_exprs, agg_list, a, b, sum_pos)
 
-    def kernel(cols, mask):
+    def kernel(cols, mask, lits):
         cols = _wrap_wide(cols)
-        return _generic_agg_compute(pred_expr, proj_exprs, agg_list, cols, mask)
+        return _generic_agg_compute(
+            pred_expr, proj_exprs, agg_list, cols, mask, lits
+        )
 
     return jax.jit(kernel)  # hslint: HS201 — builder runs via KernelCache.get_or_build
+
+
+def _fused_kernel(pred_expr, proj_exprs, agg_list, dev_cols):
+    """The cached fused kernel of the fragment's shape, and its literal
+    arguments."""
+    pred, proj, aggs, lits = _slot_for(dev_cols, pred_expr, proj_exprs, agg_list)
+    key = fused_fingerprint(_pallas_route(), pred, proj, aggs, dev_cols)
+    kernel = _KERNEL_CACHE.get_or_build(
+        key, lambda: _build_kernel(pred, proj, aggs), "fused_agg"
+    )
+    return kernel, lits
 
 
 def _device_dtype(np_dtype) -> np.dtype:
@@ -1005,20 +1238,14 @@ def _try_execute_tpu_inner(
         )
         agg_list, names = _agg_list_names(frag)
 
-        key = fused_fingerprint(
-            _pallas_route(), pred_expr, proj_exprs, agg_list, dev_cols
-        )
-        kernel = _KERNEL_CACHE.get_or_build(
-            key, lambda: _build_kernel(pred_expr, proj_exprs, agg_list),
-            "fused_agg",
-        )
+        kernel, lits = _fused_kernel(pred_expr, proj_exprs, agg_list, dev_cols)
         # ONE batched transfer for the whole result tree: per-array fetches
         # pay a device->host round trip each
         from ..utils.rpc_meter import METER, device_get as metered_get
 
         METER.record_dispatch()
         with _attr.phase("enqueue"):
-            out = kernel(dev_cols, mask)
+            out = kernel(dev_cols, mask, lits)
         matched, results = metered_get(out)
     matched = int(matched)
     scalar_values = [
@@ -1048,22 +1275,23 @@ def _pallas_grouped_shape(pred_expr, agg_list, seg_pad):
 def _build_grouped_pallas_kernel(pred_expr, proj_exprs, agg_list, seg_pad):
     from ..ops.pallas_kernels import filter_grouped_multi_sum
 
-    def kernel(cols, gids, mask):
+    def kernel(cols, gids, mask, lits):
         cols = _wrap_wide(cols)
         if pred_expr is not None:
-            mask = mask & compile_expr(pred_expr, cols)
+            mask = mask & compile_expr(pred_expr, cols, lits)
         proj_cols = dict(cols)
         for name, e in proj_exprs:
-            proj_cols[name] = compile_expr(e, cols)
+            proj_cols[name] = compile_expr(e, cols, lits)
         sum_vals = []
         for kind, child in agg_list:
             if kind == "count":
                 continue
-            vals = compile_expr(child, proj_cols)
+            vals = compile_expr(child, proj_cols, lits)
             if jnp.issubdtype(vals.dtype, jnp.integer):
                 # exact chunked accumulation owns int sums/avgs — generic body
                 return _generic_grouped_compute(
-                    pred_expr, proj_exprs, agg_list, seg_pad, cols, gids, mask
+                    pred_expr, proj_exprs, agg_list, seg_pad, cols, gids, mask,
+                    lits,
                 )
             sum_vals.append(vals)
         # every measure + the count in ONE streaming pass over pred/gids
@@ -1095,16 +1323,17 @@ def _first_masked_rows(mask, gids, seg_pad):
     )
 
 
-def _generic_grouped_compute(pred_expr, proj_exprs, agg_list, seg_pad, cols, gids, mask):
+def _generic_grouped_compute(pred_expr, proj_exprs, agg_list, seg_pad, cols,
+                             gids, mask, lits):
     """Traced body of the generic grouped kernel (shared so the Pallas route
     can fall back at trace time for integer-sum exactness)."""
     if pred_expr is not None:
-        mask = mask & compile_expr(pred_expr, cols)
+        mask = mask & compile_expr(pred_expr, cols, lits)
     gids = jnp.where(mask, gids, seg_pad - 1)
     first_masked = _first_masked_rows(mask, gids, seg_pad)
     proj_cols = dict(cols)
     for name, e in proj_exprs:
-        proj_cols[name] = compile_expr(e, cols)
+        proj_cols[name] = compile_expr(e, cols, lits)
     counts = jax.ops.segment_sum(
         jnp.ones_like(gids, dtype=jnp.int32), gids, num_segments=seg_pad
     )
@@ -1113,7 +1342,7 @@ def _generic_grouped_compute(pred_expr, proj_exprs, agg_list, seg_pad, cols, gid
         if kind == "count":
             out.append(counts)
             continue
-        vals = compile_expr(child, proj_cols)
+        vals = compile_expr(child, proj_cols, lits)
         if kind == "sum":
             if jnp.issubdtype(vals.dtype, jnp.integer):
                 out.append(_int_chunk_sums(vals, gids, seg_pad))
@@ -1139,13 +1368,25 @@ def _build_grouped_kernel(pred_expr, proj_exprs, agg_list, seg_pad):
     if _pallas_route() and _pallas_grouped_shape(pred_expr, agg_list, seg_pad) is not None:
         return _build_grouped_pallas_kernel(pred_expr, proj_exprs, agg_list, seg_pad)
 
-    def kernel(cols, gids, mask):
+    def kernel(cols, gids, mask, lits):
         cols = _wrap_wide(cols)
         return _generic_grouped_compute(
-            pred_expr, proj_exprs, agg_list, seg_pad, cols, gids, mask
+            pred_expr, proj_exprs, agg_list, seg_pad, cols, gids, mask, lits
         )
 
     return jax.jit(kernel)  # hslint: HS201 — builder runs via KernelCache.get_or_build
+
+
+def _grouped_kernel(seg_pad, pred_expr, proj_exprs, agg_list, dev_cols):
+    """The cached grouped kernel of the fragment's shape, and its literal
+    arguments."""
+    pred, proj, aggs, lits = _slot_for(dev_cols, pred_expr, proj_exprs, agg_list)
+    key = grouped_fingerprint(_pallas_route(), seg_pad, pred, proj, aggs, dev_cols)
+    kernel = _KERNEL_CACHE.get_or_build(
+        key, lambda: _build_grouped_kernel(pred, proj, aggs, seg_pad),
+        "grouped_agg",
+    )
+    return kernel, lits
 
 
 def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[ColumnBatch]:
@@ -1206,19 +1447,14 @@ def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[Colu
             (X.expr_output_name(e), e) for e in _device_projections(frag)
         )
         agg_list, names = _agg_list_names(frag)
-        key = grouped_fingerprint(
-            _pallas_route(), seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
-        )
-        kernel = _KERNEL_CACHE.get_or_build(
-            key,
-            lambda: _build_grouped_kernel(pred_expr, proj_exprs, agg_list, seg_pad),
-            "grouped_agg",
+        kernel, lits = _grouped_kernel(
+            seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
         )
         from ..utils.rpc_meter import METER, device_get as metered_get
 
         METER.record_dispatch()
         with _attr.phase("enqueue"):
-            out = kernel(dev_cols, gids_d, mask)
+            out = kernel(dev_cols, gids_d, mask, lits)
         counts_dev, first_masked, results = metered_get(out)
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]
@@ -1230,6 +1466,184 @@ def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[Colu
         plan, frag, key_cols, first_idx, counts, results, agg_list, names,
         num_groups, first_masked,
     )
+
+
+# ---------------------------------------------------------------------------
+# selection route: Filter ← FileScan over an index (row-returning scans)
+# ---------------------------------------------------------------------------
+#
+# FilterIndexRule rewrites [Project →] Filter → Scan onto a covering index
+# and returns rows. The predicate runs on the device over the device-resident
+# predicate columns (the same uploads and device cache as the aggregates),
+# and the matching rows compact there to ascending positions, at most
+# SELECT_CAP of them: one dispatch, and one fetch of a count and a few
+# positions. The host takes exactly those rows from the batch it read, so
+# rows, order, dtypes and float64 values are the host tier's; a Project above
+# runs on the host over the taken rows, as it would over the host filter's.
+# More matches than the cap run the kernel again at a cap that holds them
+# all (``select.cap_overflow``): exact, never truncated. The route reads the
+# scan monolithically; the chunk streamer takes no selections.
+
+SELECT_CAP = 256  # positions one fetch brings back
+
+# column types the device compares exactly as the host does (32-bit ints,
+# int64 within or as Wide64 words, dictionary codes); floats, held in 32
+# bits there, stay on the host
+_EXACT_SELECT_TYPES = frozenset(
+    {"int8", "int16", "int32", "int64", "date32", "bool", STRING}
+)
+
+
+def _select_pred_exact(e: Expr, schema) -> bool:
+    """True when the device decides every row as the host would: a truth
+    value (``_truth_valued``) whose comparisons and IN lists take integer,
+    date, bool and string columns and int, bool and string literals. No
+    floats, and no arithmetic, which wraps at 32 bits on the device and not
+    on the host."""
+
+    def operand(x) -> bool:
+        if isinstance(x, X.Col):
+            return (x.name in schema.names
+                    and schema.field(x.name).dtype in _EXACT_SELECT_TYPES)
+        return isinstance(x, X.Lit) and _exact_value(x.value)
+
+    def walk(x) -> bool:
+        if isinstance(x, (X.And, X.Or, X.Not)):
+            return all(walk(c) for c in x.children())
+        if isinstance(x, X.In):
+            return operand(x.child) and all(_exact_value(v) for v in x.values)
+        if type(x) in _CMP:
+            return operand(x.left) and operand(x.right)
+        return True  # a bool column or literal
+
+    return _truth_valued(e, schema) and walk(e)
+
+
+def _exact_value(v) -> bool:
+    return v is not None and not isinstance(v, (float, np.floating))
+
+
+def _build_select_kernel(pred_expr, cap: int):
+    """Predicate over the padded rows, then the first ``cap`` matching
+    positions in ascending order (the k-th match is where the running count
+    first reaches k; positions past the count read ``padded``)."""
+
+    def kernel(cols, mask, lits):
+        cols = _wrap_wide(cols)
+        keep = mask & compile_expr(pred_expr, cols, lits)
+        running = jnp.cumsum(keep, dtype=jnp.int32)
+        ranks = jnp.arange(1, cap + 1, dtype=jnp.int32)
+        pos = jnp.searchsorted(
+            running, ranks, side="left", method="scan_unrolled"
+        ).astype(jnp.int32)
+        return running[-1], pos
+
+    return jax.jit(kernel)  # hslint: HS201 — builder runs via KernelCache.get_or_build
+
+
+def _select_once(pred_expr, dev_cols, mask, cap: int) -> tuple[int, np.ndarray]:
+    """One dispatch and one fetch: (matches, first ``cap`` positions)."""
+    from ..utils.rpc_meter import METER, device_get as metered_get
+
+    pred, _proj, _aggs, lits = _slot_for(dev_cols, pred_expr)
+    kernel = _KERNEL_CACHE.get_or_build(
+        select_fingerprint(cap, pred, dev_cols),
+        lambda: _build_select_kernel(pred, cap), "select",
+    )
+    METER.record_dispatch()
+    with _attr.phase("enqueue"):
+        out = kernel(dev_cols, mask, lits)
+    count, pos = metered_get(out)
+    return int(count), np.asarray(pos)
+
+
+def _select_positions(pred: Expr, scan: FileScan, batch: ColumnBatch) -> Optional[np.ndarray]:
+    """Ascending positions of the rows of ``batch`` that pass ``pred``,
+    computed on the device; None when the batch cannot ship (nullable or
+    out-of-range columns, a string reference no code rewrite covers)."""
+    n = batch.num_rows
+    if n == 0:
+        return None
+    scols = {f.name for f in scan.full_schema if f.dtype == STRING} & pred.references()
+    if scols:
+        pred = _encode_string_predicates(pred, batch, scols)
+        if pred is None:
+            return None
+    refs = pred.references() & set(batch.columns)
+    padded = _pad_pow2(n)
+    with trace.span("kernel:select", rows=n, padded=padded) as sp:
+        dev_cols = _upload_columns(batch, refs, padded) if _literals_fit(pred) else None
+        if dev_cols is None:  # full-range int64 columns ship as word pairs
+            wide = _wide_cols(pred, batch)
+            if wide and _literals_fit(pred, wide):
+                dev_cols = _upload_columns(batch, refs, padded, wide)
+        if dev_cols is None:
+            sp.set_attr("declined", "nullable_or_out_of_range")
+            return None
+        mask = _padded_mask(padded, n)
+        count, pos = _select_once(pred, dev_cols, mask, SELECT_CAP)
+        if count > SELECT_CAP:
+            REGISTRY.counter("select.cap_overflow").inc()
+            count, pos = _select_once(pred, dev_cols, mask, _pad_pow2(count))
+        sp.set_attr("matched", count)
+    REGISTRY.counter("select.rows_scanned").inc(n)
+    REGISTRY.counter("select.rows_matched").inc(count)
+    return pos[:count].astype(np.int64)
+
+
+def try_execute_select(plan: LogicalPlan, session) -> Optional[ColumnBatch]:
+    """A ``Filter ← FileScan`` over an index as a device selection; None
+    when the shape,
+    the predicate or the data is unsupported, or the device failed (the host
+    executor takes over; failures go through the breaker as in
+    ``try_execute_tpu``)."""
+    from ..utils.backend import (
+        device_healthy,
+        record_device_failure,
+        record_device_success,
+    )
+
+    if not isinstance(plan, Filter) or not isinstance(plan.child, FileScan):
+        return None
+    scan = plan.child
+    if scan.index_info is None:
+        # only index data is the engine's resident set (cached chunks, so
+        # device copies that persist); a source scan reads fresh buffers,
+        # and the route would upload its columns and pay a round trip on
+        # every query for a filter the host runs in place
+        return None
+    pred = plan.condition
+    strings = frozenset(f.name for f in scan.full_schema if f.dtype == STRING)
+    if (
+        not pred.references()
+        or not _expr_device_ok(pred, strings)
+        or not _select_pred_exact(pred, scan.full_schema)
+        or not device_healthy()
+    ):
+        return None
+    from .executor import _exec_file_scan
+
+    # as the aggregates do: read outside the breaker, without the pushed
+    # filter, so the chunk and device caches hold stable buffers whatever
+    # the literals (bucket and row-group pruning still apply)
+    read = scan.copy(pushed_filter=None) if scan.pushed_filter is not None else scan
+    batch = _exec_file_scan(read)
+    try:
+        pos = _select_positions(pred, scan, batch)
+    except Exception as e:  # device failure: host executor takes over
+        record_device_failure(e)
+        return None
+    if pos is None:
+        return None
+    record_device_success()
+    from ..telemetry import plan_stats
+
+    plan_stats.note_route(plan.plan_id, "device")
+    plan_stats.note_scan(
+        scan.plan_id, len(read.files), sum(f.size for f in read.files),
+        rows=batch.num_rows,
+    )
+    return batch.take(pos)
 
 
 # ---------------------------------------------------------------------------
@@ -1467,16 +1881,10 @@ def _stream_global_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch]
             if dev_cols is None:
                 return None  # nullable / out-of-range chunk: monolithic path
             mask = _padded_mask(padded, n, device=device)
-            key = fused_fingerprint(
-                _pallas_route(), pred, proj_exprs, agg_list, dev_cols
-            )
-            kernel = _KERNEL_CACHE.get_or_build(
-                key, lambda: _build_kernel(pred, proj_exprs, agg_list),
-                "fused_agg",
-            )
+            kernel, lits = _fused_kernel(pred, proj_exprs, agg_list, dev_cols)
             METER.record_dispatch()
             with _attr.phase("enqueue"):
-                pending.append(kernel(dev_cols, mask))
+                pending.append(kernel(dev_cols, mask, lits))
             REGISTRY.counter("pipeline.chunks").inc()
         while len(pending) > depth:
             fold(pending.popleft())
@@ -1662,17 +2070,12 @@ def _stream_grouped_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch
                 gids_d = jnp.asarray(gids_arr) if device is None else \
                     jax.device_put(gids_arr, device)
             mask = _padded_mask(padded, n, device=device)
-            key = grouped_fingerprint(
-                _pallas_route(), seg_pad, pred, proj_exprs, agg_list, dev_cols
-            )
-            kernel = _KERNEL_CACHE.get_or_build(
-                key,
-                lambda: _build_grouped_kernel(pred, proj_exprs, agg_list, seg_pad),
-                "grouped_agg",
+            kernel, lits = _grouped_kernel(
+                seg_pad, pred, proj_exprs, agg_list, dev_cols
             )
             METER.record_dispatch()
             with _attr.phase("enqueue"):
-                out = kernel(dev_cols, gids_d, mask)
+                out = kernel(dev_cols, gids_d, mask, lits)
             pending.append((gmap, num_l, row_offset, out))
             REGISTRY.counter("pipeline.chunks").inc()
         row_offset += n
@@ -1820,16 +2223,12 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
             else ()
         )
         with trace.span("kernel:fused_agg", rows=n_seen, padded=padded):
-            key = fused_fingerprint(
-                _pallas_route(), pred_expr, proj_exprs, agg_list, dev_cols
-            )
-            kernel = _KERNEL_CACHE.get_or_build(
-                key, lambda: _build_kernel(pred_expr, proj_exprs, agg_list),
-                "fused_agg",
+            kernel, lits = _fused_kernel(
+                pred_expr, proj_exprs, agg_list, dev_cols
             )
             METER.record_dispatch()
             with _attr.phase("enqueue"):
-                out = kernel(dev_cols, mask)
+                out = kernel(dev_cols, mask, lits)
             matched, results = metered_get(out)
         matched = int(matched)
         scalar_values = [
@@ -1853,17 +2252,12 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
     with trace.span(
         "kernel:grouped_agg", rows=n_seen, padded=padded, groups=num_groups
     ):
-        key = grouped_fingerprint(
-            _pallas_route(), seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
-        )
-        kernel = _KERNEL_CACHE.get_or_build(
-            key,
-            lambda: _build_grouped_kernel(pred_expr, proj_exprs, agg_list, seg_pad),
-            "grouped_agg",
+        kernel, lits = _grouped_kernel(
+            seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
         )
         METER.record_dispatch()
         with _attr.phase("enqueue"):
-            out = kernel(dev_cols, gids_d, mask)
+            out = kernel(dev_cols, gids_d, mask, lits)
         counts_dev, first_masked, results = metered_get(out)
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]

@@ -225,6 +225,10 @@ def parse_spec(spec: str) -> list[FaultRule]:
 # workers, so all bookkeeping runs under one leaf lock.
 _PLAN: "list[FaultRule] | None" = None
 _PLAN_LOCK = threading.Lock()  # leaf: never acquires another lock inside
+# crash_after rules a thread's fire() triggered, by point, for that thread's
+# fire_after(): concurrent writers count hits in between, so the after half
+# must not read the shared count again
+_AFTER = threading.local()
 
 
 def arm(spec: str) -> list[FaultRule]:
@@ -267,24 +271,33 @@ def _fire_rule(rule: FaultRule, point: str, ctx: dict) -> None:
 
 def _select(point: str, phase: str) -> "tuple[FaultRule, dict] | None":
     """Count a hit on every matching rule; return the first that fires in
-    this phase. ``before`` fires ioerror/oom/crash_before; ``after`` fires
-    crash_after (the hit was already counted by the before call)."""
+    this phase. ``before`` fires ioerror/oom/crash_before and decides, on
+    the hit it counted, whether a crash_after rule fires; ``after`` fires
+    the crash_after rule the same thread's ``before`` call triggered."""
+    pending = getattr(_AFTER, "rules", None)
+    if pending is None:
+        pending = _AFTER.rules = {}
     with _PLAN_LOCK:
         plan = _PLAN
         if plan is None:
             return None
+        if phase != "before":
+            rule = pending.pop(point, None)
+            if rule is None or rule not in plan:
+                return None
+            rule.fired += 1
+            return rule, {}
         for rule in plan:
             if not rule.matches(point):
                 continue
-            if phase == "before":
-                rule.hits += 1
-                if rule.kind != "crash_after" and rule.should_fire():
-                    rule.fired += 1
-                    return rule, {}
-            else:
-                if rule.kind == "crash_after" and rule.should_fire():
-                    rule.fired += 1
-                    return rule, {}
+            rule.hits += 1
+            if not rule.should_fire():
+                continue
+            if rule.kind == "crash_after":
+                pending.setdefault(point, rule)
+                continue
+            rule.fired += 1
+            return rule, {}
     return None
 
 

@@ -91,9 +91,14 @@ def test_pallas_kernel_compiles(topo, one_chip, mosaic, kernel):
     assert "tpu_custom_call" in _compile_text(fn, *a, **static)
 
 
+def _lits(words):
+    """A kernel's literal vector (``slot_literals``), as a shape."""
+    return jax.ShapeDtypeStruct(np.shape(words), jnp.int32)
+
+
 def test_q6_fused_kernel_compiles(topo, one_chip, mosaic):
     from hyperspace_tpu.plan.expr import col
-    from hyperspace_tpu.plan.tpu_exec import _build_kernel
+    from hyperspace_tpu.plan.tpu_exec import _build_kernel, slot_literals
 
     pred = (
         (col("l_shipdate") >= 8766)
@@ -103,15 +108,37 @@ def test_q6_fused_kernel_compiles(topo, one_chip, mosaic):
         & (col("l_quantity") < 24)
     )
     agg_list = [("sum", col("l_extendedprice") * col("l_discount")), ("count", None)]
-    kernel = _build_kernel(pred, (), agg_list)
+    kinds = {"l_shipdate": "i", "l_discount": "f", "l_quantity": "f",
+             "l_extendedprice": "f"}
+    pred, proj, aggs, lits = slot_literals(pred, (), agg_list, kinds)
+    kernel = _build_kernel(pred, proj, aggs)
     cols = {
         "l_shipdate": _sds(one_chip, jnp.int32),
         "l_discount": _sds(one_chip, jnp.float32),
         "l_quantity": _sds(one_chip, jnp.float32),
         "l_extendedprice": _sds(one_chip, jnp.float32),
     }
-    text = kernel.lower(cols, _sds(one_chip, jnp.bool_)).compile().as_text()
+    text = kernel.lower(cols, _sds(one_chip, jnp.bool_), _lits(lits)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# the order-detail lookup at SF10: one bucket of l_orderkey padded to 2^23
+_SELECT_ROWS = 1 << 23
+
+
+def test_selection_kernel_compiles(topo, one_chip):
+    from hyperspace_tpu.plan.expr import col
+    from hyperspace_tpu.plan.tpu_exec import (
+        SELECT_CAP, _build_select_kernel, slot_literals)
+
+    pred, _, _, lits = slot_literals(col("l_orderkey") == 600000001,
+                                     col_kinds={"l_orderkey": "i"})
+    kernel = _build_select_kernel(pred, SELECT_CAP)
+    cols = {"l_orderkey": _sds(one_chip, jnp.int32, _SELECT_ROWS)}
+    compiled = kernel.lower(cols, _sds(one_chip, jnp.bool_, _SELECT_ROWS),
+                            _lits(lits)).compile()
+    count, pos = compiled.out_info
+    assert pos.shape == (SELECT_CAP,) and count.shape == ()
 
 
 # Q3 at SF10: 60M lineitem rows over 8 buckets probe 15M orders keys,

@@ -174,8 +174,10 @@ def test_phases_charged_off_the_query_thread_are_resource_time():
 def test_a_new_literal_charges_its_compile_and_a_repeat_none(warehouse,
                                                              monkeypatch):
     session, root = warehouse
-    # 19: a literal no other query of this module compiles
-    first, again = _serve([_q6(session, root, 19), _q6(session, root, 19)],
+    # 19.5: a float literal where the module's other queries have an int, a
+    # shape no other query compiles; literals are kernel arguments, so the
+    # repeat with another value of that shape compiles nothing
+    first, again = _serve([_q6(session, root, 19.5), _q6(session, root, 21.5)],
                           monkeypatch)
     assert first.phases_s().get("compile", 0.0) > 0
     assert again.phases_s().get("compile", 0.0) == 0

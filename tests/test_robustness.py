@@ -142,6 +142,37 @@ class TestFaultSpec:
         with pytest.raises(faults.InjectedCrash):
             faults.fire_after("log.write")
 
+    def test_crash_after_fires_for_the_writer_that_counted_the_hit(self):
+        """Concurrent writers: the second writer's fire() counts hit 2
+        before the first one's fire_after(); the first still crashes, the
+        second does not."""
+        import threading
+
+        faults.arm("approx.sample:crash_after:n=1")
+        first_fired, second_done = threading.Event(), threading.Event()
+        crashed = []
+
+        def writer(name, before, after):
+            faults.fire("approx.sample")
+            before.set()
+            after.wait(5)
+            try:
+                faults.fire_after("approx.sample")
+            except faults.InjectedCrash:
+                crashed.append(name)
+
+        go = threading.Event()
+        a = threading.Thread(target=writer, args=("a", first_fired, second_done))
+        b = threading.Thread(target=writer, args=("b", go, go))
+        a.start()
+        first_fired.wait(5)
+        b.start()
+        b.join(5)
+        second_done.set()
+        a.join(5)
+        faults.disarm()
+        assert crashed == ["a"]
+
     def test_unset_is_zero_overhead(self):
         """Disarmed hooks touch no counters (the clean path stays clean)."""
         faults.disarm()

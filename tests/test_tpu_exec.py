@@ -94,6 +94,17 @@ class TestTpuExec:
 class TestTpuExecEdgeCases:
     """Regression tests for device/host semantic parity edge cases."""
 
+    @pytest.mark.parametrize("pred", [col("d"), col("d") & (col("x") > 50.0),
+                                      ~col("d")])
+    def test_int_truth_values_match_host(self, df, pred):
+        """An int read as a truth value: bitwise on the device, truthiness
+        on the host, so the fragment stays on the host."""
+        session = df.session
+        host = df.filter(pred).agg(Count(lit(1)).alias("n")).to_pydict()
+        session.set_conf(C.EXEC_TPU_ENABLED, True)
+        dev = df.filter(pred).agg(Count(lit(1)).alias("n")).to_pydict()
+        assert dev == host
+
     def test_zero_match_returns_null(self, df):
         session = df.session
         session.set_conf(C.EXEC_TPU_ENABLED, True)
@@ -830,7 +841,7 @@ class TestWide64PropertySweep:
         import jax.numpy as jnp
 
         from hyperspace_tpu.plan import expr as X
-        from hyperspace_tpu.plan.tpu_exec import Wide64
+        from hyperspace_tpu.plan.tpu_exec import Wide64, _int64_words
         from hyperspace_tpu.ops.hashing import split64_np
 
         rng = np.random.default_rng(12)
@@ -852,7 +863,8 @@ class TestWide64PropertySweep:
         }
         for lit in lits[:20]:
             for kind, npop in ops.items():
-                got = np.asarray(w.compare(kind, int(lit)))
+                # the literal arrives as its two words, as a slot carries it
+                got = np.asarray(w.compare(kind, _int64_words(int(lit))))
                 np.testing.assert_array_equal(
                     got, npop(vals, lit), err_msg=f"{kind} vs {lit}"
                 )
