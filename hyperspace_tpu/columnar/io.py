@@ -347,15 +347,6 @@ class source_cache_scope:
         return False
 
 
-def _col_nbytes(col: Column) -> int:
-    nbytes = col.data.nbytes + (
-        col.validity.nbytes if col.validity is not None else 0
-    )
-    if col.dictionary:
-        nbytes += sum(len(s) for s in col.dictionary)
-    return nbytes
-
-
 def _source_cached_read(
     paths, cols: list[str], arrow_filter=None, row_groups=None
 ) -> ColumnBatch | None:
@@ -417,7 +408,7 @@ def _source_cached_read(
             )
             for c in missing:
                 col = batch.column(c)
-                _SOURCE_COL_CACHE.set(extend((fkey, c), p), col, _col_nbytes(col))
+                _SOURCE_COL_CACHE.set(extend((fkey, c), p), col, col.nbytes)
                 have[c] = col
         per_file.append(ColumnBatch({c: have[c] for c in todo}))
     if len(per_file) == 1:  # zero-copy reuse: no concat on the common layout
@@ -434,7 +425,7 @@ def _source_cached_read(
         if set_key is not None:
             for c in todo:
                 col = merged.column(c)
-                _SOURCE_COL_CACHE.set((set_key, c), col, _col_nbytes(col))
+                _SOURCE_COL_CACHE.set((set_key, c), col, col.nbytes)
     return ColumnBatch(
         {c: whole[c] if c in whole else merged.column(c) for c in cols}
     )

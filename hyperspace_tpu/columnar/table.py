@@ -121,6 +121,16 @@ class Column:
         return len(self.data)
 
     @property
+    def nbytes(self) -> int:
+        """Bytes the column holds: data, validity and dictionary text."""
+        nbytes = self.data.nbytes
+        if self.validity is not None:
+            nbytes += self.validity.nbytes
+        if self.dictionary:
+            nbytes += sum(len(s) for s in self.dictionary)
+        return nbytes
+
+    @property
     def dictionary_is_unique(self) -> bool:
         """True when no value appears under two codes (all in-repo
         constructors guarantee it; externally-built dictionaries are checked

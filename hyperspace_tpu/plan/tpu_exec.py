@@ -1389,57 +1389,87 @@ def _grouped_kernel(seg_pad, pred_expr, proj_exprs, agg_list, dev_cols):
     return kernel, lits
 
 
+def _grouping(key_parts: list[ColumnBatch], key_names: list[str], padded: int):
+    """Grouping of the key rows of ``key_parts`` (one batch of the key
+    columns per chunk, in row order): ``(num_groups, seg_pad, first_keys,
+    gids_d)``, where ``first_keys`` holds each group's key values at its
+    first occurrence (``num_groups`` rows) and ``gids_d`` the padded int32
+    device group ids, pad rows in the dump segment ``seg_pad - 1``.
+
+    It derives from the key buffers alone, never from the predicate,
+    literals or aggregates, so it caches under every key chunk's data and
+    validity buffer in chunk order, as the ``("cat", padded)`` device
+    columns do: the O(groups) host part in HOST_DERIVED_CACHE, the gids in
+    DEVICE_CACHE. A hit needs each buffer to be the same live object, so a
+    refreshed, appended or evicted chunk misses; a hit does no O(rows) host
+    work. A miss builds what the uncached route built: the concatenated
+    keys, ``factorize_group_keys``, the padded fill and its upload."""
+    from .executor import factorize_group_keys
+    from ..utils.device_cache import DEVICE_CACHE, HOST_DERIVED_CACHE
+
+    key_cols = [[part.column(nm) for nm in key_names] for part in key_parts]
+    srcs = tuple(
+        buf
+        for cols in key_cols
+        for c in cols
+        for buf in (c.data, c.validity)
+        if buf is not None
+    )
+    n = sum(part.num_rows for part in key_parts)
+    group_ids = []  # filled by a build: the per-row ids are never cached
+
+    def factorize():
+        keys = (
+            key_parts[0] if len(key_parts) == 1 else ColumnBatch.concat(key_parts)
+        )
+        cols = [keys.column(nm) for nm in key_names]
+        ids, num_groups, first_idx = factorize_group_keys(cols)
+        group_ids.append(ids)
+        return num_groups, [c.take(first_idx) for c in cols]
+
+    with trace.span("grouping", rows=n, padded=padded):
+        num_groups, first_keys = HOST_DERIVED_CACHE.get_or_put_multi(
+            srcs, ("grouping", tuple(key_names), padded), factorize
+        )
+        seg_pad = 1 << max(4, int(np.ceil(np.log2(num_groups + 1))))
+
+        def upload():
+            if not group_ids:  # host part cached, device copy gone
+                factorize()
+            arr = np.full(padded, seg_pad - 1, dtype=np.int32)
+            arr[:n] = group_ids[0].astype(np.int32)
+            return jnp.asarray(arr)
+
+        gids_d = DEVICE_CACHE.get_or_put_multi(
+            srcs, ("gids", padded, seg_pad), upload
+        )
+    REGISTRY.counter(
+        "pipeline.grouping.builds" if group_ids else "pipeline.grouping.hits"
+    ).inc()
+    return num_groups, seg_pad, first_keys, gids_d
+
+
 def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[ColumnBatch]:
     """Grouped fragment: keys factorize host-side (string keys never ship);
     masked segment reductions run on device."""
-    from .executor import factorize_group_keys
-
     n = batch.num_rows
     device_refs = _device_refs(frag)
-
-    from ..utils.device_cache import DEVICE_CACHE, HOST_DERIVED_CACHE
-
-    key_cols = [batch.column(e.name) for e in frag.agg.group_exprs]
-    # single-key grouping factorizes once per chunk: the host factorize pass
-    # and the device gid upload both cache on the key buffer's identity
-    cache_key_buf = (
-        key_cols[0].data
-        if len(key_cols) == 1 and key_cols[0].validity is None
-        else None
-    )
-    if cache_key_buf is not None:
-        group_ids, num_groups, first_idx = HOST_DERIVED_CACHE.get_or_put(
-            cache_key_buf, ("factorize",), lambda: factorize_group_keys(key_cols)
-        )
-    else:
-        group_ids, num_groups, first_idx = factorize_group_keys(key_cols)
-    seg_pad = 1 << max(4, int(np.ceil(np.log2(num_groups + 1))))
-
+    key_names = [e.name for e in frag.agg.group_exprs]
     padded = _pad_pow2(n)
     wide_ok = _wide_predicate_cols(frag, batch)
     if not _fragment_literals_fit(frag, wide_ok):
         return None
-    with trace.span(
-        "kernel:grouped_agg", rows=n, padded=padded, groups=num_groups
-    ) as sp:
+    with trace.span("kernel:grouped_agg", rows=n, padded=padded) as sp:
         dev_cols = _upload_columns(
             batch, device_refs & set(batch.columns), padded, wide_ok
         )
         if dev_cols is None:
             sp.set_attr("declined", "nullable_or_out_of_range")
             return None
-
-        def _build_gids(g=group_ids):
-            arr = np.full(padded, seg_pad - 1, dtype=np.int32)
-            arr[:n] = g.astype(np.int32)
-            return jnp.asarray(arr)
-
-        if cache_key_buf is not None:
-            gids_d = DEVICE_CACHE.get_or_put(
-                cache_key_buf, ("gids", padded, seg_pad), _build_gids
-            )
-        else:
-            gids_d = _build_gids()
+        num_groups, seg_pad, first_keys, gids_d = _grouping(
+            [batch.select(key_names)], key_names, padded
+        )
+        sp.set_attr("groups", num_groups)
         mask = _padded_mask(padded, n)
 
         pred_expr = frag.pred
@@ -1463,8 +1493,8 @@ def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[Colu
         for v, (kind, _c) in zip(results, agg_list)
     ]
     return _assemble_grouped_output(
-        plan, frag, key_cols, first_idx, counts, results, agg_list, names,
-        num_groups, first_masked,
+        plan, frag, first_keys, np.arange(num_groups), counts, results,
+        agg_list, names, num_groups, first_masked,
     )
 
 
@@ -2150,7 +2180,6 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
     the array the monolithic upload would have produced, then run the
     monolithic kernel once: bit-identical results with host memory bounded
     by the chunk size, and decode ∥ upload overlap."""
-    from .executor import factorize_group_keys
     from ..utils.device_cache import DEVICE_CACHE
     from ..utils.rpc_meter import METER, device_get as metered_get
 
@@ -2237,18 +2266,13 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
         ]
         return _assemble_global_output(plan, matched, scalar_values, agg_list, names)
 
-    # grouped: keys were collected host-side per chunk (they never ship);
-    # factorize the concatenation exactly like the monolithic path
-    keys_host = ColumnBatch.concat(key_parts)
-    key_cols = [keys_host.column(nm) for nm in key_names]
-    group_ids, num_groups, first_idx = factorize_group_keys(key_cols)
-    seg_pad = 1 << max(4, int(np.ceil(np.log2(num_groups + 1))))
+    # grouped: keys were collected host-side per chunk (they never ship)
+    num_groups, seg_pad, first_keys, gids_d = _grouping(
+        key_parts, key_names, padded
+    )
     proj_exprs = tuple(
         (X.expr_output_name(e), e) for e in _device_projections(frag)
     )
-    gids_arr = np.full(padded, seg_pad - 1, dtype=np.int32)
-    gids_arr[:n_seen] = group_ids.astype(np.int32)
-    gids_d = jnp.asarray(gids_arr)
     with trace.span(
         "kernel:grouped_agg", rows=n_seen, padded=padded, groups=num_groups
     ):
@@ -2266,8 +2290,8 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
         for v, (kind, _c) in zip(results, agg_list)
     ]
     return _assemble_grouped_output(
-        plan, frag, key_cols, first_idx, counts, results, agg_list, names,
-        num_groups, first_masked,
+        plan, frag, first_keys, np.arange(num_groups), counts, results,
+        agg_list, names, num_groups, first_masked,
     )
 
 

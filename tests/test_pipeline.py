@@ -36,6 +36,7 @@ def _write_multifile(root, n_files=5, rows=2000, seed=0):
             "x": rng.uniform(0, 100, n).tolist(),
             "q": rng.integers(1, 50, n).tolist(),
             "d": rng.integers(8000, 10000, n).astype("int32").tolist(),
+            "s": rng.choice(["F", "O"], n).tolist(),
         }
         p = os.path.join(root, "t", f"part-{i}.parquet")
         cio.write_parquet(ColumnBatch.from_pydict(data), p)
@@ -201,6 +202,14 @@ _QUERIES = {
     .group_by("flag")
     .agg(Sum(col("x")).alias("sx"), Avg(col("x")).alias("ax"),
          Count(lit(1)).alias("n")),
+    # grouped concat route on an int64 key
+    "grouped_float_int_key": lambda t: t.filter(col("d") < 9500)
+    .group_by("k")
+    .agg(Sum(col("x")).alias("sx"), Count(lit(1)).alias("n")),
+    # grouped concat route on two string keys (Q1's shape)
+    "grouped_float_two_keys": lambda t: t.filter(col("d") < 9500)
+    .group_by("flag", "s")
+    .agg(Sum(col("x")).alias("sx"), Count(lit(1)).alias("n")),
     # grouped partial route (int sums fold exactly across chunks)
     "grouped_exact": lambda t: t.filter(col("d") < 9500)
     .group_by("k")
@@ -286,6 +295,250 @@ class TestPipelinedBitIdentity:
         )
         assert got == host
         assert REGISTRY.counter("pipeline.aborted").value > before
+
+
+@pytest.fixture()
+def indexed_pipe(pipe_session, monkeypatch):
+    """pipe_session's table behind a covering index: its scans read index
+    chunks through the decoded-chunk cache, whose buffers stay the same
+    objects from query to query, as a served index's do."""
+    from hyperspace_tpu import CoveringIndexConfig, Hyperspace
+
+    session, table = pipe_session
+    hs = Hyperspace(session)
+    hs.create_index(
+        session.read.parquet(table),
+        CoveringIndexConfig("pidx", ["d"], ["k", "flag", "s", "x", "q"]),
+    )
+    session.enable_hyperspace()
+    monkeypatch.setenv("HYPERSPACE_PIPELINE", "1")
+    return session, table, hs
+
+
+class _Work:
+    """What one query cost the grouping: the grouping counters' deltas, the
+    host uploads, and the calls of the O(rows) steps a build runs."""
+
+    def __init__(self, monkeypatch):
+        from hyperspace_tpu.plan import executor
+
+        self.calls = []
+        factorize, concat = executor.factorize_group_keys, ColumnBatch.concat
+
+        def counted_factorize(key_cols):
+            self.calls.append("factorize_group_keys")
+            return factorize(key_cols)
+
+        def counted_concat(batches):
+            self.calls.append("ColumnBatch.concat")
+            return concat(batches)
+
+        monkeypatch.setattr(executor, "factorize_group_keys", counted_factorize)
+        monkeypatch.setattr(ColumnBatch, "concat", staticmethod(counted_concat))
+
+    def run(self, session, table, q):
+        from hyperspace_tpu.utils.rpc_meter import METER
+
+        names = ("pipeline.grouping.builds", "pipeline.grouping.hits")
+        before = [REGISTRY.counter(n).value for n in names]
+        uploads, self.calls[:] = METER.uploads, []
+        out = q(session.read.parquet(table)).to_pydict()
+        self.builds, self.hits = (
+            REGISTRY.counter(n).value - b for n, b in zip(names, before)
+        )
+        self.uploads = METER.uploads - uploads
+        return out
+
+
+def _host_answer(session, table, q):
+    session.set_conf(C.EXEC_TPU_ENABLED, False)
+    try:
+        return q(session.read.parquet(table)).to_pydict()
+    finally:
+        session.set_conf(C.EXEC_TPU_ENABLED, True)
+
+
+def _uncached_answer(session, table, q, monkeypatch):
+    with monkeypatch.context() as m:
+        m.setenv("HYPERSPACE_DEVICE_CACHE_MB", "0")
+        m.setenv("HYPERSPACE_HOST_DERIVED_CACHE_MB", "0")
+        return q(session.read.parquet(table)).to_pydict()
+
+
+def _assert_exact(session, table, q, got, monkeypatch):
+    """Bit for bit the answer, row order included, of the route with both
+    caches off; the host tier's groups and counts, and its float sums to
+    float32 (the host tier numbers its groups in key order, the device
+    tier in first-match order, and the query has no ORDER BY)."""
+    assert _bits(got) == _bits(_uncached_answer(session, table, q, monkeypatch))
+    host = _host_answer(session, table, q)
+    assert list(got) == list(host)
+    keys = [k for k in got if k in ("k", "flag", "s")]
+
+    def by_key(ans):
+        rows = sorted(zip(*[ans[k] for k in keys], range(len(ans[keys[0]]))))
+        order = [r[-1] for r in rows]
+        return {name: [ans[name][i] for i in order] for name in ans}
+
+    got, host = by_key(got), by_key(host)
+    for name in got:
+        if name in ("sx", "ax"):
+            np.testing.assert_allclose(got[name], host[name], rtol=1e-5)
+        else:
+            assert got[name] == host[name]
+
+
+def _grouped_x(d_min, k_max):
+    """A grouped float sum whose literals prune no index file: the index
+    sorts each bucket by d, every bucket spans d's range, k is not indexed."""
+    return lambda t: (
+        t.filter((col("d") >= d_min) & (col("k") < k_max))
+        .group_by("k")
+        .agg(Sum(col("x")).alias("sx"), Count(lit(1)).alias("n"))
+    )
+
+
+class TestGroupingCache:
+    """The grouped aggregate's grouping (group ids, group count, first keys)
+    is a cached derivation of its key chunk buffers."""
+
+    @pytest.mark.parametrize("pipeline", ["1", "0"])
+    @pytest.mark.parametrize(
+        "qname",
+        ["grouped_float", "grouped_float_int_key", "grouped_float_two_keys"],
+    )
+    def test_a_warm_repeat_reuses_the_grouping(
+        self, indexed_pipe, monkeypatch, qname, pipeline
+    ):
+        session, table, _hs = indexed_pipe
+        monkeypatch.setenv("HYPERSPACE_PIPELINE", pipeline)
+        q = _QUERIES[qname]
+        work = _Work(monkeypatch)
+        first = work.run(session, table, q)
+        assert (work.builds, work.hits) == (1, 0)
+        assert "factorize_group_keys" in work.calls
+        second = work.run(session, table, q)
+        assert (work.builds, work.hits) == (0, 1)
+        assert work.calls == []  # no key concat, no factorize
+        assert work.uploads == 0  # the gids, as the columns, stay resident
+        assert _bits(second) == _bits(first)
+        _assert_exact(session, table, q, second, monkeypatch)
+
+    @pytest.mark.parametrize("literals", [(9000, 40), (8000, 5)])
+    def test_a_new_literal_reuses_the_grouping(
+        self, indexed_pipe, monkeypatch, literals
+    ):
+        session, table, _hs = indexed_pipe
+        work = _Work(monkeypatch)
+        warm = work.run(session, table, _grouped_x(8000, 40))
+        assert work.builds == 1 and len(warm["k"]) == 40
+        q = _grouped_x(*literals)
+        got = work.run(session, table, q)
+        assert (work.builds, work.hits) == (0, 1)
+        assert work.calls == []
+        _assert_exact(session, table, q, got, monkeypatch)
+        if literals[1] == 5:  # keys 5-39 match no row: their groups drop out
+            assert sorted(got["k"]) == list(range(5))
+        else:  # the first match of each group, and so the row order, moved
+            assert sorted(got["k"]) == sorted(warm["k"])
+            assert got["k"] != warm["k"]
+
+    @pytest.mark.parametrize("change", ["chunk_cache_cleared", "append"])
+    def test_new_key_buffers_build_again(
+        self, indexed_pipe, monkeypatch, change
+    ):
+        session, table, hs = indexed_pipe
+        q = _QUERIES["grouped_float_two_keys"]
+        work = _Work(monkeypatch)
+        work.run(session, table, q)
+        if change == "append":
+            extra = os.path.join(str(os.path.dirname(table)), "extra")
+            _write_multifile(extra, n_files=1, rows=700, seed=9)
+            os.replace(
+                os.path.join(extra, "t", "part-0.parquet"),
+                os.path.join(table, "part-9.parquet"),
+            )
+            hs.append("pidx", session.read.parquet(table))
+        else:
+            cio._INDEX_CHUNK_CACHE.clear()
+        got = work.run(session, table, q)
+        assert (work.builds, work.hits) == (1, 0)
+        _assert_exact(session, table, q, got, monkeypatch)
+
+    @pytest.mark.parametrize(
+        "budget",
+        ["HYPERSPACE_HOST_DERIVED_CACHE_MB", "HYPERSPACE_DEVICE_CACHE_MB"],
+    )
+    def test_a_budget_of_0_caches_no_grouping(
+        self, indexed_pipe, monkeypatch, budget
+    ):
+        from hyperspace_tpu.utils import device_cache
+
+        session, table, _hs = indexed_pipe
+        q = _QUERIES["grouped_float_two_keys"]
+        cache = (
+            device_cache.HOST_DERIVED_CACHE
+            if budget == "HYPERSPACE_HOST_DERIVED_CACHE_MB"
+            else device_cache.DEVICE_CACHE
+        )
+        work = _Work(monkeypatch)
+        monkeypatch.setenv(budget, "0")
+        keys = set(cache._d)
+        answers = []
+        for _ in range(2):
+            answers.append(work.run(session, table, q))
+            assert (work.builds, work.hits) == (1, 0)
+        assert not {
+            k[1][0] for k in set(cache._d) - keys
+        } & {"grouping", "gids"}
+        monkeypatch.delenv(budget)
+        assert _bits(answers[0]) == _bits(answers[1])
+        assert _bits(answers[1]) == _bits(work.run(session, table, q))
+        _assert_exact(session, table, q, answers[1], monkeypatch)
+
+    def test_a_nullable_key_caches_under_its_validity_buffer(self, monkeypatch):
+        """A key's NULL mask joins the cache key: the same data under
+        another mask is another grouping, NULL a group of its own."""
+        from hyperspace_tpu.columnar.table import Column
+        from hyperspace_tpu.plan import tpu_exec
+        from hyperspace_tpu.plan.executor import factorize_group_keys
+
+        data = [np.array([3, 1, 3, 2]), np.array([1, 2, 2, 3])]
+        masks = [
+            np.array([True, False, True, True]),
+            np.array([True, True, False, True]),
+        ]
+        work = _Work(monkeypatch)
+
+        def grouping(valid):
+            parts = [
+                ColumnBatch({"k": Column(d, "int64", v)})
+                for d, v in zip(data, valid)
+            ]
+            before = REGISTRY.counter("pipeline.grouping.builds").value
+            got = tpu_exec._grouping(parts, ["k"], 16)
+            built = REGISTRY.counter("pipeline.grouping.builds").value - before
+            want_ids, want_n, want_first = factorize_group_keys(
+                [ColumnBatch.concat(parts).column("k")]
+            )
+            num_groups, seg_pad, first_keys, gids_d = got
+            assert num_groups == want_n
+            assert seg_pad == 16
+            want_keys = ColumnBatch.concat(parts).column("k").take(want_first)
+            assert list(first_keys[0].decode()) == list(want_keys.decode())
+            gids = np.asarray(gids_d)
+            assert list(gids[:8]) == list(want_ids)
+            assert list(gids[8:]) == [15] * 8
+            return built, gids_d
+
+        built, gids = grouping(masks)
+        assert built == 1 and "factorize_group_keys" in work.calls
+        work.calls.clear()
+        again, gids_again = grouping(masks)
+        assert again == 0 and gids_again is gids
+        assert "factorize_group_keys" not in work.calls
+        other, _ = grouping([masks[0], ~masks[1]])
+        assert other == 1
 
 
 class TestKernelCacheCrossQuery:
