@@ -24,11 +24,10 @@ authority; in-memory scans have no stable identity at all).
 Incremental view maintenance (view_maintenance.py): an ``hs.append``
 publishes a new snapshot whose content is old ∪ delta, so the exact key
 misses — but entries over exactly-foldable fragments (global
-count/min/max/int-sum aggregates, the PR-2 'partial' route discipline) are
-not recomputed from scratch. The miss path finds a same-structure entry
-whose file set is a subset of the new plan's, executes the fragment over
-ONLY the delta files, and folds:  ``result_vM = result_vN ⊕ agg(delta)``.
-Hot aggregates stay warm across sustained ingest at delta cost.
+count/min/max/int-sum aggregates) are not recomputed from scratch. The miss
+path finds a same-structure entry whose file set is a subset of the new
+plan's, executes the fragment over ONLY the delta files, and folds:
+``result_vM = result_vN ⊕ agg(delta)``. Hot aggregates stay warm across sustained ingest at delta cost.
 
 Modes (``HYPERSPACE_RESULT_CACHE``): ``0`` off (default — the repo's
 correctness gates pin per-run execution effects, so caching is an explicit

@@ -1,11 +1,11 @@
 """Skew-aware bucket→device placement for mesh-sharded execution.
 
 The covering indexes bucketize both join sides identically, so bucket work
-units (band-wave items in ``plan/device_join``, streamed chunks in
-``plan/tpu_exec``) are independent: any device may compute any unit and the
-host-side fold reassembles results in bucket/chunk order, bit-identical to
-single-device execution. That independence is what this module exploits —
-it only decides *where* each unit runs, never *what* runs.
+units (band-wave items in ``plan/device_join``) are independent: any device
+may compute any unit and the host-side fold reassembles results in bucket
+order, bit-identical to single-device execution. That independence is what
+this module exploits — it only decides *where* each unit runs, never *what*
+runs.
 
 Placement policy (JSPIM-style skew awareness): the join memory planner's
 per-bucket footer-stat estimates predict each bucket's decoded bytes. A
@@ -164,32 +164,3 @@ def plan_for_strategy(strategy) -> Optional[Placement]:
     if strategy is not None:
         estimates = {b: est[1] for b, est in strategy.estimates.items()}
     return plan_bucket_placement(estimates, devices, _query_offset())
-
-
-class ChunkPlacer:
-    """Greedy online least-loaded placement for streamed scan/agg chunks,
-    where per-chunk sizes are only known as chunks decode. Deterministic
-    in chunk arrival order (which the streaming executor fixes), so the
-    same query places the same way every run."""
-
-    __slots__ = ("devices", "_loads", "_offset")
-
-    def __init__(self, devices: list, offset: int = 0):
-        self.devices = devices
-        self._loads = [0] * len(devices)
-        self._offset = offset
-
-    def next(self, nbytes: int):
-        """(ordinal, device) for the next chunk; charges its bytes."""
-        n = len(self.devices)
-        o = min(range(n), key=lambda d: (self._loads[d], (d - self._offset) % n))
-        self._loads[o] += max(int(nbytes), 1)
-        return o, self.devices[o]
-
-
-def chunk_placer() -> Optional[ChunkPlacer]:
-    """A fresh ChunkPlacer when the mesh is on; None otherwise."""
-    devices = mesh_devices()
-    if len(devices) < 2:
-        return None
-    return ChunkPlacer(devices, _query_offset())

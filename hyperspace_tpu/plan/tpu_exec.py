@@ -21,7 +21,6 @@ Literals are kernel arguments (``slot_literals``): one kernel per shape.
 from __future__ import annotations
 
 import time
-from collections import deque
 from functools import partial
 from typing import Optional
 
@@ -45,7 +44,6 @@ from .kernel_cache import (
 from .nodes import Aggregate, FileScan, Filter, LogicalPlan, Project
 from ..columnar.table import Column, ColumnBatch, STRING
 from ..exceptions import HyperspaceError
-from ..serve.context import check_cancelled as _serve_check_cancelled
 from ..telemetry import attribution as _attr
 from ..telemetry import trace
 from ..telemetry.metrics import REGISTRY
@@ -507,8 +505,7 @@ def _project_identity(project: Project, name: str) -> bool:
     return False
 
 
-def _upload_columns(batch: ColumnBatch, names, padded: int, wide_ok: frozenset = frozenset(),
-                    device=None):
+def _upload_columns(batch: ColumnBatch, names, padded: int, wide_ok: frozenset = frozenset()):
     """Zero-padded device upload of the named columns; None when any column
     is nullable or exceeds the device's 32-bit integer range (host path).
     Columns in `wide_ok` (full-range int64 referenced only in literal
@@ -516,17 +513,9 @@ def _upload_columns(batch: ColumnBatch, names, padded: int, wide_ok: frozenset =
 
     Device copies are cached by source-buffer identity (utils/device_cache)
     so repeated queries over the same index chunks skip the host->device
-    transfer entirely. ``device`` commits the upload to a placed mesh
-    device under its own cache entry; None keeps the historical
-    uncommitted default-device path and its exact cache keys."""
+    transfer entirely."""
     from ..ops.hashing import split64_np
     from ..utils.device_cache import DEVICE_CACHE
-
-    def _commit(x):
-        return jnp.asarray(x) if device is None else jax.device_put(x, device)
-
-    def _dtag(t: tuple) -> tuple:
-        return t if device is None else t + (f"d{device.id}",)
 
     n = batch.num_rows
     dev_cols = {}
@@ -546,38 +535,33 @@ def _upload_columns(batch: ColumnBatch, names, padded: int, wide_ok: frozenset =
                 hi_p[:n] = hi
                 lo_p = np.zeros(padded, np.uint32)
                 lo_p[:n] = lo.view(np.uint32)
-                return (_commit(hi_p), _commit(lo_p))
+                return (jnp.asarray(hi_p), jnp.asarray(lo_p))
 
             dev_cols[name] = DEVICE_CACHE.get_or_put(
-                col.data, _dtag(("wide", padded)), _build_wide
+                col.data, ("wide", padded), _build_wide
             )
             continue
 
         def _build(data=col.data):
             arr = np.zeros(padded, dtype=_device_dtype(data.dtype))
             arr[:n] = data.astype(arr.dtype)
-            return _commit(arr)
+            return jnp.asarray(arr)
 
         dev_cols[name] = DEVICE_CACHE.get_or_put(
-            col.data, _dtag(("pad", padded)), _build
+            col.data, ("pad", padded), _build
         )
     return dev_cols
 
 
-def _padded_mask(padded: int, n: int, device=None):
+def _padded_mask(padded: int, n: int):
     """Device copy of the valid-rows mask [0..n) within [0..padded): a fresh
     upload per query costs a host->device round trip, and the
     arrays are `padded` device bytes each — so they live in the budgeted
     device LRU, not an unbounded side cache."""
     from ..utils.device_cache import DEVICE_CACHE
 
-    if device is None:
-        return DEVICE_CACHE.get_or_put_keyed(
-            ("mask", padded, n), lambda: jnp.asarray(np.arange(padded) < n)
-        )
     return DEVICE_CACHE.get_or_put_keyed(
-        ("mask", padded, n, f"d{device.id}"),
-        lambda: jax.device_put(np.arange(padded) < n, device),
+        ("mask", padded, n), lambda: jnp.asarray(np.arange(padded) < n)
     )
 
 
@@ -1677,31 +1661,27 @@ def try_execute_select(plan: LogicalPlan, session) -> Optional[ColumnBatch]:
 
 
 # ---------------------------------------------------------------------------
-# pipelined chunk streaming (scan ∥ upload ∥ dispatch)
+# pipelined chunk streaming (scan ∥ upload, then one kernel)
 # ---------------------------------------------------------------------------
 #
 # Multi-file scans execute as an ordered stream of file-group chunks: the IO
-# pool decodes chunk N+2 while chunk N+1's columns upload and chunk N's
-# kernel runs (jax dispatch is async; a bounded deque of in-flight results
-# is the double buffer). Two routes, both bit-identical to the monolithic
-# path by construction:
+# pool decodes the next chunks while this one's columns upload. The chunks
+# concatenate device-side into exactly the array the monolithic upload would
+# have produced, and the monolithic kernel runs once on it: bit-identical to
+# the monolithic path by construction, with host memory bounded by the chunk
+# size. The chunk uploads and the concatenated copy both live in the device
+# cache, so a warm repeat over cache-stable index chunks uploads nothing.
 #
-#   partial — every aggregate folds exactly across chunks (count, min, max,
-#     int sum, provably-int avg): each chunk runs the SAME fused kernel the
-#     monolithic path would build (shared fingerprint → shared executable)
-#     and the host folds the exact partials. The full batch never exists,
-#     on host or device.
-#   concat — float sums/avgs, whose f32 partial sums would not be
-#     decomposition-invariant: chunks upload individually and concatenate
-#     device-side into the exact array the monolithic upload would have
-#     produced, then the monolithic kernel runs once. The full batch exists
-#     only in device memory; host memory stays chunk-bounded.
+# The stream declines (`pipeline.declined`, monolithic read) when the
+# fragment's literals need Wide64 or its predicate references a string
+# column (chunk dictionaries differ, so no one code rewrite covers the
+# concatenated array); an int sum over the exact-sum row cap goes to the
+# host tier either way. It aborts to the monolithic path on a nullable
+# chunk, an out-of-32-bit-range int64 chunk or cross-file dtype drift.
 #
 # `HYPERSPACE_PIPELINE=0` disables the streamer (legacy monolithic path);
-# `HYPERSPACE_PIPELINE=serial` keeps the staged executor but removes every
-# overlap (the debug mode for isolating pipelining effects). Any abort —
-# nullable chunk, out-of-32-bit-range int64, cross-file dtype drift,
-# non-rewritable string predicate — falls back to the monolithic path.
+# `HYPERSPACE_PIPELINE=serial` keeps the staged executor but removes the IO
+# overlap (the debug mode for isolating pipelining effects).
 
 def _pipeline_enabled() -> bool:
     return env.env_str("HYPERSPACE_PIPELINE") != "0"
@@ -1711,78 +1691,22 @@ def _pipeline_overlap() -> bool:
     return env.env_str("HYPERSPACE_PIPELINE") != "serial"
 
 
-def _pipeline_depth() -> int:
-    """In-flight chunk dispatches before the consumer blocks on a fetch
-    (``HYPERSPACE_PIPELINE_DEPTH``, default 2 = double buffering)."""
-    try:
-        return max(1, env.env_int("HYPERSPACE_PIPELINE_DEPTH"))
-    except ValueError:
-        return 2
-
-
-def _provably_int_expr(e: Expr, frag: "_Fragment") -> bool:
-    """True only when e certainly traces to an integer on device (the
-    strict dual of _maybe_int_expr): drives the partial route's exact-fold
-    screen, where a float mistaken for int would break bit-identity."""
-    if isinstance(e, Alias):
-        return _provably_int_expr(e.child, frag)
-    if isinstance(e, X.Div):
+def _fragment_streams(frag: "_Fragment") -> bool:
+    """False = decline streaming (monolithic path): Wide64 literals, or a
+    predicate over a string column."""
+    if not _fragment_literals_fit(frag):
         return False
-    if isinstance(e, X.Lit):
-        return isinstance(e.value, (int, np.integer)) and not isinstance(
-            e.value, bool
-        )
-    if isinstance(e, X.Col):
-        sch = frag.scan.schema
-        if e.name in sch.names:
-            dt = sch.field(e.name).dtype
-            return dt.startswith("int") or dt == "date32"
-        if frag.project is not None:
-            for p in frag.project.exprs:
-                if X.expr_output_name(p) == e.name:
-                    return _provably_int_expr(p, frag)
-        return False
-    children = e.children()
-    if not children or not isinstance(e, (X.Add, X.Sub, X.Mul)):
-        return False
-    return all(_provably_int_expr(c, frag) for c in children)
-
-
-def _stream_route(frag: "_Fragment", plan) -> Optional[str]:
-    """'partial' | 'concat' | None (decline streaming, monolithic path)."""
-    from .executor import _unwrap_agg
-
-    if not _fragment_literals_fit(frag):  # Wide64 never streams
-        return None
-    schema = plan.schema
-    exact = True
-    for e in frag.agg.agg_exprs:
-        nm, agg = _unwrap_agg(e)
-        if isinstance(agg, (X.Count, X.Min, X.Max)):
-            continue
-        if isinstance(agg, X.Sum) and schema.field(nm).dtype.startswith("int"):
-            continue
-        if isinstance(agg, X.Avg) and _provably_int_expr(agg.child, frag):
-            continue
-        exact = False
-        break
-    if exact:
-        return "partial"
-    # the concat route ships predicate columns as one device array, which a
-    # per-chunk string-code rewrite cannot produce (dictionaries differ)
-    if frag.pred is not None:
-        scols = {f.name for f in frag.scan.schema if f.dtype == STRING}
-        if frag.pred.references() & scols:
-            return None
-    return "concat"
+    if frag.pred is None:
+        return True
+    scols = {f.name for f in frag.scan.schema if f.dtype == STRING}
+    return frag.pred.references().isdisjoint(scols)
 
 
 def _execute_streaming(frag: "_Fragment", scan, plan, session) -> Optional[ColumnBatch]:
     """Streamed execution of a supported fragment over a streamable scan;
     None = fall back to the monolithic read (which re-screens and may still
     run on device, with Wide64, or decline to the host tier)."""
-    route = _stream_route(frag, plan)
-    if route is None:
+    if not _fragment_streams(frag):
         REGISTRY.counter("pipeline.declined").inc()
         return None
     from .executor import iter_scan_chunks, resolve_scan_pruning
@@ -1797,8 +1721,9 @@ def _execute_streaming(frag: "_Fragment", scan, plan, session) -> Optional[Colum
     # go to the host tier either way
     if _has_int_sum(frag, plan) and _pad_pow2(n_total) > _INT_SUM_ROW_CAP:
         return None
-    overlap = _pipeline_overlap()
-    chunks = iter_scan_chunks(scan, overlap=overlap, selection=selection)
+    chunks = iter_scan_chunks(
+        scan, overlap=_pipeline_overlap(), selection=selection
+    )
     # abort-and-replan monitor: pass-through unless HYPERSPACE_ADAPTIVE is
     # on AND this scan's prune stage underdelivered its prediction
     from . import adaptive
@@ -1806,17 +1731,11 @@ def _execute_streaming(frag: "_Fragment", scan, plan, session) -> Optional[Colum
     chunks = adaptive.monitor_scan_chunks(chunks, scan, selection)
     t0 = time.perf_counter()
     with trace.span(
-        f"pipeline:{route}", rows=n_total, files=len(scan.files),
+        "pipeline:concat", rows=n_total, files=len(scan.files),
         grouped=bool(frag.agg.group_exprs),
     ) as sp:
         try:
-            if route == "partial":
-                if frag.agg.group_exprs:
-                    out = _stream_grouped_partial(frag, plan, chunks, overlap)
-                else:
-                    out = _stream_global_partial(frag, plan, chunks, overlap)
-            else:
-                out = _stream_concat(frag, plan, chunks, n_total)
+            out = _stream_concat(frag, plan, chunks, n_total)
         finally:
             chunks.close()  # stop IO read-ahead on abort
         if out is None:
@@ -1828,337 +1747,6 @@ def _execute_streaming(frag: "_Fragment", scan, plan, session) -> Optional[Colum
                 (time.perf_counter() - t0) * 1000
             )
     return out
-
-
-def _chunk_pred(frag: "_Fragment", batch: ColumnBatch) -> tuple[Optional[Expr], bool]:
-    """(predicate for this chunk, ok): string comparisons re-encode against
-    THIS chunk's dictionaries; ok=False means a string reference survives in
-    a non-rewritable position (abort the stream)."""
-    pred = frag.pred
-    if pred is None:
-        return None, True
-    scols = {
-        f.name for f in frag.scan.schema if f.dtype == STRING
-    } & pred.references()
-    if not scols:
-        return pred, True
-    rewritten = _encode_string_predicates(pred, batch, scols)
-    return rewritten, rewritten is not None
-
-
-def _stream_global_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch]:
-    """Per-chunk fused kernels + exact host folds for a global aggregate."""
-    from ..utils.rpc_meter import METER, device_get as metered_get
-
-    agg_list, names = _agg_list_names(frag)
-    proj_exprs = (
-        tuple((X.expr_output_name(e), e) for e in frag.project.exprs)
-        if frag.project is not None
-        else ()
-    )
-    device_refs = _device_refs(frag)
-    depth = _pipeline_depth() if overlap else 0
-    pending: deque = deque()
-    state = {"matched": 0}
-    accs: list = [None] * len(agg_list)
-
-    def fold(res) -> None:
-        with trace.span("pipeline:fetch", annotate=False), _attr.phase("fold"):
-            matched, results = metered_get(res)
-        state["matched"] += int(matched)
-        for i, (v, (kind, _c)) in enumerate(zip(results, agg_list)):
-            if kind == "count":
-                continue
-            if isinstance(v, tuple):  # exact int chunk sums
-                s = _combine_int_chunks(v)
-                accs[i] = s if accs[i] is None else accs[i] + s
-            elif kind == "min":
-                v = np.asarray(v)
-                accs[i] = v if accs[i] is None else np.minimum(accs[i], v)
-            elif kind == "max":
-                v = np.asarray(v)
-                accs[i] = v if accs[i] is None else np.maximum(accs[i], v)
-            else:  # unreachable on this route (floats take the concat route)
-                raise HyperspaceError(f"non-foldable {kind} on partial route")
-
-    expect_dtypes: dict = {}
-    from ..parallel import placement as mesh_placement
-
-    placer = mesh_placement.chunk_placer()
-    for chunk in chunks:
-        batch = chunk.batch
-        n = batch.num_rows
-        if n == 0:
-            continue
-        with trace.span(
-            "pipeline:chunk", index=chunk.index, rows=n,
-            decode_ms=round(chunk.decode_s * 1000, 3),
-        ):
-            if not _chunk_dtypes_ok(batch, device_refs, expect_dtypes):
-                return None
-            pred, ok = _chunk_pred(frag, batch)
-            if not ok:
-                return None
-            padded = _pad_pow2(n)
-            device = None
-            if placer is not None:
-                ordinal, device = placer.next(padded * max(len(device_refs), 1) * 8)
-                with trace.span("mesh:dispatch", device=ordinal, rows=n):
-                    pass  # zero-width marker: where this chunk was placed
-            dev_cols = _upload_columns(
-                batch, device_refs & set(batch.columns), padded, device=device
-            )
-            if dev_cols is None:
-                return None  # nullable / out-of-range chunk: monolithic path
-            mask = _padded_mask(padded, n, device=device)
-            kernel, lits = _fused_kernel(pred, proj_exprs, agg_list, dev_cols)
-            METER.record_dispatch()
-            with _attr.phase("enqueue"):
-                pending.append(kernel(dev_cols, mask, lits))
-            REGISTRY.counter("pipeline.chunks").inc()
-        while len(pending) > depth:
-            fold(pending.popleft())
-    while pending:
-        # a cancel mid-drain stops fetching the remaining in-flight
-        # device results (serving-layer cancellation contract)
-        _serve_check_cancelled()
-        fold(pending.popleft())
-
-    matched = state["matched"]
-    scalar_values = []
-    for acc, (kind, _c) in zip(accs, agg_list):
-        if kind == "count":
-            scalar_values.append(np.int64(matched))
-        elif kind == "avg":
-            scalar_values.append(acc / max(matched, 1))
-        else:
-            scalar_values.append(np.asarray(acc) if acc is not None else np.float64(0))
-    return _assemble_global_output(plan, matched, scalar_values, agg_list, names)
-
-
-_FIRST_SENTINEL = 2**31 - 1
-
-
-def _key_tuple_rows(key_cols: list[Column], idxs: np.ndarray) -> list[tuple]:
-    """Hashable group-key value tuples for the given rows (NULL -> None);
-    the cross-chunk group identity the partial route folds on."""
-    out = []
-    for i in idxs:
-        t = []
-        for kc in key_cols:
-            if kc.validity is not None and not kc.validity[i]:
-                t.append(None)
-            elif kc.dtype == STRING:
-                t.append(kc.dictionary[int(kc.data[i])] if kc.dictionary else "")
-            else:
-                t.append(kc.data[i].item())
-        out.append(tuple(t))
-    return out
-
-
-def _grown(arr: Optional[np.ndarray], size: int, fill, dtype) -> np.ndarray:
-    if arr is None:
-        return np.full(size, fill, dtype=dtype)
-    if len(arr) >= size:
-        return arr
-    out = np.full(size, fill, dtype=arr.dtype)
-    out[: len(arr)] = arr
-    return out
-
-
-def _stream_grouped_partial(frag, plan, chunks, overlap) -> Optional[ColumnBatch]:
-    """Per-chunk grouped kernels + exact host folds. Each chunk factorizes
-    its own keys (local gids, local seg_pad); the host maintains the global
-    group table in first-appearance order and folds per-group partials
-    through it. Output ordering follows the global first-passing-row index,
-    exactly like the monolithic assembly."""
-    from .executor import factorize_group_keys
-    from ..utils.device_cache import DEVICE_CACHE
-    from ..utils.rpc_meter import METER, device_get as metered_get
-
-    agg_list, names = _agg_list_names(frag)
-    proj_exprs = tuple(
-        (X.expr_output_name(e), e) for e in _device_projections(frag)
-    )
-    key_names = [e.name for e in frag.agg.group_exprs]
-    device_refs = _device_refs(frag)
-    depth = _pipeline_depth() if overlap else 0
-    pending: deque = deque()
-
-    key_index: dict = {}
-    key_slices: list[ColumnBatch] = []
-    counts_g: Optional[np.ndarray] = None
-    first_g: Optional[np.ndarray] = None
-    accs: list = [None] * len(agg_list)
-
-    def fold(entry) -> None:
-        nonlocal counts_g, first_g
-        gmap, num_l, offset, res = entry
-        with trace.span("pipeline:fetch", annotate=False), _attr.phase("fold"):
-            counts_l, first_l, results = metered_get(res)
-        size = len(key_index)
-        counts_g = _grown(counts_g, size, 0, np.int64)
-        first_g = _grown(first_g, size, np.iinfo(np.int64).max, np.int64)
-        counts_l = np.asarray(counts_l)[:num_l].astype(np.int64)
-        np.add.at(counts_g, gmap, counts_l)
-        fl = np.asarray(first_l)[:num_l].astype(np.int64)
-        valid = fl < _FIRST_SENTINEL
-        if valid.any():
-            np.minimum.at(first_g, gmap[valid], fl[valid] + offset)
-        for i, (v, (kind, _c)) in enumerate(zip(results, agg_list)):
-            if kind == "count":
-                continue
-            if isinstance(v, tuple):  # exact int chunk sums per group
-                s = _combine_int_chunks(v)[:num_l]
-                accs[i] = _grown(accs[i], size, 0, np.int64)
-                np.add.at(accs[i], gmap, s)
-            else:
-                v = np.asarray(v)[:num_l]
-                if kind == "min":
-                    accs[i] = _grown(accs[i], size, _np_extreme(v.dtype, True), v.dtype)
-                    np.minimum.at(accs[i], gmap, v)
-                elif kind == "max":
-                    accs[i] = _grown(accs[i], size, _np_extreme(v.dtype, False), v.dtype)
-                    np.maximum.at(accs[i], gmap, v)
-                else:
-                    raise HyperspaceError(f"non-foldable {kind} on partial route")
-        # groups discovered after this chunk dispatched: extend with identities
-        for i, (kind, _c) in enumerate(agg_list):
-            if accs[i] is not None and len(accs[i]) < size:
-                fill = (
-                    _np_extreme(accs[i].dtype, kind == "min")
-                    if kind in ("min", "max")
-                    else 0
-                )
-                accs[i] = _grown(accs[i], size, fill, accs[i].dtype)
-
-    expect_dtypes: dict = {}
-    row_offset = 0
-    from ..parallel import placement as mesh_placement
-
-    placer = mesh_placement.chunk_placer()
-    for chunk in chunks:
-        batch = chunk.batch
-        n = batch.num_rows
-        if n == 0:
-            continue
-        with trace.span(
-            "pipeline:chunk", index=chunk.index, rows=n,
-            decode_ms=round(chunk.decode_s * 1000, 3),
-        ):
-            if not _chunk_dtypes_ok(batch, device_refs, expect_dtypes):
-                return None
-            pred, ok = _chunk_pred(frag, batch)
-            if not ok:
-                return None
-            key_cols = [batch.column(nm) for nm in key_names]
-            gids_l, num_l, first_idx_l = factorize_group_keys(key_cols)
-            tuples = _key_tuple_rows(key_cols, first_idx_l)
-            gmap = np.empty(num_l, dtype=np.int64)
-            new_rows = []
-            for j, t in enumerate(tuples):
-                g = key_index.get(t)
-                if g is None:
-                    g = len(key_index)
-                    key_index[t] = g
-                    new_rows.append(first_idx_l[j])
-                gmap[j] = g
-            if new_rows:
-                key_slices.append(
-                    ColumnBatch(
-                        {
-                            nm: kc.take(np.asarray(new_rows, dtype=np.int64))
-                            for nm, kc in zip(key_names, key_cols)
-                        }
-                    )
-                )
-            seg_pad = 1 << max(4, int(np.ceil(np.log2(num_l + 1))))
-            padded = _pad_pow2(n)
-            device = None
-            if placer is not None:
-                ordinal, device = placer.next(padded * max(len(device_refs), 1) * 8)
-                with trace.span("mesh:dispatch", device=ordinal, rows=n):
-                    pass  # zero-width marker: where this chunk was placed
-            dev_cols = _upload_columns(
-                batch, device_refs & set(batch.columns), padded, device=device
-            )
-            if dev_cols is None:
-                return None
-            gids_arr = np.full(padded, seg_pad - 1, dtype=np.int32)
-            gids_arr[:n] = gids_l.astype(np.int32)
-            if len(key_cols) == 1 and key_cols[0].validity is None:
-                # cache-stable chunk key buffer: repeat queries reuse the
-                # device gids upload (same contract as the monolithic path)
-                gids_tag = ("gids", padded, seg_pad) if device is None else \
-                    ("gids", padded, seg_pad, f"d{device.id}")
-                gids_d = DEVICE_CACHE.get_or_put(
-                    key_cols[0].data, gids_tag,
-                    lambda: jnp.asarray(gids_arr) if device is None
-                    else jax.device_put(gids_arr, device),
-                )
-            else:
-                gids_d = jnp.asarray(gids_arr) if device is None else \
-                    jax.device_put(gids_arr, device)
-            mask = _padded_mask(padded, n, device=device)
-            kernel, lits = _grouped_kernel(
-                seg_pad, pred, proj_exprs, agg_list, dev_cols
-            )
-            METER.record_dispatch()
-            with _attr.phase("enqueue"):
-                out = kernel(dev_cols, gids_d, mask, lits)
-            pending.append((gmap, num_l, row_offset, out))
-            REGISTRY.counter("pipeline.chunks").inc()
-        row_offset += n
-        while len(pending) > depth:
-            fold(pending.popleft())
-    while pending:
-        # a cancel mid-drain stops fetching the remaining in-flight
-        # device results (serving-layer cancellation contract)
-        _serve_check_cancelled()
-        fold(pending.popleft())
-    if not key_index:
-        return None  # every chunk was empty: let the monolithic path decide
-
-    num_groups = len(key_index)
-    counts_g = _grown(counts_g, num_groups, 0, np.int64)
-    first_g = _grown(first_g, num_groups, np.iinfo(np.int64).max, np.int64)
-    keys_batch = ColumnBatch.concat(key_slices)
-    keep = counts_g > 0
-    idx = np.nonzero(keep)[0]
-    order = np.argsort(first_g[keep], kind="stable")
-    out_cols: dict[str, Column] = {}
-    for e, nm in zip(frag.agg.group_exprs, key_names):
-        kept = keys_batch.column(nm).take(idx)
-        out_cols[X.expr_output_name(e)] = kept.take(order)
-    schema = plan.schema
-    for (name, acc), (kind, _c) in zip(zip(names, accs), agg_list):
-        f = schema.field(name)
-        if kind == "count":
-            vals = counts_g
-        elif kind == "avg":
-            vals = acc / np.maximum(counts_g, 1)
-        else:
-            vals = _grown(
-                acc, num_groups,
-                _np_extreme(acc.dtype, kind == "min") if acc is not None and kind in ("min", "max") else 0,
-                np.int64 if acc is None else acc.dtype,
-            )
-        np_val = np.asarray(vals)[keep][order]
-        if kind == "count":
-            out_cols[name] = Column(np_val.astype(np.int64), "int64")
-        elif f.dtype in ("int64", "int32", "int16", "int8"):
-            out_cols[name] = Column(np_val.astype(np.dtype(f.dtype)), f.dtype)
-        else:
-            out_cols[name] = Column(np_val.astype(np.float64), "float64")
-    return ColumnBatch(out_cols)
-
-
-def _np_extreme(dtype, want_max: bool):
-    d = np.dtype(dtype)
-    if np.issubdtype(d, np.integer):
-        info = np.iinfo(d)
-        return info.max if want_max else info.min
-    return np.inf if want_max else -np.inf
 
 
 def _chunk_dtypes_ok(batch: ColumnBatch, refs, expect: dict) -> bool:

@@ -19,9 +19,9 @@ Span taxonomy (see docs/observability.md):
           compile:<kind>  a kernel-cache build (wraps the closure in
                           jax.jit; the XLA compile runs at the first call
                           and is charged to the ``compile`` phase)
-        pipeline:<route>  one streamed fragment (partial | concat)
-          pipeline:chunk  one chunk's upload + dispatch (decode_ms attr)
-          pipeline:fetch  one in-order partial fold (carries RPC deltas)
+        pipeline:concat   one streamed fragment: chunk uploads, then one
+                          kernel over their device-side concatenation
+          pipeline:chunk  one chunk's upload (decode_ms attr)
         join:load         one streamed bucket-pair load (consumer-side wait)
         join:plan         per-bucket strategy selection from footer stats
         join:band         one band wave's stacked upload + kernel dispatch

@@ -216,12 +216,6 @@ _register(
     "plan/tpu_exec.py", choices=("1", "serial", "0"),
 )
 _register(
-    "HYPERSPACE_PIPELINE_DEPTH", "int", 2,
-    "Dispatch window of the chunk streamer (uploads in flight ahead of the "
-    "device).",
-    "plan/tpu_exec.py",
-)
-_register(
     "HYPERSPACE_PRUNE", "mode", "1",
     "Predicate-driven index pruning: 1 = on (default), 0 = off, verify = "
     "prune AND read full, raise on post-filter divergence (debug).",

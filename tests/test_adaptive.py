@@ -547,7 +547,9 @@ def _events(i, n_per, base):
     return {
         "ev_k": list(range(base, base + n_per)),
         "ev_id": [10_000_000 + base + j for j in range(n_per)],
-        "ev_cat": [f"c{(base + j) % 3}" for j in range(n_per)],
+        # an int category: a string predicate would decline the stream,
+        # and the abort monitor watches streamed scans only
+        "ev_cat": [(base + j) % 3 for j in range(n_per)],
         "ev_v": rng.uniform(0, 1, n_per).tolist(),
     }
 
@@ -590,7 +592,7 @@ def _sidecars(session, name="ev_idx"):
 def _agg_q(session, src):
     return (
         session.read.parquet(src)
-        .filter(col("ev_cat") == "c1")
+        .filter(col("ev_cat") == 1)
         .group_by("ev_cat")
         .agg(
             Count(lit(1)).alias("n"),

@@ -103,19 +103,10 @@ class TestPlacerBinPacking:
     def test_single_device_mesh_is_none(self):
         assert placement.plan_bucket_placement({0: MB}, devices=["d0"]) is None
 
-    def test_chunk_placer_balances_greedily(self):
-        cp = placement.ChunkPlacer(DEV8[:4])
-        ordinals = [cp.next(100)[0] for _ in range(8)]
-        assert sorted(ordinals) == [0, 0, 1, 1, 2, 2, 3, 3]
-        # deterministic: a fresh placer over the same sizes places the same
-        cp2 = placement.ChunkPlacer(DEV8[:4])
-        assert [cp2.next(100)[0] for _ in range(8)] == ordinals
-
     def test_mesh_off_means_no_devices(self, monkeypatch):
         monkeypatch.delenv("HYPERSPACE_MESH", raising=False)
         assert placement.mesh_devices() == []
         assert placement.mesh_size() == 0
-        assert placement.chunk_placer() is None
 
     def test_mesh_on_sees_forced_host_devices(self, monkeypatch):
         monkeypatch.setenv("HYPERSPACE_MESH", "1")

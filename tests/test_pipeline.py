@@ -187,35 +187,36 @@ def pipe_session(tmp_path, monkeypatch):
 
 
 _QUERIES = {
-    # concat route: float sums
+    # float sums
     "global_float": lambda t: t.filter(col("d") < 9000).agg(
         Sum(col("x") * col("x")).alias("s"), Count(lit(1)).alias("n")
     ),
-    # partial route: exact folds only
+    # exact aggregates only: count, int sum and avg, min, max
     "global_exact": lambda t: t.filter(col("d") < 9500).agg(
         Sum(col("q")).alias("sq"), Min(col("x")).alias("mn"),
         Max(col("q")).alias("mx"), Avg(col("q")).alias("aq"),
         Count(lit(1)).alias("n"),
     ),
-    # grouped concat route with string keys (keys stay host-side)
+    # grouped, with string keys (keys stay host-side)
     "grouped_float": lambda t: t.filter(col("d") < 9500)
     .group_by("flag")
     .agg(Sum(col("x")).alias("sx"), Avg(col("x")).alias("ax"),
          Count(lit(1)).alias("n")),
-    # grouped concat route on an int64 key
+    # grouped on an int64 key
     "grouped_float_int_key": lambda t: t.filter(col("d") < 9500)
     .group_by("k")
     .agg(Sum(col("x")).alias("sx"), Count(lit(1)).alias("n")),
-    # grouped concat route on two string keys (Q1's shape)
+    # grouped on two string keys (Q1's shape)
     "grouped_float_two_keys": lambda t: t.filter(col("d") < 9500)
     .group_by("flag", "s")
     .agg(Sum(col("x")).alias("sx"), Count(lit(1)).alias("n")),
-    # grouped partial route (int sums fold exactly across chunks)
+    # grouped exact aggregates (int sums, min, int avg)
     "grouped_exact": lambda t: t.filter(col("d") < 9500)
     .group_by("k")
     .agg(Sum(col("q")).alias("sq"), Min(col("q")).alias("mn"),
          Avg(col("q")).alias("aq"), Count(lit(1)).alias("n")),
-    # per-chunk string-predicate re-encoding on the partial route
+    # a string predicate: the stream declines (chunk dictionaries differ)
+    # and the monolithic device read re-encodes it once
     "string_pred": lambda t: t.filter(col("flag") == "A").agg(
         Count(lit(1)).alias("n"), Sum(col("q")).alias("sq")
     ),
@@ -228,15 +229,19 @@ class TestPipelinedBitIdentity:
         session, table = pipe_session
         q = _QUERIES[qname]
         monkeypatch.setenv("HYPERSPACE_PIPELINE", "1")
-        before = REGISTRY.counter("pipeline.chunks").value
+        declines = qname == "string_pred"
+        counter = "pipeline.declined" if declines else "pipeline.chunks"
+        before = REGISTRY.counter(counter).value
         on = q(session.read.parquet(table)).to_pydict()
-        assert REGISTRY.counter("pipeline.chunks").value > before  # streamed
+        assert REGISTRY.counter(counter).value > before  # declined / streamed
         monkeypatch.setenv("HYPERSPACE_PIPELINE", "0")
         off = q(session.read.parquet(table)).to_pydict()
         monkeypatch.setenv("HYPERSPACE_PIPELINE", "serial")
         serial = q(session.read.parquet(table)).to_pydict()
         assert _bits(on) == _bits(off)  # pipelined == monolithic, bit for bit
         assert _bits(on) == _bits(serial)  # overlap never changes results
+        if declines:  # exact count and int sum: the host tier's answer too
+            assert on == _host_answer(session, table, q)
 
     def test_pipelined_matches_host_exact_aggs(self, pipe_session, monkeypatch):
         """Exact aggregates (counts, int sums) must agree with the HOST tier
@@ -405,7 +410,10 @@ class TestGroupingCache:
     @pytest.mark.parametrize("pipeline", ["1", "0"])
     @pytest.mark.parametrize(
         "qname",
-        ["grouped_float", "grouped_float_int_key", "grouped_float_two_keys"],
+        [
+            "grouped_exact", "grouped_float", "grouped_float_int_key",
+            "grouped_float_two_keys",
+        ],
     )
     def test_a_warm_repeat_reuses_the_grouping(
         self, indexed_pipe, monkeypatch, qname, pipeline

@@ -407,9 +407,10 @@ class TestEnvRegistry:
             assert expected in names
 
     def test_typed_reads(self, monkeypatch):
-        assert env_registry.env_int("HYPERSPACE_PIPELINE_DEPTH") == 2
-        monkeypatch.setenv("HYPERSPACE_PIPELINE_DEPTH", "5")
-        assert env_registry.env_int("HYPERSPACE_PIPELINE_DEPTH") == 5
+        monkeypatch.delenv("HYPERSPACE_ADAPTIVE_WARMUP_CHUNKS", raising=False)
+        assert env_registry.env_int("HYPERSPACE_ADAPTIVE_WARMUP_CHUNKS") == 2
+        monkeypatch.setenv("HYPERSPACE_ADAPTIVE_WARMUP_CHUNKS", "5")
+        assert env_registry.env_int("HYPERSPACE_ADAPTIVE_WARMUP_CHUNKS") == 5
         monkeypatch.delenv("HYPERSPACE_VERIFY_PLAN", raising=False)
         assert env_registry.env_bool("HYPERSPACE_VERIFY_PLAN") is False
         monkeypatch.setenv("HYPERSPACE_VERIFY_PLAN", "1")

@@ -237,9 +237,9 @@ def main() -> int:
                 ColumnBatch.from_pydict(
                     {
                         "ev_k": list(range(base, base + per)),
-                        "ev_cat": [
-                            f"c{(base + j) % 3}" for j in range(per)
-                        ],
+                        # an int category: a string predicate would
+                        # decline the stream the abort monitor watches
+                        "ev_cat": [(base + j) % 3 for j in range(per)],
                         "ev_v": rng.uniform(0, 1, per).tolist(),
                     }
                 ),
@@ -252,7 +252,7 @@ def main() -> int:
         )
     finally:
         covering.INDEX_ROW_GROUP_SIZE = rgs_orig
-    # plant the mis-estimate: NDV 1e9 says "almost no group holds c1"
+    # plant the mis-estimate: NDV 1e9 says "almost no group holds ev_cat 1"
     sides = sorted(
         glob.glob(
             os.path.join(ws, "indexes", "ev_idx", "**", "_sketch.*.json"),
@@ -272,7 +272,7 @@ def main() -> int:
     def scan_q():
         return (
             session.read.parquet(os.path.join(ws, "events"))
-            .filter(col("ev_cat") == "c1")
+            .filter(col("ev_cat") == 1)
             .group_by("ev_cat")
             .agg(
                 Count(lit(1)).alias("n"),
