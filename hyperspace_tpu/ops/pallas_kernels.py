@@ -144,8 +144,12 @@ def filter_grouped_sum(
     cardinality keys) as a single Pallas streaming pass: per-group (8,128)
     accumulator slabs reduce to scalars outside the kernel. The predicate
     must already mask padding rows. Returns (sums[G] f32, counts[G] i32)."""
-    sums, counts = filter_grouped_multi_sum(pred, gids, (x,), num_groups)
+    sums, counts, _first = filter_grouped_multi_sum(pred, gids, (x,), num_groups)
     return sums[0], counts
+
+
+# a group's first passing row when no row of it passes
+_NO_ROW = 2**31 - 1
 
 
 def _grouped_multi_sum_kernel_body(num_groups: int, num_vals: int):
@@ -155,7 +159,7 @@ def _grouped_multi_sum_kernel_body(num_groups: int, num_vals: int):
         pred_ref, gid_ref = refs[0], refs[1]
         x_refs = refs[2 : 2 + num_vals]
         s_refs = refs[2 + num_vals : 2 + 2 * num_vals]
-        c_ref = refs[2 + 2 * num_vals]
+        c_ref, f_ref = refs[2 + 2 * num_vals], refs[3 + 2 * num_vals]
         i = pl.program_id(0)
 
         @pl.when(i == 0)
@@ -163,33 +167,45 @@ def _grouped_multi_sum_kernel_body(num_groups: int, num_vals: int):
             for s_ref in s_refs:
                 s_ref[...] = jnp.zeros(acc_shape, jnp.float32)
             c_ref[...] = jnp.zeros(acc_shape, jnp.int32)
+            f_ref[...] = jnp.full(acc_shape, _NO_ROW, jnp.int32)
 
         pred = pred_ref[...]
         gids = gid_ref[...]
-        # static unroll over the (small) group domain: every measure and the
-        # count accumulate in the SAME streaming pass — pred/gids are read
-        # from HBM once per block regardless of how many sums the fragment has
+        # the global row index of each element of this step's (8,128) tile
+        row = (
+            i * _BLOCK_ROWS + jax.lax.broadcasted_iota(jnp.int32, _ACC_SHAPE, 0)
+        ) * _LANES + jax.lax.broadcasted_iota(jnp.int32, _ACC_SHAPE, 1)
+        # static unroll over the (small) group domain: every measure, the
+        # count and the first passing row accumulate in the SAME streaming
+        # pass — pred/gids are read from HBM once per block regardless of
+        # how many sums the fragment has
         for g in range(num_groups):
             m = pred & (gids == g)
             lo, hi = g * _BLOCK_ROWS, (g + 1) * _BLOCK_ROWS
             for x_ref, s_ref in zip(x_refs, s_refs):
                 s_ref[lo:hi, :] += jnp.where(m, x_ref[...], jnp.float32(0))
             c_ref[lo:hi, :] += m.astype(jnp.int32)
+            f_ref[lo:hi, :] = jnp.minimum(
+                f_ref[lo:hi, :], jnp.where(m, row, jnp.int32(_NO_ROW))
+            )
 
     return kernel
 
 
 @partial(jax.jit, static_argnames=("num_groups",))  # hslint: HS201 — module-level jit singleton; traced once per shape
 def filter_grouped_multi_sum(pred, gids, xs, num_groups: int):
-    """Per-group sums of each value column in ``xs`` plus the shared
-    count(pred), all in ONE streaming pass (a k-measure Q1 fragment costs
-    one HBM read of pred/gids, not k). ``xs`` may be empty (count-only).
-    Returns (tuple of sums[G] f32, counts[G] i32)."""
+    """Per-group sums of each value column in ``xs``, the shared
+    count(pred) and each group's first row passing ``pred``, all in ONE
+    streaming pass (a k-measure Q1 fragment costs one HBM read of
+    pred/gids, not k). ``xs`` may be empty (count-only). A group with no
+    passing row keeps 2**31 - 1 as its first row, as a segment min does.
+    Returns (tuple of sums[G] f32, counts[G] i32, first rows[G] i32)."""
     xs = tuple(xs)
     if pred.shape[0] == 0:
         return (
             tuple(jnp.zeros((num_groups,), jnp.float32) for _ in xs),
             jnp.zeros((num_groups,), jnp.int32),
+            jnp.full((num_groups,), _NO_ROW, jnp.int32),
         )
     num_vals = len(xs)
     steps, blocks = _pad_blocks(
@@ -201,17 +217,16 @@ def filter_grouped_multi_sum(pred, gids, xs, num_groups: int):
         _grouped_multi_sum_kernel_body(num_groups, num_vals),
         grid=(steps,),
         in_specs=[_IN_SPEC] * (2 + num_vals),
-        out_specs=[acc_spec] * (num_vals + 1),
+        out_specs=[acc_spec] * (num_vals + 2),
         out_shape=[jax.ShapeDtypeStruct(acc_shape, jnp.float32)] * num_vals
-        + [jax.ShapeDtypeStruct(acc_shape, jnp.int32)],
+        + [jax.ShapeDtypeStruct(acc_shape, jnp.int32)] * 2,
         interpret=_interpret(),
     )(*blocks)
-    sums = tuple(
-        o.reshape(num_groups, _BLOCK_ROWS, _LANES).sum(axis=(1, 2))
-        for o in outs[:num_vals]
-    )
-    counts = outs[num_vals].reshape(num_groups, _BLOCK_ROWS, _LANES).sum(axis=(1, 2))
-    return sums, counts
+    slabs = [o.reshape(num_groups, _BLOCK_ROWS, _LANES) for o in outs]
+    sums = tuple(o.sum(axis=(1, 2)) for o in slabs[:num_vals])
+    counts = slabs[num_vals].sum(axis=(1, 2))
+    first = slabs[num_vals + 1].min(axis=(1, 2))
+    return sums, counts, first
 
 
 def _minmax_kernel(x_ref, valid_ref, mn_ref, mx_ref):

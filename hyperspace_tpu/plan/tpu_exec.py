@@ -1278,10 +1278,11 @@ def _build_grouped_pallas_kernel(pred_expr, proj_exprs, agg_list, seg_pad):
                     lits,
                 )
             sum_vals.append(vals)
-        # every measure + the count in ONE streaming pass over pred/gids
-        sums, counts = filter_grouped_multi_sum(mask, gids, sum_vals, seg_pad)
-        gids_m = jnp.where(mask, gids, seg_pad - 1)
-        first_masked = _first_masked_rows(mask, gids_m, seg_pad)
+        # every measure, the count and each group's first passing row in
+        # ONE streaming pass over pred/gids: no XLA scatter
+        sums, counts, first_masked = filter_grouped_multi_sum(
+            mask, gids, sum_vals, seg_pad
+        )
         out = []
         i = 0
         for kind, _child in agg_list:
@@ -1371,6 +1372,36 @@ def _grouped_kernel(seg_pad, pred_expr, proj_exprs, agg_list, dev_cols):
         "grouped_agg",
     )
     return kernel, lits
+
+
+def _grouped_scatters(kernel, args) -> int:
+    """The XLA scatters in a grouped kernel's program: the Pallas route has
+    none, the generic one a segment scatter for the first rows, the counts
+    and each float sum, avg, min or max, four for an int sum. Its cache key
+    fixes the count, so it is counted once, from the program traced at the
+    kernel's first dispatch, and kept on the cached kernel."""
+    n = getattr(kernel, "scatters", None)
+    if n is None:
+        from ..staticcheck.kernel_audit import _iter_eqns
+
+        n = kernel.scatters = sum(
+            eqn.primitive.name.startswith("scatter")
+            for eqn in _iter_eqns(jax.make_jaxpr(kernel)(*args))
+        )
+    return n
+
+
+def _dispatch_grouped(kernel, dev_cols, gids_d, mask, lits):
+    """One dispatch of a cached grouped kernel and the fetch of its result;
+    adds the kernel's scatters to ``kernel.grouped.scatters``."""
+    from ..utils.rpc_meter import METER, device_get as metered_get
+
+    args = (dev_cols, gids_d, mask, lits)
+    METER.record_dispatch()
+    with _attr.phase("enqueue"):
+        out = kernel(*args)
+    REGISTRY.counter("kernel.grouped.scatters").inc(_grouped_scatters(kernel, args))
+    return metered_get(out)
 
 
 def _grouping(key_parts: list[ColumnBatch], key_names: list[str], padded: int):
@@ -1464,12 +1495,9 @@ def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[Colu
         kernel, lits = _grouped_kernel(
             seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
         )
-        from ..utils.rpc_meter import METER, device_get as metered_get
-
-        METER.record_dispatch()
-        with _attr.phase("enqueue"):
-            out = kernel(dev_cols, gids_d, mask, lits)
-        counts_dev, first_masked, results = metered_get(out)
+        counts_dev, first_masked, results = _dispatch_grouped(
+            kernel, dev_cols, gids_d, mask, lits
+        )
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]
     results = [
@@ -1867,10 +1895,9 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
         kernel, lits = _grouped_kernel(
             seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
         )
-        METER.record_dispatch()
-        with _attr.phase("enqueue"):
-            out = kernel(dev_cols, gids_d, mask, lits)
-        counts_dev, first_masked, results = metered_get(out)
+        counts_dev, first_masked, results = _dispatch_grouped(
+            kernel, dev_cols, gids_d, mask, lits
+        )
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]
     results = [

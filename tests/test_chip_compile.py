@@ -122,6 +122,43 @@ def test_q6_fused_kernel_compiles(topo, one_chip, mosaic):
     assert "tpu_custom_call" in text
 
 
+def test_q1_grouped_kernel_compiles_without_scatter(topo, one_chip, mosaic):
+    """Q1's grouped kernel at 2^23 rows and 16 segments: one Pallas pass
+    gives the sums, the counts and each group's first passing row, so the
+    compiled program holds no XLA scatter."""
+    from hyperspace_tpu.plan.expr import col, lit
+    from hyperspace_tpu.plan.tpu_exec import _build_grouped_kernel, slot_literals
+
+    disc_price = col("l_extendedprice") * (lit(1.0) - col("l_discount"))
+    agg_list = [
+        ("sum", col("l_quantity")),
+        ("sum", col("l_extendedprice")),
+        ("sum", disc_price),
+        ("sum", disc_price * (lit(1.0) + col("l_tax"))),
+        ("avg", col("l_quantity")),
+        ("avg", col("l_extendedprice")),
+        ("avg", col("l_discount")),
+        ("count", None),
+    ]
+    kinds = {"l_shipdate": "i", "l_quantity": "f", "l_extendedprice": "f",
+             "l_discount": "f", "l_tax": "f"}
+    pred, proj, aggs, lits = slot_literals(
+        col("l_shipdate") <= 10471, (), agg_list, kinds
+    )
+    kernel = _build_grouped_kernel(pred, proj, aggs, 16)
+    rows = 1 << 23
+    cols = {
+        name: _sds(one_chip, jnp.int32 if kind == "i" else jnp.float32, rows)
+        for name, kind in kinds.items()
+    }
+    text = kernel.lower(
+        cols, _sds(one_chip, jnp.int32, rows), _sds(one_chip, jnp.bool_, rows),
+        _lits(lits),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert " scatter(" not in text
+
+
 # the order-detail lookup at SF10: one bucket of l_orderkey padded to 2^23
 _SELECT_ROWS = 1 << 23
 
