@@ -76,14 +76,11 @@ def _host_facts() -> dict:
     # mesh facts: artifacts from different device topologies are not
     # comparable (tools/bench_compare.py refuses mismatched counts)
     try:
-        from hyperspace_tpu.parallel.placement import mesh_enabled
         from hyperspace_tpu.utils.backend import device_count
 
         facts["devices_visible"] = device_count()
-        facts["mesh_enabled"] = mesh_enabled()
     except Exception:
         facts["devices_visible"] = None
-        facts["mesh_enabled"] = False
     try:
         from hyperspace_tpu import native
 
@@ -1115,92 +1112,6 @@ def _measure_adaptive(session, ws: str) -> dict:
     }
 
 
-def _measure_mesh_scale(session, ws: str) -> dict:
-    """Mesh-sharded scale-out: the TPC-H join queries re-run on the device
-    tier with HYPERSPACE_MESH=1 so band waves fan out across every visible
-    device (skew-aware placement, parallel/placement.py) instead of all
-    landing on device 0. Mesh-on must be bit-identical (float.hex) to
-    mesh-off — placement moves work, never changes answers — and the
-    section records the placer's balance telemetry (devices used, byte
-    imbalance ratio, fallback count). Skipped with a reason when fewer
-    than 2 devices are visible. BENCH_MESH=0 skips the section."""
-    from hyperspace_tpu import constants as C
-    from hyperspace_tpu.benchmark import TPCH_QUERIES
-    from hyperspace_tpu.serve import budget as serve_budget
-    from hyperspace_tpu.telemetry.metrics import REGISTRY
-    from hyperspace_tpu.utils.backend import device_count
-
-    ndev = device_count()
-    if ndev < 2:
-        return {"skipped": "single_device", "devices_visible": ndev}
-    names = [n for n in ("q3", "q10") if n in TPCH_QUERIES]
-
-    def _bits(d: dict) -> str:
-        return repr(
-            {
-                k: [x.hex() if isinstance(x, float) else x for x in v]
-                for k, v in d.items()
-            }
-        )
-
-    session.enable_hyperspace()
-    session.set_conf(C.EXEC_TPU_ENABLED, True)
-    prior_mesh = os.environ.get("HYPERSPACE_MESH")
-    bit_ok = True
-    try:
-        # ---- mesh off: the single-device reference ----------------------
-        os.environ["HYPERSPACE_MESH"] = "0"
-        reference = {}
-        t_off = 0.0
-        for name in names:
-            reference[name] = _bits(TPCH_QUERIES[name](session, ws).to_pydict())
-            t, _ = _timed(lambda: TPCH_QUERIES[name](session, ws).collect(), 1)
-            t_off += t
-        # ---- mesh on: same bits, waves spread over the mesh -------------
-        os.environ["HYPERSPACE_MESH"] = "1"
-        buckets0 = REGISTRY.counter("mesh.placement.buckets").value
-        fallbacks0 = REGISTRY.counter("mesh.placement.fallbacks").value
-        t_on = 0.0
-        for name in names:
-            bit_ok = bit_ok and (
-                _bits(TPCH_QUERIES[name](session, ws).to_pydict())
-                == reference[name]
-            )
-            t, _ = _timed(lambda: TPCH_QUERIES[name](session, ws).collect(), 1)
-            t_on += t
-        buckets = REGISTRY.counter("mesh.placement.buckets").value - buckets0
-        fallbacks = (
-            REGISTRY.counter("mesh.placement.fallbacks").value - fallbacks0
-        )
-        devices_used = REGISTRY.gauge("mesh.placement.devices_used").value
-        imbalance = REGISTRY.gauge("mesh.placement.bytes_imbalance_ratio").value
-        ledgers = {
-            f"d{o}": acct.held_bytes()
-            for o, acct in serve_budget.device_budgets().items()
-        }
-        ledgers_drained = all(v == 0 for v in ledgers.values())
-    finally:
-        if prior_mesh is None:
-            os.environ.pop("HYPERSPACE_MESH", None)
-        else:
-            os.environ["HYPERSPACE_MESH"] = prior_mesh
-        session.set_conf(C.EXEC_TPU_ENABLED, False)
-        session.disable_hyperspace()
-    return {
-        "devices_visible": ndev,
-        "queries": names,
-        "mesh_off_ms": round(t_off * 1000, 1),
-        "mesh_on_ms": round(t_on * 1000, 1),
-        "placed_buckets": buckets,
-        "placement_fallbacks": fallbacks,
-        "devices_used": devices_used,
-        "bytes_imbalance_ratio": round(imbalance, 4),
-        "ledgers_drained": ledgers_drained,
-        "bit_identical": bit_ok,
-        "results_match": bool(bit_ok and ledgers_drained),
-    }
-
-
 def _measure_cached_qps(session, ws: str) -> dict:
     """Repeat-heavy serving with the snapshot-keyed result cache
     (cache/result_cache.py): the dashboard-workload shape where the same
@@ -1933,15 +1844,6 @@ def main() -> None:
             adaptive = _measure_adaptive(session, ws)
         correct = correct and adaptive["results_match_raw"]
 
-    # ---- mesh-sharded scale-out: band waves fan out across the mesh ------
-    # (non-mutating; device tier — must run BEFORE hybrid-refresh mutates)
-    mesh_scale = None
-    if os.environ.get("BENCH_MESH", "1") == "1":
-        with _bench_span("mesh_scale"):
-            mesh_scale = _measure_mesh_scale(session, ws)
-        if "skipped" not in mesh_scale:
-            correct = correct and mesh_scale["results_match"]
-
     # ---- repeat-heavy serving through the result cache (non-mutating on
     # TPC-H; its freshness leg writes only the events_cached table) --------
     cached = None
@@ -2014,7 +1916,6 @@ def main() -> None:
         "multi_tenant": tenant_qos,
         "spill_join": spill,
         "adaptive": adaptive,
-        "mesh_scale": mesh_scale,
         "cached_qps": cached,
         "ingest_rw": ingest_rw,
         "approx_tier": approx_tier,

@@ -13,9 +13,9 @@ device tier must not have degraded. The session runs strict
 (``HYPERSPACE_DEVICE_STRICT=1``): a device failure raises instead of
 falling back to the host.
 
-``--chips 4`` runs only the mesh path (mesh-partitioned index build,
-mesh aggregates, mesh-placed bucketed joins) and the one-chip answers it
-is compared with.
+``--chips 4`` runs only the deployment on four chips (mesh-partitioned
+index build, mesh aggregates, Q3 and Q17 served each on its own home chip)
+and the one-chip answers it is compared with.
 
 There is no CPU path: without a TPU the script exits non-zero and prints
 no result. The last line of a passing run is
@@ -233,31 +233,51 @@ def run_queries(session, ws: str, reference: dict, rtol: float,
     return ok
 
 
-@contextlib.contextmanager
-def placement_on(n_devices: int):
-    """Skew-aware bucket->device placement over ``n_devices``
-    (``HYPERSPACE_MESH=1``, capped by ``HYPERSPACE_MESH_DEVICES``)."""
-    saved = {k: os.environ.get(k)
-             for k in ("HYPERSPACE_MESH", "HYPERSPACE_MESH_DEVICES")}
-    os.environ["HYPERSPACE_MESH"] = "1"
-    os.environ["HYPERSPACE_MESH_DEVICES"] = str(n_devices)
+def homed_leg(mesh_session, ws: str, n_devices: int, one_chip: dict,
+              rtol: float) -> bool:
+    """Q3 and Q17 served to ``n_devices`` clients at once through the
+    scheduler on the deployment (``hyperspace.tpu.exec.meshDevices``): each
+    query runs on its own home chip, and every answer matches one chip's."""
+    import threading
+
+    from hyperspace_tpu.serve.scheduler import QueryScheduler
+
+    gate = threading.Barrier(n_devices)
+    sched = QueryScheduler(max_concurrent=n_devices)
+    ok = True
+    t0 = time.perf_counter()
     try:
-        yield
+        handles = []
+        for i in range(n_devices):
+            name = ("q3", "q17")[i % 2]
+            df = query(name, mesh_session, ws)
+
+            def fn(df=df):
+                gate.wait(timeout=1800)  # every query in flight at once
+                return df.collect()
+
+            handles.append((name, sched.submit(fn, label=name)))
+        for name, h in handles:
+            diff = mismatch(h.result(timeout=1800).to_pydict(),
+                            one_chip[name], rtol)
+            ok = ok and diff is None
+            log(f"mesh homed {name} on chip {h.ctx.device_home}: match "
+                f"one-chip {diff is None}" + (f" ({diff})" if diff else ""))
+        homes = sorted(h.ctx.device_home for _, h in handles)
     finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        sched.shutdown()
+    log(f"mesh homed: {time.perf_counter() - t0:.3f}s, homed on chips "
+        f"{homes}")
+    return ok and homes == list(range(n_devices))
 
 
 def mesh_phase(ws: str, n_devices: int, rtol: float) -> bool:
-    """The mesh path users select with ``hyperspace.tpu.exec.meshDevices``
-    and ``HYPERSPACE_MESH=1``, each leg compared with the one-chip answers
-    on the same data: a mesh-partitioned index build (exchange
-    all_to_all) queried with mesh aggregates (psum) and the mesh join
-    probe, then bucketed joins placed across the devices. Every device
-    must have held work."""
+    """The deployment users select with ``hyperspace.tpu.exec.meshDevices``,
+    each leg compared with the one-chip answers on the same data: a
+    mesh-partitioned index build (exchange all_to_all) queried directly
+    with mesh aggregates (psum) and the mesh join probe, then Q3 and Q17
+    served at once, each on its own home chip. Every device must have held
+    work."""
     import jax
 
     from hyperspace_tpu.telemetry import trace
@@ -282,14 +302,10 @@ def mesh_phase(ws: str, n_devices: int, rtol: float) -> bool:
                 log(f"mesh {leg} {name}: match one-chip {diff is None}"
                     + (f" ({diff})" if diff else ""))
         seen = {s.name for s in cap.sink.spans}
-        ordinals = {s.attrs.get("device") for s in cap.sink.spans
-                    if s.name == "mesh:dispatch"}
         missing = want_spans - seen
         ok = ok and not missing
         log(f"mesh {leg}: {time.perf_counter() - t0:.3f}s, spans missing "
-            f"{sorted(missing)}, placed on devices {sorted(ordinals)}")
-        if leg == "placed":
-            ok = ok and ordinals == set(range(n_devices))
+            f"{sorted(missing)}")
 
     with trace.capture() as cap:
         mesh_session = build(ws, os.path.join(ws, "indexes_mesh"), n_devices)
@@ -298,8 +314,7 @@ def mesh_phase(ws: str, n_devices: int, rtol: float) -> bool:
     log(f"mesh build exchanged over the mesh: {built}")
     check("aggregates+join", QUERIES, mesh_session,
           {"kernel:mesh_agg", "kernel:mesh_join_probe"})
-    with placement_on(n_devices):
-        check("placed", ("q3", "q17"), session, {"mesh:dispatch"})
+    ok = homed_leg(mesh_session, ws, n_devices, one_chip, rtol) and ok
 
     peaks = [
         (d.memory_stats() or {}).get("peak_bytes_in_use")

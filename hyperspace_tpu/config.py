@@ -192,6 +192,16 @@ class HyperspaceConf:
         return int(self._get(C.EXEC_MESH_DEVICES, C.EXEC_MESH_DEVICES_DEFAULT))
 
     @property
+    def exec_band_placement(self) -> str:
+        v = str(self._get(C.EXEC_BAND_PLACEMENT, C.EXEC_BAND_PLACEMENT_DEFAULT))
+        if v not in C.BAND_PLACEMENTS:
+            raise HyperspaceError(
+                f"{C.EXEC_BAND_PLACEMENT} must be one of {C.BAND_PLACEMENTS}, "
+                f"got {v!r}"
+            )
+        return v
+
+    @property
     def exec_mesh_slices(self) -> int:
         v = int(self._get(C.EXEC_MESH_SLICES, C.EXEC_MESH_SLICES_DEFAULT))
         if v < 1:

@@ -129,6 +129,12 @@ EXEC_MESH_DEVICES_DEFAULT = 0
 # bucket, the streaming-build layout).
 EXEC_MESH_SLICES = "hyperspace.tpu.exec.meshSlices"
 EXEC_MESH_SLICES_DEFAULT = 1
+# Where a served query's join bands run on a deployment of several chips:
+# "home" runs every band on the query's home chip; "spread" has the
+# skew-aware placer (parallel/placement.py) pack them over all the chips.
+EXEC_BAND_PLACEMENT = "hyperspace.tpu.exec.bandPlacement"
+EXEC_BAND_PLACEMENT_DEFAULT = "home"
+BAND_PLACEMENTS = ("home", "spread")
 # Fused-XLA execution of supported plan fragments. Off by default on CPU
 # (host numpy path is exact float64); bench/production TPU sessions turn it on.
 EXEC_TPU_ENABLED = "hyperspace.tpu.exec.enabled"

@@ -81,16 +81,40 @@ def bucket_exchange(
     overflow is the true max per-(src,dst) count — if overflow > capacity the
     result is truncated and the caller must retry with a larger capacity.
     """
-    n_dest = mesh.shape[axis]
-    body = partial(_exchange_body, axis, n_dest, capacity)
+    from ..plan.kernel_cache import MESH_CACHE
+
+    fn = MESH_CACHE.get_or_build(
+        ("bucket_exchange", mesh, axis, capacity),
+        lambda: _build_exchange(mesh, axis, capacity), "bucket_exchange",
+    )
+    return fn(cols, dest)
+
+
+def _build_exchange(mesh: Mesh, axis: str, capacity: int):
+    """The exchange as ONE compiled program per mesh, capacity and shape:
+    run op by op outside jit, shard_map compiles each of its operations
+    apart, some hundred compiles a partition on the chip."""
+    body = partial(_exchange_body, axis, mesh.shape[axis], capacity)
     fn = shard_map(
         body,
         mesh=mesh,
-        in_specs=(jax.tree.map(lambda _: P(axis), cols), P(axis)),
-        out_specs=(jax.tree.map(lambda _: P(axis), cols), P(axis), P()),
+        in_specs=(P(axis), P(axis)),
+        out_specs=(P(axis), P(axis), P()),
         check_vma=False,
     )
-    return fn(cols, dest)
+    return jax.jit(fn)  # hslint: HS201 — built through MESH_CACHE.get_or_build
+
+
+def _build_bucket_dest(num_buckets: int, n_dest: int):
+    """Each row's bucket and destination shard, in one compiled program
+    per shape."""
+    from ..ops.hashing import bucket_ids_jnp
+
+    def bucket_dest(words):
+        bucket = bucket_ids_jnp(words, num_buckets)
+        return bucket, bucket % jnp.int32(n_dest)
+
+    return jax.jit(bucket_dest)  # hslint: HS201 — built through MESH_CACHE.get_or_build
 
 
 def exchange_with_retry(mesh, cols, dest, rows_per_shard: int, axis: str = SHARD_AXIS):
@@ -131,7 +155,7 @@ def partition_batch_mesh(batch, bucket_columns, num_buckets: int, mesh: Mesh, ax
     from jax.sharding import NamedSharding
 
     from ..ops.bucketize import key_hash_words
-    from ..ops.hashing import _words_np, bucket_ids_jnp
+    from ..ops.hashing import _words_np
 
     from .mesh import is_hierarchical
 
@@ -162,6 +186,7 @@ def partition_batch_mesh(batch, bucket_columns, num_buckets: int, mesh: Mesh, ax
     row_id = np.full(padded, -1, np.int32)
     row_id[:n] = np.arange(n, dtype=np.int32)
 
+    from ..plan.kernel_cache import MESH_CACHE
     from ..telemetry import trace
     from ..utils.rpc_meter import METER
 
@@ -174,8 +199,11 @@ def partition_batch_mesh(batch, bucket_columns, num_buckets: int, mesh: Mesh, ax
         row_d = jax.device_put(jnp.asarray(row_id), shard)
         # each transported word is one single-word hash column; mixing order
         # matches hash32_np's word order, so placement is bit-identical
-        bucket_d = bucket_ids_jnp(words_d, num_buckets)
-        dest_d = bucket_d % jnp.int32(D)
+        bucket_dest = MESH_CACHE.get_or_build(
+            ("bucket_dest", num_buckets, D),
+            lambda: _build_bucket_dest(num_buckets, D), "bucket_dest",
+        )
+        bucket_d, dest_d = bucket_dest(words_d)
         out, valid = exchange_with_retry(
             mesh, {"b": bucket_d, "r": row_d}, dest_d, padded // D, axis
         )

@@ -91,15 +91,6 @@ def num_shards(mesh: Mesh, axis: "str | tuple[str, ...] | None" = None) -> int:
     return mesh.shape[axis]
 
 
-def visible_devices(cap: int = 0) -> list:
-    """The device list scale-out placement may target. ``cap`` > 0 clamps
-    the list (``HYPERSPACE_MESH_DEVICES``); the order is ``jax.devices()``
-    order, which is stable for a process lifetime — placement determinism
-    leans on that."""
-    devices = jax.devices()
-    return list(devices[:cap] if cap > 0 else devices)
-
-
 def active_mesh(session) -> Mesh | None:
     """The execution mesh requested by `hyperspace.tpu.exec.meshDevices`;
     None when the conf asks for at most one device. Raises ValueError when

@@ -1,25 +1,31 @@
-"""Skew-aware bucket→device placement for mesh-sharded execution.
+"""The chips of a deployment, and where a served query's join bands run.
+
+A session deploys on N chips with ``hyperspace.tpu.exec.meshDevices`` = N.
+SF-scale indexes fit on one chip, so the chips hold replicas: the serving
+scheduler runs each query on one of them, its home chip
+(``serve/context.query_home``). ``hyperspace.tpu.exec.bandPlacement``
+says where the bands of its bucketed joins run: ``home`` (the default)
+runs every band there; ``spread`` packs them over all the chips with the
+skew-aware placer below.
 
 The covering indexes bucketize both join sides identically, so bucket work
-units (band-wave items in ``plan/device_join``) are independent: any device
+units (band-wave items in ``plan/device_join``) are independent: any chip
 may compute any unit and the host-side fold reassembles results in bucket
-order, bit-identical to single-device execution. That independence is what
-this module exploits — it only decides *where* each unit runs, never *what*
-runs.
+order, bit-identical to one chip. The placer only decides *where* each
+unit runs, never *what* runs.
 
 Placement policy (JSPIM-style skew awareness): the join memory planner's
 per-bucket footer-stat estimates predict each bucket's decoded bytes. A
-bucket predicted to exceed the per-device fair share is split into as many
+bucket predicted to exceed the per-chip fair share is split into as many
 ranges as shares it covers (its probe chunks then rotate through those
 ranges), and all ranges are largest-first bin packed onto the least-loaded
-device. Buckets with no stats fall back to deterministic round-robin —
+chip. Buckets with no stats fall back to deterministic round-robin —
 counted in ``mesh.placement.fallbacks`` so a stats-starved workload is
-visible. Everything is a pure function of the estimates dict and the
-device count, so placement is deterministic for a fixed dataset.
+visible. Everything is a pure function of the estimates dict and the chip
+count, so placement is deterministic for a fixed dataset.
 
-Default-off behind ``HYPERSPACE_MESH``; locally the path is driven with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU, where
-placement, balance, and bit-identity are all provable at nproc=1.
+Locally the path is driven with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU.
 """
 
 from __future__ import annotations
@@ -29,56 +35,37 @@ from typing import Optional
 
 from ..telemetry import trace
 from ..telemetry.metrics import REGISTRY
-from ..utils import env
 
 
-def mesh_enabled() -> bool:
-    """``HYPERSPACE_MESH=1`` — the scale-out placement master switch."""
-    return env.env_bool("HYPERSPACE_MESH")
-
-
-def mesh_devices() -> list:
-    """The devices placement may target: ``[]`` when the knob is off or
-    fewer than two devices are visible (a 1-device mesh is just the default
-    device with extra bookkeeping)."""
-    if not mesh_enabled():
+def mesh_devices(session) -> list:
+    """The chips ``session`` deploys on: the first ``meshDevices`` of
+    ``jax.devices()``, or ``[]`` with the conf at most 1 (a 1-device mesh
+    is just the default device with extra bookkeeping). More chips than
+    are visible raises: a deployment never silently shrinks."""
+    n = session.conf.exec_mesh_devices if session is not None else 0
+    if n <= 1:
         return []
-    try:
-        cap = env.env_int("HYPERSPACE_MESH_DEVICES")
-    except ValueError:
-        cap = 0
-    from .mesh import visible_devices
+    import jax
 
-    devices = visible_devices(cap)
-    return devices if len(devices) >= 2 else []
-
-
-def mesh_size() -> int:
-    return len(mesh_devices())
-
-
-def _query_offset() -> int:
-    """The serving scheduler's home-device assignment for the current
-    query (tenant-weighted occupancy argmin) — placement rotates its
-    round-robin and tie-breaks from here so concurrent queries spread
-    instead of all packing from ordinal 0."""
-    from ..serve.context import current_query
-
-    q = current_query()
-    home = getattr(q, "device_home", None) if q is not None else None
-    return int(home) if home is not None else 0
+    devices = jax.devices()
+    if len(devices) < n:
+        raise ValueError(
+            f"hyperspace.tpu.exec.meshDevices={n} but only {len(devices)} "
+            "devices are visible"
+        )
+    return list(devices[:n])
 
 
 class Placement:
-    """An immutable bucket→device assignment. ``chunk`` indexes a split
+    """An immutable bucket→chip assignment. ``chunk`` indexes a split
     bucket's probe chunks: a bucket planned into k ranges rotates its
     chunks through the k packed ordinals; unplanned buckets round-robin
-    deterministically from the query's home offset."""
+    deterministically from the query's home chip."""
 
     __slots__ = ("devices", "_units", "_offset")
 
-    def __init__(self, devices: list, units: dict, offset: int):
-        self.devices = devices
+    def __init__(self, devices, units: dict, offset: int):
+        self.devices = tuple(devices)
         self._units = units  # bucket -> tuple[ordinal, ...] in range order
         self._offset = offset
 
@@ -89,26 +76,21 @@ class Placement:
             return (bucket + chunk + self._offset) % len(self.devices)
         return ords[chunk % len(ords)]
 
-    def device_for(self, bucket: int, chunk: int = 0):
-        return self.devices[self.ordinal_for(bucket, chunk)]
-
     def slot_for(self, bucket: int, chunk: int = 0) -> tuple:
-        """The ``(ordinal, device)`` pair band schedulers thread through
-        ``_BandScheduler.add`` — hashable, so it doubles as the wave
-        grouping key."""
-        o = self.ordinal_for(bucket, chunk)
-        return o, self.devices[o]
+        """The chip as ``(ordinal, devices)``, the form
+        ``utils.backend.on_home`` takes; band schedulers thread it through
+        ``_BandScheduler.add``, and it doubles as the wave grouping key."""
+        return self.ordinal_for(bucket, chunk), self.devices
 
 
 def plan_bucket_placement(
-    estimates: dict, devices: "list | None" = None, offset: int = 0
+    estimates: dict, devices, offset: int = 0
 ) -> Optional[Placement]:
     """Largest-first bin packing of predicted per-bucket decoded bytes
-    onto the mesh. ``estimates`` maps bucket -> predicted bytes (buckets
-    absent from it take the round-robin fallback at lookup time). None
-    when no mesh is on."""
-    if devices is None:
-        devices = mesh_devices()
+    onto ``devices``. ``estimates`` maps bucket -> predicted bytes
+    (buckets absent from it take the round-robin fallback at lookup
+    time); ``offset``, the home chip, breaks load ties. None for fewer
+    than two chips."""
     ndev = len(devices)
     if ndev < 2:
         return None
@@ -119,7 +101,7 @@ def plan_bucket_placement(
         share = total / ndev
         # one work unit per fair share the bucket covers: a skewed bucket
         # becomes several ranges its split chunks rotate through, so ONE
-        # hot bucket can no longer pin the balance to a single device
+        # hot bucket can no longer pin the balance to a single chip
         work = []
         for b in sorted(estimates):
             nbytes = float(estimates[b])
@@ -153,14 +135,19 @@ def plan_bucket_placement(
     return Placement(devices, units, offset)
 
 
-def plan_for_strategy(strategy) -> Optional[Placement]:
-    """A Placement for one bucketed join, driven by the memory planner's
-    footer-stat estimates (a stable read-only map — ``observe_actual``
-    writes a separate observed-actuals ledger)."""
-    devices = mesh_devices()
-    if len(devices) < 2:
+def plan_for_strategy(strategy, session) -> Optional[Placement]:
+    """A Placement for one bucketed join of a query homed on a deployment
+    whose session spreads join bands (``bandPlacement`` = ``spread``),
+    driven by the memory planner's footer-stat estimates (a stable
+    read-only map — ``observe_actual`` writes a separate observed-actuals
+    ledger). None otherwise: every band runs on the home chip."""
+    from ..utils.backend import home
+
+    h = home()
+    if h is None or session is None \
+            or session.conf.exec_band_placement != "spread":
         return None
     estimates = {}
     if strategy is not None:
         estimates = {b: est[1] for b, est in strategy.estimates.items()}
-    return plan_bucket_placement(estimates, devices, _query_offset())
+    return plan_bucket_placement(estimates, h[1], h[0])

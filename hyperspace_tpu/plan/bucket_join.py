@@ -860,14 +860,15 @@ def _try_device_join_paths(
     which reuses ``loaded`` ([(lb, rb, l_sorted, r_sorted)] indexed by
     bucket, possibly None when the screens declined before loading).
 
-    The mesh path (when a mesh is active) collects every pair up front —
-    its shard waves need the full set — and gets first shot. Otherwise
+    The mesh path (when a mesh is active and the query has no home chip)
+    collects every pair up front — its shard waves need the full set — and
+    gets first shot. Otherwise
     bucket pairs STREAM through _iter_bucket_pairs into the band-stacked
     probe (device_join.try_batched_plain_join), whose waves dispatch while
     later pairs still decode; HYPERSPACE_PIPELINE=0 keeps the barrier +
     one-global-wave behavior."""
     from ..parallel.mesh import active_mesh
-    from ..utils.backend import device_healthy
+    from ..utils.backend import device_healthy, home
 
     if _plain_join_plan_screen(left, right, lkeys, rkeys, session) is None:
         return None, None, None
@@ -875,7 +876,8 @@ def _try_device_join_paths(
         return None, None, None
     from ..parallel.mesh import is_hierarchical
 
-    mesh = active_mesh(session)
+    # a query homed on one chip of the deployment joins there, as on one
+    mesh = None if home() is not None else active_mesh(session)
     if mesh is not None and is_hierarchical(mesh):
         # the co-partitioned probe moves bucket rows: intra-slice only by
         # design (same rationale as the build exchange) — on a hierarchical

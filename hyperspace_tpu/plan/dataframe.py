@@ -243,7 +243,7 @@ class DataFrame:
         # JSONL cover ad-hoc queries too. Served queries (an attribution
         # scope is already installed) keep their scheduler-owned record.
         if attribution.current_stats() is not None:
-            return self._collect_inner()
+            return self._collect_served()
         from ..serve.context import QueryCancelledError, QueryContext
         from ..telemetry.attribution import LEDGER
 
@@ -262,6 +262,17 @@ class DataFrame:
         finally:
             # after the scope exited: the rollups are not charged back
             LEDGER.finish(stats, outcome, error)
+
+    def _collect_served(self) -> ColumnBatch:
+        """A served query: on its home chip when its session deploys on
+        several (``serve.context.query_home``), as on one chip otherwise.
+        The kernel cache makes what it compiles there ready on the other
+        chips (``plan/kernel_cache.ChipKernel``)."""
+        from ..serve.context import query_home
+        from ..utils.backend import on_home
+
+        with on_home(query_home(self.session)):
+            return self._collect_inner()
 
     def _collect_inner(self) -> ColumnBatch:
         from ..cache.result_cache import serve_collect

@@ -43,6 +43,7 @@ from ..telemetry import attribution as _attr
 from ..telemetry import trace
 from ..telemetry.metrics import REGISTRY
 from ..utils import env
+from ..utils.backend import home, on_home
 
 
 def _pow2(n: int, floor: int = 10) -> int:
@@ -106,7 +107,7 @@ class _Wave:
         self.rec = rec
         self.nbytes = nbytes
         self.done = None
-        self.ordinal = ordinal  # mesh device ordinal the wave dispatched to
+        self.ordinal = ordinal  # the chip whose device ledger it charges
 
 
 class _BandScheduler:
@@ -137,7 +138,7 @@ class _BandScheduler:
 
     def __init__(self, dispatch, banded: bool, wave: int = _JOIN_WAVE,
                  ledger=None, estimate=None, retire=None):
-        self._dispatch = dispatch  # (pads, items[, device]) -> device record
+        self._dispatch = dispatch  # (pads, items) -> device record
         self.banded = banded
         self.wave = wave
         self._ledger = ledger  # plan/join_memory.DeviceLedger or None
@@ -152,16 +153,17 @@ class _BandScheduler:
         self._n_items = 0
 
     def add(self, item, n_l: int, n_r: int, place=None) -> None:
-        """``place`` is the mesh placement of this item — ``(ordinal,
-        device)`` from ``parallel.placement`` or None (the default
-        device). Placed items band by ``(pads, place)`` so each wave's
-        single dispatch targets exactly one device; mesh-off behavior
-        (place None everywhere) is unchanged to the byte."""
+        """``place`` is the chip the skew-aware placer chose for this item
+        (``(ordinal, devices)`` from ``parallel.placement``) or None (the
+        query's home chip, or the default device off a deployment).
+        Placed items band by ``(pads, place)`` so each wave's single
+        dispatch targets exactly one chip; unplaced behavior is unchanged
+        to the byte."""
         self._max_l = max(self._max_l, n_l)
         self._max_r = max(self._max_r, n_r)
         self._n_items += 1
         if not self.banded:
-            # ONE global wave: per-wave device targeting is meaningless
+            # ONE global wave: per-wave chip targeting is meaningless
             self._groups.setdefault(None, []).append(item)
             return
         band = (_band_pads(n_l, n_r), place)
@@ -205,7 +207,10 @@ class _BandScheduler:
     def _flush(self, pads, items, place=None) -> None:
         if self.dead is not None or self.declined is not None or not items:
             return
-        ordinal = place[0] if place is not None else 0
+        # the placed chip, else the query's home chip (0 off a deployment):
+        # the wave runs there and charges its device ledger
+        h = home() if place is None else place
+        ordinal = 0 if h is None else h[0]
         need = 0
         if self._ledger is not None and self._ledger.enabled and self._estimate:
             need = int(self._estimate(pads, items))
@@ -222,11 +227,13 @@ class _BandScheduler:
                 if place is None:
                     rec = self._dispatch(pads, items)
                 else:
+                    # the wave's uploads, cache entries and kernel land on
+                    # the placed chip
                     with trace.span(
                         "mesh:dispatch", device=ordinal, pad_l=pads[0],
                         pad_r=pads[1], buckets=len(items),
-                    ):
-                        rec = self._dispatch(pads, items, place[1])
+                    ), on_home(place):
+                        rec = self._dispatch(pads, items)
         except _JoinDeclined as e:
             if reserved:
                 self._ledger.release(need, device=ordinal)
@@ -873,9 +880,9 @@ def try_stacked_join_agg(
 
 def _log_mesh_exec(session, strategy, place, records, path: str) -> None:
     """MeshBucketedExec index-usage event for a PLACED execution — the
-    mesh-path twin of the ``BucketedJoinExec`` event the single-device
-    tiers emit, with the message naming the placement so telemetry shows
-    which devices a query's waves actually landed on."""
+    placed twin of the ``BucketedJoinExec`` event the one-chip tiers emit,
+    with the message naming the placement so telemetry shows which chips a
+    query's waves actually landed on."""
     if session is None:
         return
     name = getattr(strategy, "index_name", "") if strategy is not None else ""
@@ -925,32 +932,22 @@ def _stacked_join_agg_impl(
             for it in items
         )
 
-    def _dispatch_agg(pads, items, device=None):
+    def _dispatch_agg(pads, items):
         pad_l, pad_r = pads
         dt = state["dt"]
         (_gc, agg_specs, left_names, right_gather, _rf, right_names) = state["elig"]
         rk_pad_val = np.iinfo(dt).max if dt.kind == "i" else np.float32(np.inf)
         B = len(items)
 
-        def _commit(stack):
-            # mesh placement: commit the upload to the wave's placed device
-            # (uncommitted otherwise — the historical default-device path)
-            return jnp.asarray(stack) if device is None else \
-                jax.device_put(stack, device)
-
-        def _dtag(t: tuple) -> tuple:
-            # per-device cache entries: mesh-off keys stay byte-identical
-            return t if device is None else t + (f"d{device.id}",)
-
         def _build_rk():
             stack = np.full((B, pad_r), rk_pad_val, dtype=dt)
             for i, it in enumerate(items):
                 stack[i, : len(it.rk_arr)] = it.rk_arr
-            return _commit(stack)
+            return jnp.asarray(stack)
 
         rk_d = DEVICE_CACHE.get_or_put_multi(
             tuple(it.rb.column(rk_name).data for it in items),
-            _dtag(("stackrk", pad_r, dt.str, _chunk_tags(items, True))),
+            ("stackrk", pad_r, dt.str, _chunk_tags(items, True)),
             _build_rk,
         )
 
@@ -965,14 +962,14 @@ def _stacked_join_agg_impl(
                     for i, it in enumerate(items):
                         a = getattr(it, ship_attr)[c]
                         stack[i, : len(a)] = a
-                    return _commit(stack)
+                    return jnp.asarray(stack)
 
                 srcs = tuple(
                     getattr(it, batch_attr).column(c).data for it in items
                 )
                 out[c] = DEVICE_CACHE.get_or_put_multi(
                     srcs,
-                    _dtag((tag, pad, c, _chunk_tags(items, tag == "stackr"))),
+                    (tag, pad, c, _chunk_tags(items, tag == "stackr")),
                     _build,
                 )
             return out
@@ -984,11 +981,11 @@ def _stacked_join_agg_impl(
             stack = np.zeros((B, pad_l), dtype=dt)
             for i, it in enumerate(items):
                 stack[i, : len(it.lk_arr)] = it.lk_arr
-            return _commit(stack)
+            return jnp.asarray(stack)
 
         lk_d = DEVICE_CACHE.get_or_put_multi(
             tuple(it.lb.column(lk_name).data for it in items),
-            _dtag(("stacklk", pad_l, dt.str, _chunk_tags(items, False))),
+            ("stacklk", pad_l, dt.str, _chunk_tags(items, False)),
             _build_lk,
         )
         n_l = jnp.asarray(np.array([len(it.lk_arr) for it in items], np.int32))
@@ -1048,11 +1045,12 @@ def _stacked_join_agg_impl(
     n_buckets = 0
     place = None
     if banded:
-        # skew-aware mesh placement (None when HYPERSPACE_MESH is off or
-        # <2 devices): non-banded mode is ONE global wave, nothing to place
+        # skew-aware placement over the deployment's chips (None unless
+        # the session spreads join bands): non-banded mode is ONE global
+        # wave, nothing to place
         from ..parallel import placement as mesh_placement
 
-        place = mesh_placement.plan_for_strategy(strategy)
+        place = mesh_placement.plan_for_strategy(strategy, session)
 
     # ---- lazy consumption: prep + band + (maybe) dispatch per pair -------
     for b, lb, rb, _l_sorted, r_sorted in pairs:
@@ -1168,8 +1166,8 @@ def _stacked_join_agg_impl(
         pending = [w for w in records if w.done is None]
         if pending:
             if place is not None:
-                # the cross-device gather: ONE fetch spanning every placed
-                # wave (device_get pulls from each wave's own device)
+                # the cross-chip gather: ONE fetch spanning every placed
+                # wave (device_get pulls from each wave's own chip)
                 with trace.span(
                     "mesh:gather", waves=len(pending),
                     devices=len({w.ordinal for w in pending}),
@@ -1375,13 +1373,11 @@ def _split_probe_items(w, split: int):
 
 
 def _stack_band_keys(items, arr_attr: str, src_attr: str, pad: int, dt,
-                     pad_val, device=None):
+                     pad_val):
     """Device copy of one band wave's stacked key slabs, cached by the
     ORIGINAL key buffers' identities + the per-item derivation (chunk
     offset, slab length, sort flag): sorted/sliced/padded stacks are
-    deterministic per source set, so steady-state repeats upload nothing.
-    ``device`` commits the slab to a placed mesh device (with its own
-    cache entry); None keeps the historical uncommitted default."""
+    deterministic per source set, so steady-state repeats upload nothing."""
     from ..utils.device_cache import DEVICE_CACHE
 
     srcs = tuple(getattr(it, src_attr) for it in items)
@@ -1394,16 +1390,13 @@ def _stack_band_keys(items, arr_attr: str, src_attr: str, pad: int, dt,
             for it in items
         ),
     )
-    if device is not None:
-        tag = tag + (f"d{device.id}",)
 
     def _build():
         stack = np.full((len(items), pad), pad_val, dtype=dt)
         for i, it in enumerate(items):
             a = getattr(it, arr_attr)
             stack[i, : len(a)] = a
-        return jnp.asarray(stack) if device is None else \
-            jax.device_put(stack, device)
+        return jnp.asarray(stack)
 
     return DEVICE_CACHE.get_or_put_multi(srcs, tag, _build)
 
@@ -1469,14 +1462,12 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
     split_default = join_split_rows() if banded else 0
     state: dict = {"dt": None}
 
-    def _dispatch_probe(pads, items, device=None):
+    def _dispatch_probe(pads, items):
         pad_l, pad_r = pads
         dt = state["dt"]
         pad_val = np.iinfo(dt).max if dt.kind == "i" else np.float32(np.inf)
-        lk_d = _stack_band_keys(items, "lk32", "lk_src", pad_l, dt, pad_val,
-                                device=device)
-        rk_d = _stack_band_keys(items, "rk32", "rk_src", pad_r, dt, pad_val,
-                                device=device)
+        lk_d = _stack_band_keys(items, "lk32", "lk_src", pad_l, dt, pad_val)
+        rk_d = _stack_band_keys(items, "rk32", "rk_src", pad_r, dt, pad_val)
         n_l = jnp.asarray(np.array([len(it.lk32) for it in items], np.int32))
         n_r = jnp.asarray(np.array([len(it.rk32) for it in items], np.int32))
         kernel = JOIN_CACHE.get_or_build(
@@ -1552,11 +1543,12 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
     n_splits = 0
     place = None
     if banded:
-        # skew-aware mesh placement (None when HYPERSPACE_MESH is off or
-        # <2 devices): non-banded mode is ONE global wave, nothing to place
+        # skew-aware placement over the deployment's chips (None unless
+        # the session spreads join bands): non-banded mode is ONE global
+        # wave, nothing to place
         from ..parallel import placement as mesh_placement
 
-        place = mesh_placement.plan_for_strategy(strategy)
+        place = mesh_placement.plan_for_strategy(strategy, session)
     # consumption runs OUTSIDE the breaker scope: a host IO error from a
     # streaming caller must propagate as a scan error, not latch the tier
     # off; device errors inside the dispatch are the scheduler's to record
@@ -1597,7 +1589,7 @@ def _batched_plain_join_impl(work, residual, session, banded, strategy,
         if pending:
             if place is not None:
                 # zero-width marker: the probe/expand fetches below gather
-                # results from every placed device in one pass
+                # results from every placed chip in one pass
                 with trace.span(
                     "mesh:gather", waves=len(pending),
                     devices=len({w.ordinal for w in pending}),

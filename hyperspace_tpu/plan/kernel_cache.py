@@ -41,7 +41,7 @@ import threading
 from collections import OrderedDict
 from typing import Callable
 
-from ..staticcheck.concurrency import TrackedLock
+from ..staticcheck.concurrency import TrackedLock, guarded_by
 
 
 def _dev_dtype_label(v) -> str:
@@ -140,6 +140,8 @@ class KernelCache:
                 faults.fire("kernel.compile", kind=kind)
                 kernel = builder()
             REGISTRY.counter("kernel.retrace").inc()
+            if hasattr(kernel, "lower"):
+                kernel = ChipKernel(kernel)
             kernel = observe_compile(self.name, kind, key, kernel)
             self.set(key, kernel)
         finally:
@@ -168,6 +170,176 @@ class KernelCache:
     def __iter__(self):
         with self._lock:
             return iter(list(self._d))
+
+
+# --- kernels on a deployment of several chips -------------------------------
+#
+# JAX compiles a jitted kernel once per chip: a query homed on chip 2
+# (utils.backend.on_home) that calls a kernel compiled so far only on chip 0
+# compiles it again. So the first call of a kernel at new argument shapes on
+# any chip of a deployment also compiles it, at those shapes, for every
+# other chip of the deployment, on a background pool: the query that made
+# the call does not wait for them, and their compile time is charged to no
+# query. A later call on a chip whose compile is still running waits for it
+# (charged to its ``compile`` phase) instead of compiling a second time.
+# Off a deployment a call is one contextvar read more than the bare kernel.
+
+_ready_lock = TrackedLock("kernel_cache.ready")
+_ready_pool = None
+_ready_pending: set = guarded_by(set(), _ready_lock,
+                                 name="kernel_cache._ready_pending")
+
+
+def _leaf_signature(leaf):
+    import jax
+    import numpy as np
+
+    if isinstance(leaf, jax.Array):
+        return leaf.shape, leaf.dtype, leaf.weak_type, leaf.committed
+    if isinstance(leaf, (np.ndarray, np.generic)):
+        return leaf.shape, leaf.dtype
+    return type(leaf)
+
+
+def _abstract(leaf, device):
+    """``leaf`` as the kernel would see it on ``device``: an array becomes
+    its shape and dtype there, committed to it if the leaf is committed."""
+    import jax
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+
+    if isinstance(leaf, jax.Array):
+        return jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, weak_type=leaf.weak_type,
+            sharding=SingleDeviceSharding(device) if leaf.committed else None,
+        )
+    if isinstance(leaf, np.ndarray):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+    return leaf
+
+
+class ChipKernel:
+    """A cached jitted kernel that makes each new argument signature ready
+    on every chip of the deployment it is called on (see above), and
+    counts each call on a deployment in ``device.dispatch.d<k>``. Other
+    attributes are the jitted kernel's."""
+
+    def __init__(self, fn):
+        self._fn = fn
+        self._lock = TrackedLock("kernel_cache.chip_kernel")
+        self._ready: dict = {}  # (signature, chip) -> Future | None
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
+
+    def __call__(self, *args, **kwargs):
+        from ..utils.backend import home
+
+        h = home()
+        if h is not None:
+            self._on_chip(h, args, kwargs)
+        return self._fn(*args, **kwargs)
+
+    def _on_chip(self, h: tuple, args, kwargs) -> None:
+        import jax
+
+        from ..utils.rpc_meter import METER
+
+        ordinal, chips = h
+        leaves, tree = jax.tree_util.tree_flatten((args, kwargs))
+        if any(isinstance(x, jax.core.Tracer) for x in leaves):
+            return  # traced (make_jaxpr, an enclosing jit): nothing runs
+        METER.record_chip_dispatch(leaves, chips)
+        sig = (tree, tuple(_leaf_signature(x) for x in leaves))
+        with self._lock:
+            wait = self._ready.get((sig, ordinal))
+            if (sig, ordinal) in self._ready:
+                todo = []
+            else:
+                # this call compiles it here; the other chips compile aside
+                self._ready[(sig, ordinal)] = None
+                todo = [k for k in range(len(chips))
+                        if (sig, k) not in self._ready]
+                for k in todo:
+                    self._ready[(sig, k)] = _submit_ready(
+                        self._fn, args, kwargs, chips[k])
+        if wait is not None and not wait.done():
+            from ..telemetry import attribution
+
+            with attribution.phase("compile"):
+                wait.result()
+
+
+def _submit_ready(fn, args, kwargs, device):
+    import jax
+
+    from ..utils.workers import io_pool
+
+    global _ready_pool
+    a_args, a_kwargs = jax.tree_util.tree_map(
+        lambda x: _abstract(x, device), (args, kwargs))
+    with _ready_lock:
+        if _ready_pool is None:
+            # six compiles at once: two for each other chip of four
+            _ready_pool = io_pool(6, "hs-ready")
+        fut = _ready_pool.submit(_compile_on, fn, a_args, a_kwargs, device)
+        _ready_pending.add(fut)
+    fut.add_done_callback(_ready_done)
+    return fut
+
+
+def _ready_done(fut) -> None:
+    with _ready_lock:
+        _ready_pending.discard(fut)
+
+
+def _compile_on(fn, args, kwargs, device) -> None:
+    """Compile ``fn`` at ``args`` for ``device``, into the jitted kernel's
+    own cache. A failure is logged and counted, never raised: the first
+    query to call the kernel on that chip then compiles it itself."""
+    import logging
+
+    import jax
+
+    from ..telemetry import trace
+    from ..telemetry.metrics import REGISTRY
+
+    try:
+        with trace.span("kernel:ready", device=device.id), \
+                jax.default_device(device):
+            fn.lower(*args, **kwargs).compile()
+        REGISTRY.counter("kernel.ready.compiles").inc()
+    except Exception as e:  # never fails a query
+        REGISTRY.counter("kernel.ready.errors").inc()
+        logging.getLogger(__name__).warning(
+            "readying a kernel on %s failed: %s", device, e)
+
+
+_concat = None
+
+
+def concat_kernel() -> ChipKernel:
+    """The device concatenation of arrays: one jitted program, which JAX
+    specializes per part shapes, made ready on every chip like a cached
+    kernel."""
+    global _concat
+    with _ready_lock:
+        if _concat is None:
+            import jax
+            import jax.numpy as jnp
+
+            _concat = ChipKernel(jax.jit(lambda *parts: jnp.concatenate(parts)))
+        return _concat
+
+
+def ready_idle(timeout: "float | None" = None) -> bool:
+    """Wait until no kernel is being readied on another chip; False if
+    ``timeout`` passed first."""
+    from concurrent.futures import wait
+
+    with _ready_lock:
+        pending = list(_ready_pending)
+    return not wait(pending, timeout=timeout).not_done
 
 
 # --- canonical fingerprints -------------------------------------------------

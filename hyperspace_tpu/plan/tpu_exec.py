@@ -747,6 +747,16 @@ def _pad_pow2(n: int) -> int:
     return 1 << max(10, int(np.ceil(np.log2(max(1, n)))))
 
 
+def _concat_padded(parts: list, tail: int):
+    """``parts`` end to end on the device, then ``tail`` zeros: one jitted
+    program (specialized per part shapes), not eager operations, so the
+    other chips of a deployment find it compiled
+    (``kernel_cache.ChipKernel``)."""
+    from .kernel_cache import concat_kernel
+
+    return concat_kernel()(*parts, np.zeros(tail, parts[0].dtype))
+
+
 # Compiled kernels cache cross-query by canonical plan fingerprint — shared
 # between the monolithic and pipelined executors (plan/kernel_cache.py owns
 # the instances, the fingerprint format, and the hit/miss/evict metrics).
@@ -1846,11 +1856,7 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
     dev_cols = {}
     for name, parts in dev_parts.items():
         def _cat(parts=parts):
-            tail = padded - n_seen
-            arrs = list(parts)
-            if tail:
-                arrs.append(jnp.zeros(tail, dtype=parts[0].dtype))
-            return jnp.concatenate(arrs)
+            return _concat_padded(parts, padded - n_seen)
 
         # keyed on every chunk buffer: a repeat query over cache-stable index
         # chunks reuses the concatenated device column outright
@@ -2136,10 +2142,12 @@ def try_device_sort(sort_plan, batch: ColumnBatch, session) -> Optional[ColumnBa
 
 def _mesh_for(session):
     """Active execution mesh when conf requests one (see
-    parallel.mesh.active_mesh)."""
+    parallel.mesh.active_mesh); None for a query homed on one chip of the
+    deployment, which runs there as on one chip."""
     from ..parallel.mesh import active_mesh
+    from ..utils.backend import home
 
-    return active_mesh(session)
+    return None if home() is not None else active_mesh(session)
 
 
 def _execute_on_mesh(frag: _Fragment, batch: ColumnBatch, plan, session, mesh) -> Optional[ColumnBatch]:

@@ -340,9 +340,9 @@ def configured_device_budget_bytes() -> int:
 
 
 def _device_budget_name(ordinal: int) -> str:
-    # ordinal 0 keeps the historical metric prefix EXACTLY so mesh-off
+    # ordinal 0 keeps the historical metric prefix EXACTLY so one-chip
     # telemetry (and every existing dashboard/test) is byte-for-byte
-    # unchanged; mesh ordinals suffix .d<N>
+    # unchanged; the other chips of a deployment suffix .d<N>
     return (
         "serve.device_budget" if ordinal == 0
         else f"serve.device_budget.d{ordinal}"
@@ -359,9 +359,10 @@ def device_budget(ordinal: int = 0) -> BudgetAccountant:
     """The DEVICE-byte accountant for one mesh device ordinal — every
     bucketed-join band scheduler reserves wave footprints through these
     (N concurrent spilling joins share each device's ledger). Ordinal 0
-    is the historical single-device ledger; under ``HYPERSPACE_MESH`` a
-    wave placed on device d reserves through ordinal d, so concurrent
-    spilling joins pack across the mesh instead of queueing on one chip.
+    is the historical single-device ledger; on a deployment of several
+    chips (``hyperspace.tpu.exec.meshDevices``) a wave on chip d, the
+    query's home chip, reserves through ordinal d, so each chip's joins
+    park on its own ledger.
     Each ledger is sized by ``HYPERSPACE_DEVICE_BUDGET_MB`` (the knob is
     per device: a mesh multiplies the fleet budget by its size) at first
     use; ``reset_device_budget()`` re-reads the knob (tests/bench)."""

@@ -47,14 +47,16 @@ class QueryContext:
     the owning tenant's name (the QoS dimension: per-tenant queues, budget
     partitions, and ledger rollups all key on it; "default" when the
     submitter never said otherwise) and ``deadline_s`` the optional SLO
-    the admission door checked against. ``device_home`` is the mesh device
-    ordinal the scheduler placed this query on (tenant-weighted occupancy
-    argmin at dispatch; None outside the scheduler or with the mesh off) —
-    the skew-aware placer rotates its packing from it so concurrent
-    queries spread across the mesh."""
+    the admission door checked against. ``device_home`` is the ordinal of
+    the chip the query runs on when its session deploys on several
+    (``hyperspace.tpu.exec.meshDevices``): the scheduler's
+    ``home_chooser`` picks it (tenant-weighted occupancy argmin) when the
+    query first reaches the device (``query_home``); None outside the
+    scheduler or on one chip."""
 
     __slots__ = ("query_id", "label", "priority", "tenant", "deadline_s",
-                 "device_home", "approx_fraction", "_cancelled")
+                 "device_home", "home_chooser", "approx_fraction",
+                 "_cancelled")
 
     def __init__(self, label: str = "query", priority: int = 0,
                  tenant: str = "default",
@@ -65,6 +67,7 @@ class QueryContext:
         self.tenant = tenant
         self.deadline_s = deadline_s
         self.device_home: Optional[int] = None
+        self.home_chooser = None  # (chips) -> ordinal, set at dispatch
         # sampling fraction the QoS degrade policy selected for this query
         # (serve/scheduler.py); None = exact. plan/sampling.py reads it at
         # collect time and engages the sampled tier when eligible.
@@ -122,3 +125,21 @@ def check_cancelled() -> None:
         raise QueryCancelledError(
             f"query {ctx.query_id} ({ctx.label}) cancelled"
         )
+
+
+def query_home(session) -> "tuple | None":
+    """The home chip of the running served query on ``session``'s
+    deployment, as ``(ordinal, devices)`` for ``utils.backend.on_home``:
+    chosen by the scheduler on first use and kept for the query. None
+    outside the scheduler or on a deployment of one chip."""
+    ctx = _current.get()
+    if ctx is None or ctx.home_chooser is None:
+        return None
+    from ..parallel.placement import mesh_devices
+
+    devices = mesh_devices(session)
+    if not devices:
+        return None
+    if ctx.device_home is None:
+        ctx.device_home = ctx.home_chooser(len(devices))
+    return ctx.device_home, tuple(devices)

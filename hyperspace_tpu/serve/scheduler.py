@@ -229,12 +229,6 @@ class QueryScheduler:
                            tenant=tenant_name, deadline_s=deadline_s)
         h = QueryHandle(ctx, fn, self)
         now = time.perf_counter()
-        # warm the mesh size OUTSIDE the scheduler lock: the first call may
-        # run the watchdog-guarded backend probe, which must never happen
-        # under self._lock (_home_device_locked reads the memoized answer)
-        from ..parallel.placement import mesh_size
-
-        mesh_size()
         # the token bucket is checked lock-free at the very door: a
         # rate-limited submission never contends on the scheduler lock
         rate_ok = ten.try_acquire_token()
@@ -390,25 +384,31 @@ class QueryScheduler:
             self._queues.on_dequeue(tenant_name)
             h.status = _RUNNING
             h._admit_t = time.perf_counter()
-            h.ctx.device_home = self._home_device_locked()
+            h.ctx.home_chooser = self._choose_home
             self._active[h.query_id] = h
             self._queues.on_activate(tenant_name)
             self._pool.submit(self._run, h)
 
-    def _home_device_locked(self) -> "Optional[int]":
-        """Whole-query mesh placement: the device ordinal with the least
-        tenant-weighted occupancy among currently ACTIVE queries (a
-        weight-4 tenant's query counts 4x a weight-1 tenant's when
-        choosing the emptiest device), ties to the lowest ordinal. None
-        with the mesh off — the skew-aware placer then packs from ordinal
-        0 exactly as before. TENANTS is a leaf lock under self._lock (the
-        same order budget._tenant_over_share_locked established)."""
-        from ..parallel.placement import mesh_size
+    def _choose_home(self, n: int) -> int:
+        """The home chip of a query whose session deploys on ``n`` chips,
+        chosen when it first runs on the device (``serve.context
+        .query_home``); counted in ``serve.home.d<k>``."""
+        with self._lock:
+            home = self._home_device_locked(n)
+        from ..telemetry.metrics import REGISTRY
+
+        REGISTRY.counter(f"serve.home.d{home}").inc()
+        return home
+
+    def _home_device_locked(self, n: int) -> int:
+        """Whole-query placement on a deployment of ``n`` chips: the chip
+        with the least tenant-weighted occupancy among currently ACTIVE
+        queries (a weight-4 tenant's query counts 4x a weight-1 tenant's
+        when choosing the emptiest chip), ties to the lowest ordinal.
+        TENANTS is a leaf lock under self._lock (the same order
+        budget._tenant_over_share_locked established)."""
         from .tenant import TENANTS
 
-        n = mesh_size()
-        if n < 2:
-            return None
         occupancy = [0.0] * n
         for active in self._active.values():
             home = active.ctx.device_home
@@ -474,7 +474,11 @@ class QueryScheduler:
                     "serve:query", query_id=h.query_id, label=h.label,
                     priority=h.priority, tenant=h.ctx.tenant,
                 ) as sp:
-                    out = h._fn()
+                    try:
+                        out = h._fn()
+                    finally:
+                        if h.ctx.device_home is not None:
+                            sp.set_attr("device_home", h.ctx.device_home)
                     sp.set_attr("status", "done")
                     if (h._predicted_s is not None
                             and h.ctx.approx_fraction is None):
@@ -682,8 +686,8 @@ def serve_state() -> dict:
 def _device_budget_state() -> dict:
     """Device-ledger occupancy + spill counters: the device-memory block
     rendered by hs.profile, tools/hs_top.py, and the exporter /snapshot.
-    Under ``HYPERSPACE_MESH`` every instantiated per-device ordinal rolls
-    up under ``devices`` (keyed ``d<N>``), so the mesh's ledgers are
+    On a deployment of several chips every instantiated per-chip ordinal
+    rolls up under ``devices`` (keyed ``d<N>``), so the chips' ledgers are
     visible in the same block; ordinal 0 stays the top-level state the
     single-device dashboards already read."""
     from ..telemetry.metrics import REGISTRY

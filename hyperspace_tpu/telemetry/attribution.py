@@ -333,16 +333,22 @@ def bound(fn):
     """Wrap a pool task so it carries the SUBMITTING thread's attribution
     target: the streamers decode on shared IO pools, and without this the
     worker-side increments (chunk cache hits, decode latencies, retries)
-    would escape the query's ledger and break conservation. Identity when
-    no target is installed — the non-serving path stays allocation-free."""
+    would escape the query's ledger and break conservation. The task also
+    runs on the submitting query's home chip (``utils.backend.on_home``),
+    so device work on a pool thread stays there. Identity when no target
+    is installed — the non-serving path stays allocation-free."""
+    from ..utils.backend import home, on_home
+
     stats = _attr_target.get()
     if stats is None:
         return fn
+    chip = home()
 
     def run(*args, **kwargs):
         token = _attr_target.set(stats)
         try:
-            return fn(*args, **kwargs)
+            with on_home(chip):
+                return fn(*args, **kwargs)
         finally:
             _attr_target.reset(token)
 

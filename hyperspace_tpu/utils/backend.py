@@ -8,6 +8,8 @@ device was never reached.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 import time
 
@@ -64,6 +66,41 @@ def enable_compile_cache() -> str:
     # the query kernels compile in about a second each: cache all of them
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return cache_dir
+
+
+# ---------------------------------------------------------------------------
+# home chips
+# ---------------------------------------------------------------------------
+# A session deployed on N chips (``hyperspace.tpu.exec.meshDevices`` = N)
+# holds a replica of its device state on each chip, and the serving
+# scheduler runs each query on one of them, its home chip: the query's
+# thread runs with that chip as JAX's default device, so its uploads,
+# kernels and device caches all live there.
+
+_home: contextvars.ContextVar = contextvars.ContextVar("hs_home", default=None)
+
+
+def home() -> "tuple | None":
+    """``(ordinal, devices)`` of the running query's home chip, or None
+    outside a homed query."""
+    return _home.get()
+
+
+@contextlib.contextmanager
+def on_home(h: "tuple | None"):
+    """Run the block on home chip ``h`` (``(ordinal, devices)``, as
+    ``home()`` gives it) as JAX's default device; None runs it as is."""
+    if h is None:
+        yield
+        return
+    import jax
+
+    token = _home.set(h)
+    try:
+        with jax.default_device(h[1][h[0]]):
+            yield
+    finally:
+        _home.reset(token)
 
 
 def _reset_for_testing() -> None:

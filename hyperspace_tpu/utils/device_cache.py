@@ -11,7 +11,9 @@ Safety against id() reuse: each entry holds a weakref to the source array
 and a lookup only hits when the weakref still resolves to the *same object*
 (a dead or rebound ref is evicted). Mutated/derived arrays get fresh ids and
 therefore fresh entries. Eviction is least-recently-used by device bytes
-(``HYPERSPACE_DEVICE_CACHE_MB``, default 6144; 0 disables).
+(``HYPERSPACE_DEVICE_CACHE_MB``, default 6144; 0 disables), a budget per
+chip: on a deployment of several chips each home chip keeps its own
+entries.
 """
 
 from __future__ import annotations
@@ -43,20 +45,37 @@ def _cache_gauge(name: str, value: float) -> None:
 
 
 class DeviceArrayCache:
+    """LRU of derived arrays under a byte budget. With ``per_chip`` (the
+    device cache) each home chip of a deployment (``utils.backend``) has
+    an LRU of its own, keyed and budgeted apart: a query reads and fills
+    the entries of the chip it runs on, and each chip evicts under its own
+    budget. Off a deployment everything lives in one LRU, as on one chip."""
+
     # default budget sized for a v5e chip (16 GB HBM): 6 GB of resident
     # columns keeps a 50M-row query working set (≈1.8 GB) plus the join
     # indexes hot without re-uploading them on every repeat
-    def __init__(self, budget_env: str = "HYPERSPACE_DEVICE_CACHE_MB", default_mb: str = "6144") -> None:
+    def __init__(self, budget_env: str = "HYPERSPACE_DEVICE_CACHE_MB",
+                 default_mb: str = "6144", per_chip: bool = False) -> None:
         self._budget_env = budget_env
         self._default_mb = default_mb
+        self._per_chip = per_chip
         self._metric = "device" if budget_env == "HYPERSPACE_DEVICE_CACHE_MB" else "host_derived"
-        self._d: OrderedDict = OrderedDict()
-        self._bytes = 0
+        self._chips: dict = {}  # chip id (None: no home chip) -> OrderedDict
+        self._chip_bytes: dict = {}  # chip id -> resident bytes
         self._lock = TrackedLock(f"device_cache.{self._metric}")
         self.hits = 0
         self.misses = 0
         self.evictions = 0
         self.evicted_bytes = 0
+
+    def _chip(self):
+        """The LRU the calling query reads and fills: its home chip's."""
+        if not self._per_chip:
+            return None
+        from .backend import home
+
+        h = home()
+        return None if h is None else h[1][h[0]].id
 
     def get_or_put(self, src, key_extra, builder: Callable, meter: bool = True):
         """The device copy of ``src`` (a numpy array) under derivation
@@ -81,19 +100,21 @@ class DeviceArrayCache:
                 METER.record_upload(_tree_nbytes(value))
             return value
         srcs = tuple(srcs)
+        chip = self._chip()
         key = (tuple(id(s) for s in srcs), key_extra)
         with self._lock:
-            entry = self._d.get(key)
+            lru = self._chips.setdefault(chip, OrderedDict())
+            entry = lru.get(key)
             if entry is not None:
                 refs, value, nbytes = entry
                 if all(r() is s for r, s in zip(refs, srcs)):
-                    self._d.move_to_end(key)
+                    lru.move_to_end(key)
                     self.hits += 1
                     _cache_counter(self._metric, "hits")
                     return value
                 # an id was reused by a different array — stale entry
-                del self._d[key]
-                self._bytes -= nbytes
+                del lru[key]
+                self._chip_bytes[chip] -= nbytes
             self.misses += 1
         _cache_counter(self._metric, "misses")
         value, nbytes = self._build(key_extra, builder, meter)
@@ -104,7 +125,7 @@ class DeviceArrayCache:
         except TypeError:  # un-weakref-able source: don't cache
             return value
         with self._lock:
-            existing = self._d.get(key)
+            existing = lru.get(key)
             if existing is not None:
                 # lost a concurrent build race: serve the already-cached
                 # object so every caller holds THE resident copy (downstream
@@ -113,22 +134,32 @@ class DeviceArrayCache:
                 # their ids cannot have been reused.
                 value = existing[1]
             else:
-                self._d[key] = (refs, value, nbytes)
-                self._bytes += nbytes
-            evicted_n = evicted_b = 0
-            while self._bytes > budget and self._d:
-                _, (_r, _v, nb) = self._d.popitem(last=False)
-                self._bytes -= nb
-                evicted_n += 1
-                evicted_b += nb
-            self.evictions += evicted_n
-            self.evicted_bytes += evicted_b
-            occupancy = self._bytes
-        if evicted_n:
-            _cache_counter(self._metric, "evictions", evicted_n)
-            _cache_counter(self._metric, "evicted_bytes", evicted_b)
-        _cache_gauge(self._metric, occupancy)
+                lru[key] = (refs, value, nbytes)
+                self._chip_bytes[chip] = self._chip_bytes.get(chip, 0) + nbytes
+            evicted = self._evict_locked(chip, budget)
+        self._note_evictions(evicted)
         return value
+
+    def _evict_locked(self, chip, budget: int) -> tuple:
+        """Evict ``chip``'s least recently used entries down to ``budget``;
+        ``(entries, bytes)`` evicted and the occupancy of every chip."""
+        lru = self._chips[chip]
+        n = b = 0
+        while self._chip_bytes.get(chip, 0) > budget and lru:
+            _, (_r, _v, nb) = lru.popitem(last=False)
+            self._chip_bytes[chip] -= nb
+            n += 1
+            b += nb
+        self.evictions += n
+        self.evicted_bytes += b
+        return n, b, sum(self._chip_bytes.values())
+
+    def _note_evictions(self, evicted: tuple) -> None:
+        n, b, occupancy = evicted
+        if n:
+            _cache_counter(self._metric, "evictions", n)
+            _cache_counter(self._metric, "evicted_bytes", b)
+        _cache_gauge(self._metric, occupancy)
 
     def _build(self, key_extra, builder: Callable, meter: bool = True):
         """Run the builder; a DEVICE_CACHE miss IS a host->device transfer,
@@ -158,11 +189,13 @@ class DeviceArrayCache:
 
                 METER.record_upload(_tree_nbytes(value))
             return value
+        chip = self._chip()
         full_key = ("keyed", key)
         with self._lock:
-            entry = self._d.get(full_key)
+            lru = self._chips.setdefault(chip, OrderedDict())
+            entry = lru.get(full_key)
             if entry is not None:
-                self._d.move_to_end(full_key)
+                lru.move_to_end(full_key)
                 self.hits += 1
                 _cache_counter(self._metric, "hits")
                 return entry[1]
@@ -172,43 +205,42 @@ class DeviceArrayCache:
         if nbytes > budget:
             return value
         with self._lock:
-            if full_key not in self._d:
-                self._d[full_key] = (None, value, nbytes)
-                self._bytes += nbytes
-            evicted_n = evicted_b = 0
-            while self._bytes > budget and self._d:
-                _, (_r, _v, nb) = self._d.popitem(last=False)
-                self._bytes -= nb
-                evicted_n += 1
-                evicted_b += nb
-            self.evictions += evicted_n
-            self.evicted_bytes += evicted_b
-            occupancy = self._bytes
-        if evicted_n:
-            _cache_counter(self._metric, "evictions", evicted_n)
-            _cache_counter(self._metric, "evicted_bytes", evicted_b)
-        _cache_gauge(self._metric, occupancy)
+            if full_key not in lru:
+                lru[full_key] = (None, value, nbytes)
+                self._chip_bytes[chip] = self._chip_bytes.get(chip, 0) + nbytes
+            evicted = self._evict_locked(chip, budget)
+        self._note_evictions(evicted)
         return value
 
     @property
     def occupancy_bytes(self) -> int:
-        return self._bytes
+        with self._lock:
+            return sum(self._chip_bytes.values())
+
+    def chip_bytes(self) -> dict:
+        """Resident bytes by chip id (None: entries of queries without a
+        home chip)."""
+        with self._lock:
+            return {c: b for c, b in self._chip_bytes.items() if b}
 
     def check_consistency(self) -> bool:
-        """Byte accounting invariant: the occupancy counter equals the sum
-        of the resident entries' sizes (race-stress gate)."""
+        """Byte accounting invariant: each chip's occupancy counter equals
+        the sum of its resident entries' sizes (race-stress gate)."""
         with self._lock:
-            return self._bytes == sum(e[2] for e in self._d.values())
+            return all(
+                self._chip_bytes.get(c, 0) == sum(e[2] for e in lru.values())
+                for c, lru in self._chips.items()
+            )
 
     def clear(self) -> None:
         with self._lock:
-            self._d.clear()
-            self._bytes = 0
+            self._chips.clear()
+            self._chip_bytes.clear()
         _cache_gauge(self._metric, 0)
 
 
 # process-wide caches shared by every executor path: device uploads charge
 # the device budget; cheap-to-recompute host derivations (argsorts,
 # factorize results) get their own budget so they cannot evict transfers
-DEVICE_CACHE = DeviceArrayCache()
+DEVICE_CACHE = DeviceArrayCache(per_chip=True)
 HOST_DERIVED_CACHE = DeviceArrayCache("HYPERSPACE_HOST_DERIVED_CACHE_MB", "512")

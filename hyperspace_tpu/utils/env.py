@@ -291,24 +291,6 @@ _register(
     "models/dataskipping/sketch_store.py",
 )
 
-# mesh scale-out (parallel/placement.py, parallel/mesh.py)
-_register(
-    "HYPERSPACE_MESH", "bool", False,
-    "Mesh-sharded scale-out execution: bucketed-join band waves and "
-    "streaming scan/agg chunks fan out across every visible device via the "
-    "skew-aware placer (largest-first bin packing by predicted decoded "
-    "bytes; round-robin when footer stats are missing). Results stay "
-    "bit-identical to single-device execution; off (default) keeps every "
-    "dispatch on the default device.",
-    "parallel/placement.py",
-)
-_register(
-    "HYPERSPACE_MESH_DEVICES", "int", 0,
-    "Cap on the devices the mesh placer targets (0 = all visible; values "
-    "above the visible count clamp down).",
-    "parallel/placement.py",
-)
-
 # result cache / incremental views (cache/)
 _register(
     "HYPERSPACE_RESULT_CACHE", "mode", "0",

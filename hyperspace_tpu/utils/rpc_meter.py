@@ -45,6 +45,27 @@ class RpcMeter:
         with self._lock:
             self.dispatches += n
 
+    def record_chip_dispatch(self, leaves, chips: tuple) -> None:
+        """Count one kernel call of a query on a deployment of several
+        chips (``chips``, the deployment's devices) in
+        ``device.dispatch.d<k>``, k the chip it runs on: the device its
+        first committed operand among ``leaves`` lives on, which JAX runs
+        it on, else the calling thread's default device (the query's home
+        chip), where uncommitted operands go."""
+        import jax
+
+        device = None
+        for leaf in leaves:
+            if isinstance(leaf, jax.Array) and leaf.committed:
+                device = next(iter(leaf.devices()))
+                break
+        if device is None:
+            device = jax.config.jax_default_device
+        if device in chips and self is METER:
+            from ..telemetry.metrics import REGISTRY
+
+            REGISTRY.counter(f"device.dispatch.d{chips.index(device)}").inc()
+
     def record_upload(self, nbytes: int, n: int = 1) -> None:
         # `device.upload` injection point: every REAL host->device transfer
         # (monolithic, chunk-streamed, join, mesh) meters through here —

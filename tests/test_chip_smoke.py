@@ -12,8 +12,8 @@ import chip_smoke
 def test_phases_pass_on_cpu_mesh(tmp_path, capsys, chips):
     """chips=1: every query matches the host raw scan on a device route
     through DataFrame and the scheduler; chips=4: the mesh build, mesh
-    aggregates and placed joins match the one-chip answers on 4 of the 8
-    virtual devices."""
+    aggregates and Q3 and Q17 served each on its own home chip match the
+    one-chip answers on 4 of the 8 virtual devices."""
     assert chip_smoke.run(str(tmp_path), rows=100_000, seed=1, chips=chips)
     out = capsys.readouterr().out
     assert "FAIL" not in out
@@ -24,7 +24,7 @@ def test_phases_pass_on_cpu_mesh(tmp_path, capsys, chips):
         assert "device.degrades 0, breaker closed" in out
     else:
         assert "mesh build exchanged over the mesh: True" in out
-        assert "placed on devices [0, 1, 2, 3]" in out
+        assert "homed on chips [0, 1, 2, 3]" in out
 
 
 def test_main_refuses_without_tpu(capsys):

@@ -132,6 +132,32 @@ class TestMeshPartitionParity:
         batch = self._batch(n=4)
         assert partition_batch_mesh(batch, ["i32"], 8, mesh) is None
 
+    def test_a_partition_compiles_three_programs_and_a_repeat_none(self, mesh):
+        """The hash and the exchange are one compiled program each (run op
+        by op they were some hundred compiles a partition): a partition
+        compiles at most three programs, and another index of the same
+        table, the same shape again, compiles nothing."""
+        from jax import monitoring
+
+        from hyperspace_tpu.parallel.exchange import partition_batch_mesh
+
+        compiles = []
+
+        def on_duration(event, secs, **_):
+            if event == "/jax/core/compile/backend_compile_duration":
+                compiles.append(secs)
+
+        batch = self._batch(n=5003)
+        monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            assert partition_batch_mesh(batch, ["i64"], 8, mesh) is not None
+            first = len(compiles)
+            assert partition_batch_mesh(batch, ["f64"], 8, mesh) is not None
+        finally:
+            monitoring.unregister_event_duration_listener(on_duration)
+        assert first <= 3
+        assert len(compiles) == first
+
     def test_skewed_keys_retry_capacity(self, mesh):
         """All rows share one key: per-(src,dst) counts overflow the first
         capacity guess and the retry path must still return every row."""

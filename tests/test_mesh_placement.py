@@ -1,18 +1,26 @@
-"""Mesh-sharded scale-out execution tests (parallel/placement.py).
+"""A deployment on several chips (``hyperspace.tpu.exec.meshDevices``,
+``parallel/placement.py``, the scheduler's home chips).
 
-Three layers pin the mesh contract:
+Five layers pin the contract:
 
-* the skew-aware placer as a pure function — largest-first bin packing with
-  fair-share splitting of hot buckets, deterministic fallback round-robin
-  for stats-starved buckets, and strict determinism on fixed inputs;
-* end-to-end bit-identity — with the conftest's 8 forced host devices,
-  ``HYPERSPACE_MESH=1`` must produce float.hex-identical results to
-  ``HYPERSPACE_MESH=0`` on the skewed bucketed-join fixtures and the TPC-H
-  join queries (placement moves work, never changes answers);
-* per-device memory ledgers — each mesh ordinal holds its own
-  ``BudgetAccountant``, a saturated device parks/spills without stalling
-  its neighbors, and every ledger conserves exactly (sum of releases ==
-  sum of admissions; zero held at quiescence).
+* the deployment's chips come from the one conf, and more chips than are
+  visible raise;
+* the skew-aware placer (``bandPlacement=spread``) as a pure function —
+  largest-first bin packing with fair-share splitting of hot buckets,
+  deterministic fallback round-robin for stats-starved buckets, and strict
+  determinism on fixed inputs;
+* end-to-end bit-identity — with the conftest's 8 forced host devices, a
+  query served on a home chip must produce float.hex-identical results to
+  one chip on the skewed bucketed-join fixtures and the TPC-H join queries
+  (the home chip moves work, never changes answers), and every band of its
+  joins runs on that chip — or, with ``bandPlacement=spread``, on the
+  chips the placer chose;
+* per-device memory ledgers — each chip holds its own
+  ``BudgetAccountant``, a saturated chip parks/spills without stalling its
+  neighbors, and every ledger conserves exactly (sum of releases == sum of
+  admissions; zero held at quiescence);
+* the scheduler's home chip: the least-occupied chip, none without the
+  conf.
 """
 
 import types
@@ -44,7 +52,7 @@ def hex_rows(d: dict) -> str:
 
 
 # ---------------------------------------------------------------------------
-# placer units: planted skewed stats, no jax involved (devices are opaque)
+# the deployment's chips
 # ---------------------------------------------------------------------------
 
 
@@ -52,6 +60,9 @@ DEV8 = [f"dev{i}" for i in range(8)]
 
 
 class TestPlacerBinPacking:
+    """The chips a session deploys on, and the placer's packing of join
+    bands over them (planted skewed stats; devices are opaque)."""
+
     def test_uniform_stats_spread_over_all_devices(self):
         est = {b: 10 * MB for b in range(8)}
         p = placement.plan_bucket_placement(est, devices=DEV8)
@@ -60,10 +71,10 @@ class TestPlacerBinPacking:
         assert REGISTRY.gauge("mesh.placement.bytes_imbalance_ratio").value == 1.0
 
     def test_hot_bucket_splits_across_devices(self):
-        """One bucket carrying 30% of the bytes exceeds the per-device fair
+        """One bucket carrying 30% of the bytes exceeds the per-chip fair
         share, so the placer splits it into ranges its chunks rotate
-        through — without the split one device would hold 30% of the work
-        (imbalance ~2.4x on 8 devices)."""
+        through — without the split one chip would hold 30% of the work
+        (imbalance ~2.4x on 8 chips)."""
         est = {0: 30 * MB}
         est.update({b: 10 * MB for b in range(1, 8)})
         p = placement.plan_bucket_placement(est, devices=DEV8)
@@ -91,28 +102,35 @@ class TestPlacerBinPacking:
         assert REGISTRY.counter("mesh.placement.fallbacks").value == before + 3
 
     def test_offset_rotates_packing(self):
-        """The query's home device breaks load ties, so two concurrent
-        queries with different homes pack onto different devices instead
+        """The query's home chip breaks load ties, so two concurrent
+        queries with different homes pack onto different chips instead
         of both starting at ordinal 0."""
         est = {0: MB}
         p0 = placement.plan_bucket_placement(dict(est), devices=DEV8, offset=0)
         p3 = placement.plan_bucket_placement(dict(est), devices=DEV8, offset=3)
         assert p0.ordinal_for(0) == 0
         assert p3.ordinal_for(0) == 3
+        assert p3.slot_for(0) == (3, tuple(DEV8))
 
     def test_single_device_mesh_is_none(self):
         assert placement.plan_bucket_placement({0: MB}, devices=["d0"]) is None
 
-    def test_mesh_off_means_no_devices(self, monkeypatch):
-        monkeypatch.delenv("HYPERSPACE_MESH", raising=False)
-        assert placement.mesh_devices() == []
-        assert placement.mesh_size() == 0
+    def test_mesh_off_means_no_devices(self, tmp_session):
+        assert placement.mesh_devices(tmp_session) == []
+        tmp_session.set_conf(C.EXEC_MESH_DEVICES, 1)
+        assert placement.mesh_devices(tmp_session) == []
+        assert placement.mesh_devices(None) == []
 
-    def test_mesh_on_sees_forced_host_devices(self, monkeypatch):
-        monkeypatch.setenv("HYPERSPACE_MESH", "1")
-        assert placement.mesh_size() >= 2
-        monkeypatch.setenv("HYPERSPACE_MESH_DEVICES", "2")
-        assert placement.mesh_size() == 2
+    def test_mesh_on_sees_forced_host_devices(self, tmp_session):
+        import jax
+
+        tmp_session.set_conf(C.EXEC_MESH_DEVICES, 8)
+        assert placement.mesh_devices(tmp_session) == jax.devices()[:8]
+        tmp_session.set_conf(C.EXEC_MESH_DEVICES, 2)
+        assert placement.mesh_devices(tmp_session) == jax.devices()[:2]
+        tmp_session.set_conf(C.EXEC_MESH_DEVICES, 16)  # conftest gives 8
+        with pytest.raises(ValueError, match="meshDevices=16"):
+            placement.mesh_devices(tmp_session)
 
 
 # ---------------------------------------------------------------------------
@@ -171,43 +189,116 @@ def _agg_q(session, tmp_path):
     )
 
 
-def _mesh_vs_off(session, tmp_path, q, monkeypatch):
+def _served_on(session, df, chip: int):
+    """``df`` collected twice through a scheduler on ``session``'s
+    deployment, homed on ``chip``: the second answer, and each chip's
+    dispatches in the second run (the first, which compiled, also ran on
+    every other chip to leave its executables there)."""
+    from hyperspace_tpu.serve.context import current_query
+    from hyperspace_tpu.serve.scheduler import QueryScheduler
+
+    def fn():
+        current_query().device_home = chip
+        return df.collect()
+
+    sched = QueryScheduler(max_concurrent=1, queue_depth=4)
+    try:
+        first = sched.submit(fn, label="homed").result(timeout=300)
+        before = [REGISTRY.counter(f"device.dispatch.d{k}").value
+                  for k in range(8)]
+        out = sched.submit(fn, label="homed").result(timeout=300)
+        after = [REGISTRY.counter(f"device.dispatch.d{k}").value
+                 for k in range(8)]
+    finally:
+        sched.shutdown(wait=True)
+    assert hex_rows(first.to_pydict()) == hex_rows(out.to_pydict())
+    return out, [a - b for a, b in zip(after, before)]
+
+
+def _homed_vs_one_chip(session, tmp_path, q, chip: int = 3):
     session.enable_hyperspace()
     session.set_conf(C.EXEC_TPU_ENABLED, True)
     try:
-        monkeypatch.setenv("HYPERSPACE_MESH", "0")
         off = hex_rows(q(session, tmp_path).to_pydict())
-        monkeypatch.setenv("HYPERSPACE_MESH", "1")
-        on = hex_rows(q(session, tmp_path).to_pydict())
+        session.set_conf(C.EXEC_MESH_DEVICES, 8)
+        out, dispatched = _served_on(session, q(session, tmp_path), chip)
     finally:
+        session.set_conf(C.EXEC_MESH_DEVICES, 0)
         session.set_conf(C.EXEC_TPU_ENABLED, False)
         session.disable_hyperspace()
-    return off, on
+    return off, hex_rows(out.to_pydict()), dispatched
 
 
 class TestMeshBitIdentity:
-    def test_plain_join_bit_identical(self, skew_env, monkeypatch):
+    def test_plain_join_bit_identical(self, skew_env):
         session, tmp_path = skew_env
-        buckets0 = REGISTRY.counter("mesh.placement.buckets").value
-        off, on = _mesh_vs_off(session, tmp_path, _plain_q, monkeypatch)
+        bands0 = REGISTRY.counter("pipeline.join.bands").value
+        off, on, dispatched = _homed_vs_one_chip(session, tmp_path, _plain_q)
         assert on == off
-        assert REGISTRY.counter("mesh.placement.buckets").value > buckets0
+        # every band wave of the homed query ran on its home chip
+        assert REGISTRY.counter("pipeline.join.bands").value > bands0
+        assert dispatched[3] > 0
+        assert sum(dispatched) == dispatched[3]
 
-    def test_fused_agg_bit_identical_and_balanced(self, skew_env, monkeypatch):
+    def test_fused_agg_bit_identical_and_balanced(self, skew_env):
+        """The same aggregate join homed on each of the 8 chips in turn:
+        the same bits everywhere, and each chip dispatches as much."""
         session, tmp_path = skew_env
-        off, on = _mesh_vs_off(session, tmp_path, _agg_q, monkeypatch)
+        per_chip = []
+        for chip in range(8):
+            off, on, dispatched = _homed_vs_one_chip(
+                session, tmp_path, _agg_q, chip)
+            assert on == off
+            assert sum(dispatched) == dispatched[chip] > 0
+            per_chip.append(dispatched[chip])
+        assert len(set(per_chip)) == 1
+
+    def test_spread_bands_bit_identical_and_balanced(self, skew_env):
+        """``bandPlacement=spread``: the placer spreads the homed query's
+        bands over the chips (the skew fixture is the acceptance shape:
+        >= 4 of 8 chips, the hot bucket does not pin the balance past
+        2x), the bits stay those of one chip, and each wave's kernel runs
+        on the chip it was placed on."""
+        session, tmp_path = skew_env
+        session.set_conf(C.EXEC_BAND_PLACEMENT, "spread")
+        try:
+            usage0 = REGISTRY.counter("rules.usage.MeshBucketedExec").value
+            off, on, dispatched = _homed_vs_one_chip(
+                session, tmp_path, _agg_q)
+        finally:
+            session.set_conf(C.EXEC_BAND_PLACEMENT, "home")
         assert on == off
-        # the skew fixture is the acceptance shape: work must actually
-        # spread (>= 4 of 8 devices) and the hot bucket must not pin the
-        # balance past 2x
         assert REGISTRY.gauge("mesh.placement.devices_used").value >= 4
         assert REGISTRY.gauge("mesh.placement.bytes_imbalance_ratio").value < 2.0
+        assert sum(1 for n in dispatched if n) >= 4
+        assert REGISTRY.counter("rules.usage.MeshBucketedExec").value > usage0
 
-    def test_mesh_emits_usage_event(self, skew_env, monkeypatch):
+    def test_band_placement_conf_is_checked(self, tmp_session):
+        assert tmp_session.conf.exec_band_placement == "home"
+        tmp_session.set_conf(C.EXEC_BAND_PLACEMENT, "everywhere")
+        with pytest.raises(Exception, match="bandPlacement"):
+            tmp_session.conf.exec_band_placement
+
+    def test_mesh_emits_usage_event(self, skew_env):
+        """A direct collect on the deployment takes the mesh join route
+        (``MeshBucketedExec``); a query served on a home chip never does."""
         session, tmp_path = skew_env
-        before = REGISTRY.counter("rules.usage.MeshBucketedExec").value
-        _mesh_vs_off(session, tmp_path, _plain_q, monkeypatch)
-        assert REGISTRY.counter("rules.usage.MeshBucketedExec").value > before
+        session.enable_hyperspace()
+        session.set_conf(C.EXEC_TPU_ENABLED, True)
+        session.set_conf(C.EXEC_MESH_DEVICES, 8)
+        try:
+            before = REGISTRY.counter("rules.usage.MeshBucketedExec").value
+            direct = hex_rows(_plain_q(session, tmp_path).to_pydict())
+            mid = REGISTRY.counter("rules.usage.MeshBucketedExec").value
+            out, _ = _served_on(session, _plain_q(session, tmp_path), 5)
+            after = REGISTRY.counter("rules.usage.MeshBucketedExec").value
+        finally:
+            session.set_conf(C.EXEC_MESH_DEVICES, 0)
+            session.set_conf(C.EXEC_TPU_ENABLED, False)
+            session.disable_hyperspace()
+        assert mid > before
+        assert after == mid
+        assert hex_rows(out.to_pydict()) == direct
 
 
 @pytest.fixture(scope="module")
@@ -225,7 +316,7 @@ def tpch_env(tmp_path_factory):
 
 class TestMeshTPCH:
     @pytest.mark.parametrize("name", ["q3", "q10", "q17"])
-    def test_tpch_bit_identical(self, tpch_env, name, monkeypatch):
+    def test_tpch_bit_identical(self, tpch_env, name):
         from hyperspace_tpu.benchmark import TPCH_QUERIES
 
         session, root = tpch_env
@@ -233,14 +324,16 @@ class TestMeshTPCH:
         session.enable_hyperspace()
         session.set_conf(C.EXEC_TPU_ENABLED, True)
         try:
-            monkeypatch.setenv("HYPERSPACE_MESH", "0")
             off = hex_rows(q(session, root).to_pydict())
-            monkeypatch.setenv("HYPERSPACE_MESH", "1")
-            on = hex_rows(q(session, root).to_pydict())
+            session.set_conf(C.EXEC_MESH_DEVICES, 8)
+            out, dispatched = _served_on(session, q(session, root), 6)
         finally:
+            session.set_conf(C.EXEC_MESH_DEVICES, 0)
             session.set_conf(C.EXEC_TPU_ENABLED, False)
             session.disable_hyperspace()
-        assert on == off, f"{name} diverges under mesh placement"
+        assert hex_rows(out.to_pydict()) == off, (
+            f"{name} diverges on a home chip")
+        assert sum(dispatched) == dispatched[6] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -353,37 +446,50 @@ class TestSchedulerHomeDevice:
             for i, h in enumerate(homes)
         }
 
-    def test_home_none_with_mesh_off(self, monkeypatch):
-        monkeypatch.delenv("HYPERSPACE_MESH", raising=False)
-        sched = self._scheduler()
-        try:
-            assert sched._home_device_locked() is None
-        finally:
-            sched.shutdown(wait=True)
-
-    def test_home_is_least_occupied_ordinal(self, monkeypatch):
-        monkeypatch.setenv("HYPERSPACE_MESH", "1")
-        sched = self._scheduler()
-        try:
-            n = 8
-            sched._active = self._fake_active([0, 0, 1, 3])
-            home = sched._home_device_locked()
-            assert home == 2  # first zero-occupancy ordinal
-            sched._active = self._fake_active(list(range(n)))
-            assert sched._home_device_locked() == 0  # all equal: lowest wins
-        finally:
-            sched._active = {}
-            sched.shutdown(wait=True)
-
-    def test_submitted_query_gets_home(self, tmp_session, tmp_path, monkeypatch):
-        monkeypatch.setenv("HYPERSPACE_MESH", "1")
-        from hyperspace_tpu import serve
-
+    def _df(self, session, tmp_path):
         cio.write_parquet(
             ColumnBatch.from_pydict({"a": [1, 2, 3]}),
             str(tmp_path / "t" / "t.parquet"),
         )
-        df = tmp_session.read.parquet(str(tmp_path / "t")).select("a")
+        return session.read.parquet(str(tmp_path / "t")).select("a")
+
+    def test_home_none_with_mesh_off(self, tmp_session, tmp_path):
+        from hyperspace_tpu import serve
+
+        df = self._df(tmp_session, tmp_path)
+        homes = [REGISTRY.counter(f"serve.home.d{k}").value for k in range(8)]
+        sched = serve.QueryScheduler(max_concurrent=1, queue_depth=4)
+        try:
+            h = sched.submit_query(df, label="no-home")
+            h.result(timeout=60)
+            assert h.ctx.device_home is None
+        finally:
+            sched.shutdown(wait=True)
+        assert [
+            REGISTRY.counter(f"serve.home.d{k}").value for k in range(8)
+        ] == homes
+
+    def test_home_is_least_occupied_ordinal(self):
+        sched = self._scheduler()
+        try:
+            n = 8
+            sched._active = self._fake_active([0, 0, 1, 3])
+            home = sched._home_device_locked(n)
+            assert home == 2  # first zero-occupancy ordinal
+            sched._active = self._fake_active(list(range(n)))
+            assert sched._home_device_locked(n) == 0  # all equal: lowest wins
+            sched._active = self._fake_active([0, 1, 2, 3])
+            assert sched._home_device_locked(4) == 0  # a deployment of 4
+        finally:
+            sched._active = {}
+            sched.shutdown(wait=True)
+
+    def test_submitted_query_gets_home(self, tmp_session, tmp_path):
+        from hyperspace_tpu import serve
+
+        tmp_session.set_conf(C.EXEC_MESH_DEVICES, 8)
+        df = self._df(tmp_session, tmp_path)
+        homes = [REGISTRY.counter(f"serve.home.d{k}").value for k in range(8)]
         sched = serve.QueryScheduler(max_concurrent=1, queue_depth=4)
         try:
             h = sched.submit_query(df, label="home-probe")
@@ -392,3 +498,5 @@ class TestSchedulerHomeDevice:
             assert 0 <= h.ctx.device_home < 8
         finally:
             sched.shutdown(wait=True)
+        k = h.ctx.device_home
+        assert REGISTRY.counter(f"serve.home.d{k}").value == homes[k] + 1

@@ -491,13 +491,17 @@ class TestGroupingCache:
         )
         work = _Work(monkeypatch)
         monkeypatch.setenv(budget, "0")
-        keys = set(cache._d)
+
+        def cached_keys():
+            return {k for lru in cache._chips.values() for k in lru}
+
+        keys = cached_keys()
         answers = []
         for _ in range(2):
             answers.append(work.run(session, table, q))
             assert (work.builds, work.hits) == (1, 0)
         assert not {
-            k[1][0] for k in set(cache._d) - keys
+            k[1][0] for k in cached_keys() - keys
         } & {"grouping", "gids"}
         monkeypatch.delenv(budget)
         assert _bits(answers[0]) == _bits(answers[1])

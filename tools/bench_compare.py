@@ -61,18 +61,6 @@ _SECTION_METRICS = {
         "rows_ingested",
         "queries_under_ingest",
     ),
-    # mesh-sharded scale-out: band waves across the device mesh vs the
-    # single-device reference (bit-identical by construction; timings and
-    # placement balance are the diffable signal)
-    "mesh_scale": (
-        "devices_visible",
-        "mesh_off_ms",
-        "mesh_on_ms",
-        "placed_buckets",
-        "placement_fallbacks",
-        "devices_used",
-        "bytes_imbalance_ratio",
-    ),
     # approximate query tier: exact leg vs sampled legs on the dedicated
     # join fixture, plus the acceptance bar (best sampled speedup >= 5x)
     "approx_tier": (

@@ -1,27 +1,31 @@
 #!/usr/bin/env python
-"""Mesh scale-out smoke: run the TPC-H join bench queries (q3, q10, q17)
-over covering join indexes on a FORCED 8-virtual-device CPU mesh with
-mesh-sharded execution ON (HYPERSPACE_MESH=1, skew-aware bucket→device
-placement) and OFF (=0, everything on device 0) on the same generated
-dataset — including the hot-key skew variant where 30% of lineitem rows
-carry ONE order key — and assert the results are bit-identical. Placement
-must actually engage: >= 4 of the 8 devices used on the skew fixture and a
-predicted-bytes imbalance ratio under 2.0 (the fair-share split gate: a
-naive per-bucket packing of the hot bucket lands near 3x). Every per-device
-memory ledger must drain to zero and the whole smoke runs with
-HYPERSPACE_LOCK_AUDIT=1 — any lock-order violation fails it. Prints one
-JSON line; exit 0 iff every gate holds.
+"""Deployment smoke: the TPC-H join bench queries (q3, q10, q17) over
+covering join indexes, served on a FORCED 8-virtual-device CPU host
+deployed on all eight (``hyperspace.tpu.exec.meshDevices=8``): eight
+clients at once through the ``QueryScheduler``, each query on its own home
+chip, against the same queries collected on one chip — including the
+hot-key skew variant where 30% of lineitem rows carry ONE order key — and
+asserts the results are bit-identical. Every chip must take a query and
+keep its own device-cache entries. Then the same queries, served one at a
+time with ``hyperspace.tpu.exec.bandPlacement=spread``, must stay
+bit-identical while the skew-aware placer spreads their join bands: >= 4
+of the 8 chips used on the skew fixture and a predicted-bytes imbalance
+ratio under 2.0 (the fair-share split gate: a naive per-bucket packing of
+the hot bucket lands near 3x). Every per-chip memory ledger must drain to
+zero; the whole smoke runs with HYPERSPACE_LOCK_AUDIT=1 — any lock-order
+violation fails it. Prints one JSON line; exit 0 iff every gate holds.
 
     timeout 600 env JAX_PLATFORMS=cpu python tools/mesh_smoke.py
 
 Env: SMOKE_ROWS (lineitem rows, default 120000); HYPERSPACE_JOIN_SPLIT_ROWS
 is forced small so the hot bucket's probe chunks rotate through their
-placed device ranges.
+placed chip ranges.
 """
 
 import json
 import os
 import sys
+import threading
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -45,8 +49,10 @@ def main() -> int:
     from hyperspace_tpu import constants as C
     from hyperspace_tpu.benchmark import TPCH_QUERIES, generate_tpch
     from hyperspace_tpu.serve import budget as serve_budget
+    from hyperspace_tpu.serve.scheduler import QueryScheduler
     from hyperspace_tpu.telemetry.metrics import REGISTRY
     from hyperspace_tpu.utils.backend import device_count
+    from hyperspace_tpu.utils.device_cache import DEVICE_CACHE
 
     rows = int(os.environ.get("SMOKE_ROWS", 120_000))
     ws = tempfile.mkdtemp(prefix="hs_mesh_smoke_")
@@ -88,41 +94,6 @@ def main() -> int:
     join_queries = ("q3", "q10", "q17")
     devices_visible = device_count()
 
-    def run(mesh: str) -> dict:
-        os.environ["HYPERSPACE_MESH"] = mesh
-        session.enable_hyperspace()
-        session.set_conf(C.EXEC_TPU_ENABLED, True)
-        out = {}
-        try:
-            for name in join_queries:
-                out[name] = TPCH_QUERIES[name](session, ws).to_pydict()
-        finally:
-            session.set_conf(C.EXEC_TPU_ENABLED, False)
-            session.disable_hyperspace()
-        return out
-
-    off = run("0")
-    buckets0 = REGISTRY.counter("mesh.placement.buckets").value
-    fallbacks0 = REGISTRY.counter("mesh.placement.fallbacks").value
-    usage0 = REGISTRY.counter("rules.usage.MeshBucketedExec").value
-    on = run("1")
-    os.environ.pop("HYPERSPACE_MESH", None)
-    placed_buckets = REGISTRY.counter("mesh.placement.buckets").value - buckets0
-    fallbacks = REGISTRY.counter("mesh.placement.fallbacks").value - fallbacks0
-    usage_events = (
-        REGISTRY.counter("rules.usage.MeshBucketedExec").value - usage0
-    )
-    devices_used = int(REGISTRY.gauge("mesh.placement.devices_used").value)
-    imbalance = REGISTRY.gauge("mesh.placement.bytes_imbalance_ratio").value
-    ledgers = {
-        f"d{o}": acct.held_bytes()
-        for o, acct in serve_budget.device_budgets().items()
-    }
-    ledgers_drained = all(v == 0 for v in ledgers.values()) and all(
-        acct.check_consistency()
-        for acct in serve_budget.device_budgets().values()
-    )
-
     def bits(d):
         return repr(
             {
@@ -131,35 +102,101 @@ def main() -> int:
             }
         )
 
-    mismatches = [name for name in on if bits(on[name]) != bits(off[name])]
+    def collect_all(sess) -> dict:
+        return {name: TPCH_QUERIES[name](sess, ws).to_pydict()
+                for name in join_queries}
+
+    session.enable_hyperspace()
+    session.set_conf(C.EXEC_TPU_ENABLED, True)
+    off = collect_all(session)  # one chip: no query has a home
+
+    deployed = HyperspaceSession(warehouse_dir=ws)
+    deployed.set_conf(C.INDEX_NUM_BUCKETS, 8)
+    deployed.set_conf(C.EXEC_MESH_DEVICES, devices_visible)
+    deployed.set_conf(C.EXEC_TPU_ENABLED, True)
+    deployed.enable_hyperspace()
+    homes0 = [REGISTRY.counter(f"serve.home.d{k}").value
+              for k in range(devices_visible)]
+    gate = threading.Barrier(devices_visible)
+    sched = QueryScheduler(max_concurrent=devices_visible)
+    on: dict = {}
+    homes: list = []
+    try:
+        handles = []
+        for i in range(devices_visible):
+            name = join_queries[i % len(join_queries)]
+            df = TPCH_QUERIES[name](deployed, ws)
+
+            def fn(df=df):
+                gate.wait(timeout=300)  # every client's query in flight
+                return df.collect()
+
+            handles.append((name, sched.submit(fn, label=name)))
+        mismatches = []
+        for name, h in handles:
+            got = h.result(timeout=600).to_pydict()
+            homes.append(h.ctx.device_home)
+            on.setdefault(name, got)
+            if bits(got) != bits(off[name]):
+                mismatches.append(f"{name}@d{h.ctx.device_home}")
+        homed = [REGISTRY.counter(f"serve.home.d{k}").value - homes0[k]
+                 for k in range(devices_visible)]
+        # the skew-aware placer: each query's bands over all the chips
+        deployed.set_conf(C.EXEC_BAND_PLACEMENT, "spread")
+        buckets0 = REGISTRY.counter("mesh.placement.buckets").value
+        usage0 = REGISTRY.counter("rules.usage.MeshBucketedExec").value
+        for name in join_queries:
+            got = sched.submit_query(
+                TPCH_QUERIES[name](deployed, ws), label=name
+            ).result(timeout=600).to_pydict()
+            if bits(got) != bits(off[name]):
+                mismatches.append(f"{name}@spread")
+        placed_buckets = (
+            REGISTRY.counter("mesh.placement.buckets").value - buckets0)
+        usage_events = (
+            REGISTRY.counter("rules.usage.MeshBucketedExec").value - usage0)
+        devices_used = int(REGISTRY.gauge("mesh.placement.devices_used").value)
+        imbalance = REGISTRY.gauge("mesh.placement.bytes_imbalance_ratio").value
+    finally:
+        sched.shutdown()
+    chips_cached = sorted(DEVICE_CACHE.chip_bytes(), key=str)
+    ledgers = {
+        f"d{o}": acct.held_bytes()
+        for o, acct in serve_budget.device_budgets().items()
+    }
+    ledgers_drained = all(v == 0 for v in ledgers.values()) and all(
+        acct.check_consistency()
+        for acct in serve_budget.device_budgets().values()
+    )
     lock_violations = int(
         REGISTRY.counter("staticcheck.lock.violations").value
     )
     result = {
         "rows": rows,
-        "queries": len(on),
+        "queries": len(handles),
         "devices_visible": devices_visible,
         "bit_identical": not mismatches,
         "mismatches": mismatches,
+        "homes": sorted(homes),
+        "homed_per_chip": homed,
+        "chips_cached": len(chips_cached),
+        "cache_consistent": DEVICE_CACHE.check_consistency(),
         "placed_buckets": placed_buckets,
-        "placement_fallbacks": fallbacks,
         "devices_used": devices_used,
         "bytes_imbalance_ratio": round(imbalance, 4),
         "usage_events": usage_events,
         "ledgers_held": ledgers,
         "ledgers_drained": ledgers_drained,
         "lock_violations": lock_violations,
-        "mesh_counters": {
-            k: v
-            for k, v in REGISTRY.snapshot().items()
-            if k.startswith(("mesh.", "serve.device_budget"))
-            and not isinstance(v, dict)
-        },
     }
     print(json.dumps(result))
     ok = (
         not mismatches
         and devices_visible >= 8
+        and sorted(homes) == list(range(devices_visible))
+        and all(n == 1 for n in homed)
+        and len(chips_cached) >= devices_visible
+        and result["cache_consistent"]
         and placed_buckets > 0
         and devices_used >= 4
         and imbalance < 2.0
