@@ -366,13 +366,15 @@ def fused_fingerprint(pallas_route: bool, pred_expr, proj_exprs, agg_list,
     )
 
 
-def grouped_fingerprint(pallas_route: bool, seg_pad: int, pred_expr,
-                        proj_exprs, agg_list, dev_cols: dict) -> tuple:
-    """Grouped segment-reduction kernel (seg_pad is baked into the body)."""
+def grouped_fingerprint(pallas_route: bool, seg_pad: int, runs: bool,
+                        pred_expr, proj_exprs, agg_list, dev_cols: dict) -> tuple:
+    """Grouped segment-reduction kernel (seg_pad is baked into the body;
+    ``runs``: the run route, which reduces over one run of rows a group)."""
     return (
         "grouped",
         pallas_route,
         seg_pad,
+        runs,
         repr(pred_expr),
         tuple((nm, repr(e)) for nm, e in proj_exprs),
         tuple((k, repr(c)) for k, c in agg_list),

@@ -777,6 +777,7 @@ from ..ops.intsum import (  # noqa: E402
     combine_int_chunks as _combine_int_chunks,
     int_chunk_sums as _int_chunk_sums,
 )
+from ..ops.runs import run_reduce, run_starts  # noqa: E402
 
 
 def _combine_chunks_maybe_avg(v, kind: str, counts):
@@ -1306,6 +1307,9 @@ def _build_grouped_pallas_kernel(pred_expr, proj_exprs, agg_list, seg_pad):
     return jax.jit(kernel)  # hslint: HS201 — builder runs via KernelCache.get_or_build
 
 
+_NO_ROW = 2**31 - 1  # the first passing row of a group with none
+
+
 def _first_masked_rows(mask, gids, seg_pad):
     """Per-group index of the first row PASSING the predicate: the host
     tier orders grouped output by first post-filter occurrence, and the
@@ -1314,7 +1318,7 @@ def _first_masked_rows(mask, gids, seg_pad):
     chunks."""
     idx = jnp.arange(gids.shape[0], dtype=jnp.int32)
     return jax.ops.segment_min(
-        jnp.where(mask, idx, jnp.int32(2**31 - 1)), gids, num_segments=seg_pad
+        jnp.where(mask, idx, jnp.int32(_NO_ROW)), gids, num_segments=seg_pad
     )
 
 
@@ -1355,13 +1359,73 @@ def _generic_grouped_compute(pred_expr, proj_exprs, agg_list, seg_pad, cols,
     return counts, first_masked, tuple(out)
 
 
-def _build_grouped_kernel(pred_expr, proj_exprs, agg_list, seg_pad):
+def _run_grouped_compute(pred_expr, proj_exprs, agg_list, cols, gids, mask,
+                         lits, last_rows, seg_pad):
+    """Traced body of the run-route grouped kernel: every group is one run
+    of rows, ``last_rows`` each group's last row (negative for a group with
+    none), so each reduction is a segmented scan read at the runs' last rows
+    (``ops/runs.py``), with no scatter. Failing rows stay in their run and
+    add the identity. Int sums keep their exact chunked scatters."""
+    if pred_expr is not None:
+        mask = mask & compile_expr(pred_expr, cols, lits)
+    starts = run_starts(gids)
+
+    def per_run(v, op, identity):
+        return run_reduce(v, starts, last_rows, op, identity)
+
+    idx = jnp.arange(gids.shape[0], dtype=jnp.int32)
+    first_masked = per_run(jnp.where(mask, idx, _NO_ROW), jnp.minimum, _NO_ROW)
+    counts = per_run(mask.astype(jnp.int32), jnp.add, 0)
+    proj_cols = dict(cols)
+    for name, e in proj_exprs:
+        proj_cols[name] = compile_expr(e, cols, lits)
+    out = []
+    for kind, child in agg_list:
+        if kind == "count":
+            out.append(counts)
+            continue
+        vals = compile_expr(child, proj_cols, lits)
+        if kind in ("sum", "avg"):  # an avg's sum: the host divides
+            vals = jnp.where(mask, vals, 0)
+            if jnp.issubdtype(vals.dtype, jnp.integer):
+                out.append(_int_chunk_sums(vals, gids, seg_pad))
+            else:
+                out.append(per_run(vals, jnp.add, 0))
+        else:  # min, max
+            fill = _extreme(vals.dtype, kind == "min")
+            op = jnp.minimum if kind == "min" else jnp.maximum
+            out.append(per_run(jnp.where(mask, vals, fill), op, fill))
+    return counts, first_masked, tuple(out)
+
+
+def _run_route(pred_expr, agg_list, seg_pad, runs: bool) -> bool:
+    """Whether a grouped kernel takes the run route: its grouping is one run
+    of rows a group (``runs``), and the Pallas histogram, which keeps its
+    precedence, does not take it."""
+    return runs and not (
+        _pallas_route()
+        and _pallas_grouped_shape(pred_expr, agg_list, seg_pad) is not None
+    )
+
+
+def _build_grouped_kernel(pred_expr, proj_exprs, agg_list, seg_pad,
+                          runs: bool = False):
     """Grouped fragment: predicate + per-group segment reductions in one
     jitted pass; rows failing the mask land in the dump segment seg_pad-1.
     On TPU, small-group sum/count fragments stream through the Pallas
-    histogram kernel instead."""
+    histogram kernel instead; otherwise a grouping of one run of rows a
+    group (``runs``) reduces over its runs, its kernel taking each group's
+    last row as a fifth argument."""
     if _pallas_route() and _pallas_grouped_shape(pred_expr, agg_list, seg_pad) is not None:
         return _build_grouped_pallas_kernel(pred_expr, proj_exprs, agg_list, seg_pad)
+    if runs:
+        def run_kernel(cols, gids, mask, lits, last_rows):
+            return _run_grouped_compute(
+                pred_expr, proj_exprs, agg_list, _wrap_wide(cols), gids, mask,
+                lits, last_rows, seg_pad,
+            )
+
+        return jax.jit(run_kernel)  # hslint: HS201 — builder runs via KernelCache.get_or_build
 
     def kernel(cols, gids, mask, lits):
         cols = _wrap_wide(cols)
@@ -1372,22 +1436,28 @@ def _build_grouped_kernel(pred_expr, proj_exprs, agg_list, seg_pad):
     return jax.jit(kernel)  # hslint: HS201 — builder runs via KernelCache.get_or_build
 
 
-def _grouped_kernel(seg_pad, pred_expr, proj_exprs, agg_list, dev_cols):
-    """The cached grouped kernel of the fragment's shape, and its literal
-    arguments."""
+def _grouped_kernel(seg_pad, pred_expr, proj_exprs, agg_list, dev_cols,
+                    runs: bool):
+    """The cached grouped kernel of the fragment's shape, its literal
+    arguments, and whether it takes the run route (``runs``: the grouping
+    is one run of rows a group)."""
     pred, proj, aggs, lits = _slot_for(dev_cols, pred_expr, proj_exprs, agg_list)
-    key = grouped_fingerprint(_pallas_route(), seg_pad, pred, proj, aggs, dev_cols)
+    runs = _run_route(pred, aggs, seg_pad, runs)
+    key = grouped_fingerprint(
+        _pallas_route(), seg_pad, runs, pred, proj, aggs, dev_cols
+    )
     kernel = _KERNEL_CACHE.get_or_build(
-        key, lambda: _build_grouped_kernel(pred, proj, aggs, seg_pad),
+        key, lambda: _build_grouped_kernel(pred, proj, aggs, seg_pad, runs),
         "grouped_agg",
     )
-    return kernel, lits
+    return kernel, lits, runs
 
 
 def _grouped_scatters(kernel, args) -> int:
     """The XLA scatters in a grouped kernel's program: the Pallas route has
     none, the generic one a segment scatter for the first rows, the counts
-    and each float sum, avg, min or max, four for an int sum. Its cache key
+    and each float sum, avg, min or max, four for an int sum; the run route
+    only an int sum's four. Its cache key
     fixes the count, so it is counted once, from the program traced at the
     kernel's first dispatch, and kept on the cached kernel."""
     n = getattr(kernel, "scatters", None)
@@ -1401,12 +1471,16 @@ def _grouped_scatters(kernel, args) -> int:
     return n
 
 
-def _dispatch_grouped(kernel, dev_cols, gids_d, mask, lits):
+def _dispatch_grouped(kernel, dev_cols, gids_d, mask, lits, last_rows=None):
     """One dispatch of a cached grouped kernel and the fetch of its result;
-    adds the kernel's scatters to ``kernel.grouped.scatters``."""
+    adds the kernel's scatters to ``kernel.grouped.scatters``. A run-route
+    kernel takes ``last_rows`` and counts in ``kernel.grouped.runs``."""
     from ..utils.rpc_meter import METER, device_get as metered_get
 
     args = (dev_cols, gids_d, mask, lits)
+    if last_rows is not None:
+        args += (last_rows,)
+        REGISTRY.counter("kernel.grouped.runs").inc()
     METER.record_dispatch()
     with _attr.phase("enqueue"):
         out = kernel(*args)
@@ -1414,21 +1488,35 @@ def _dispatch_grouped(kernel, dev_cols, gids_d, mask, lits):
     return metered_get(out)
 
 
+def _last_rows(ids: np.ndarray, seg_pad: int) -> np.ndarray:
+    """Each group's last row, where every group is one run of rows: a
+    ``seg_pad`` int32 vector, -1 for a group with no rows."""
+    ends = np.append(np.flatnonzero(np.diff(ids)), len(ids) - 1)
+    out = np.full(seg_pad, -1, np.int32)
+    out[ids[ends]] = ends
+    return out
+
+
 def _grouping(key_parts: list[ColumnBatch], key_names: list[str], padded: int):
     """Grouping of the key rows of ``key_parts`` (one batch of the key
     columns per chunk, in row order): ``(num_groups, seg_pad, first_keys,
-    gids_d)``, where ``first_keys`` holds each group's key values at its
-    first occurrence (``num_groups`` rows) and ``gids_d`` the padded int32
-    device group ids, pad rows in the dump segment ``seg_pad - 1``.
+    gids_d, last_rows_d)``, where ``first_keys`` holds each group's key
+    values at its first occurrence (``num_groups`` rows), ``gids_d`` the
+    padded int32 device group ids, pad rows in the dump segment
+    ``seg_pad - 1``, and ``last_rows_d``, where every group is one
+    contiguous run of rows (a covering index's bucketed, sorted key), each
+    group's last row on the device (``_last_rows``), else None.
 
     It derives from the key buffers alone, never from the predicate,
     literals or aggregates, so it caches under every key chunk's data and
     validity buffer in chunk order, as the ``("cat", padded)`` device
-    columns do: the O(groups) host part in HOST_DERIVED_CACHE, the gids in
-    DEVICE_CACHE. A hit needs each buffer to be the same live object, so a
-    refreshed, appended or evicted chunk misses; a hit does no O(rows) host
-    work. A miss builds what the uncached route built: the concatenated
-    keys, ``factorize_group_keys``, the padded fill and its upload."""
+    columns do: the O(groups) host part and whether the groups are runs in
+    HOST_DERIVED_CACHE, the gids and last rows in DEVICE_CACHE. A hit needs
+    each buffer to be the same live object, so a refreshed, appended or
+    evicted chunk misses; a hit does no O(rows) host work. A miss builds
+    what the uncached route built: the concatenated keys,
+    ``factorize_group_keys``, the padded fill and its upload, and the
+    O(rows) test for runs."""
     from .executor import factorize_group_keys
     from ..utils.device_cache import DEVICE_CACHE, HOST_DERIVED_CACHE
 
@@ -1450,28 +1538,38 @@ def _grouping(key_parts: list[ColumnBatch], key_names: list[str], padded: int):
         cols = [keys.column(nm) for nm in key_names]
         ids, num_groups, first_idx = factorize_group_keys(cols)
         group_ids.append(ids)
-        return num_groups, [c.take(first_idx) for c in cols]
+        runs = n > 0 and 1 + int(np.count_nonzero(np.diff(ids))) == num_groups
+        return num_groups, [c.take(first_idx) for c in cols], runs
 
     with trace.span("grouping", rows=n, padded=padded):
-        num_groups, first_keys = HOST_DERIVED_CACHE.get_or_put_multi(
+        num_groups, first_keys, runs = HOST_DERIVED_CACHE.get_or_put_multi(
             srcs, ("grouping", tuple(key_names), padded), factorize
         )
         seg_pad = 1 << max(4, int(np.ceil(np.log2(num_groups + 1))))
 
-        def upload():
+        def ids():
             if not group_ids:  # host part cached, device copy gone
                 factorize()
+            return group_ids[0]
+
+        def upload():
             arr = np.full(padded, seg_pad - 1, dtype=np.int32)
-            arr[:n] = group_ids[0].astype(np.int32)
+            arr[:n] = ids().astype(np.int32)
             return jnp.asarray(arr)
 
         gids_d = DEVICE_CACHE.get_or_put_multi(
             srcs, ("gids", padded, seg_pad), upload
         )
+        last_rows_d = None
+        if runs:
+            last_rows_d = DEVICE_CACHE.get_or_put_multi(
+                srcs, ("last_rows", padded, seg_pad),
+                lambda: jnp.asarray(_last_rows(ids(), seg_pad)),
+            )
     REGISTRY.counter(
         "pipeline.grouping.builds" if group_ids else "pipeline.grouping.hits"
     ).inc()
-    return num_groups, seg_pad, first_keys, gids_d
+    return num_groups, seg_pad, first_keys, gids_d, last_rows_d
 
 
 def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[ColumnBatch]:
@@ -1491,7 +1589,7 @@ def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[Colu
         if dev_cols is None:
             sp.set_attr("declined", "nullable_or_out_of_range")
             return None
-        num_groups, seg_pad, first_keys, gids_d = _grouping(
+        num_groups, seg_pad, first_keys, gids_d, last_rows_d = _grouping(
             [batch.select(key_names)], key_names, padded
         )
         sp.set_attr("groups", num_groups)
@@ -1502,11 +1600,12 @@ def _execute_grouped(frag: _Fragment, batch: ColumnBatch, plan) -> Optional[Colu
             (X.expr_output_name(e), e) for e in _device_projections(frag)
         )
         agg_list, names = _agg_list_names(frag)
-        kernel, lits = _grouped_kernel(
-            seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
+        kernel, lits, runs = _grouped_kernel(
+            seg_pad, pred_expr, proj_exprs, agg_list, dev_cols,
+            last_rows_d is not None,
         )
         counts_dev, first_masked, results = _dispatch_grouped(
-            kernel, dev_cols, gids_d, mask, lits
+            kernel, dev_cols, gids_d, mask, lits, last_rows_d if runs else None
         )
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]
@@ -1889,7 +1988,7 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
         return _assemble_global_output(plan, matched, scalar_values, agg_list, names)
 
     # grouped: keys were collected host-side per chunk (they never ship)
-    num_groups, seg_pad, first_keys, gids_d = _grouping(
+    num_groups, seg_pad, first_keys, gids_d, last_rows_d = _grouping(
         key_parts, key_names, padded
     )
     proj_exprs = tuple(
@@ -1898,11 +1997,12 @@ def _stream_concat(frag, plan, chunks, n_total) -> Optional[ColumnBatch]:
     with trace.span(
         "kernel:grouped_agg", rows=n_seen, padded=padded, groups=num_groups
     ):
-        kernel, lits = _grouped_kernel(
-            seg_pad, pred_expr, proj_exprs, agg_list, dev_cols
+        kernel, lits, runs = _grouped_kernel(
+            seg_pad, pred_expr, proj_exprs, agg_list, dev_cols,
+            last_rows_d is not None,
         )
         counts_dev, first_masked, results = _dispatch_grouped(
-            kernel, dev_cols, gids_d, mask, lits
+            kernel, dev_cols, gids_d, mask, lits, last_rows_d if runs else None
         )
     counts_full = np.asarray(counts_dev)
     counts = counts_full[:num_groups]

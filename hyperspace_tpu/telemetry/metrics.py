@@ -19,6 +19,7 @@ Canonical metric names (see docs/observability.md for the full catalog):
     cache.kernel_join.{hits,misses,evictions}      bucketed-join kernel cache
     kernel.retrace                                 kernel builds (cache misses)
     kernel.grouped.scatters                        XLA scatters of grouped dispatches
+    kernel.grouped.runs                            run-route grouped dispatches
     pipeline.{chunks,queries,aborted,declined}     streaming executor
     pipeline.query_ms                              streamed-query latencies
     pipeline.join.{pairs,bands,buckets,splits}     streamed bucketed join

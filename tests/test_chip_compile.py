@@ -159,6 +159,28 @@ def test_q1_grouped_kernel_compiles_without_scatter(topo, one_chip, mosaic):
     assert " scatter(" not in text
 
 
+def test_q17_grouped_kernel_compiles_without_scatter(topo, one_chip, mosaic):
+    """Q17's per-part average over ``li_partkey`` at SF1: 2^23 rows in 2^18
+    segments, each part one run of the bucketed, sorted index. The run
+    route's segmented scans and last-row gathers give the counts, the first
+    rows and the sum, so the compiled program holds no XLA scatter."""
+    from hyperspace_tpu.plan.expr import col
+    from hyperspace_tpu.plan.tpu_exec import _build_grouped_kernel, slot_literals
+
+    pred, proj, aggs, lits = slot_literals(
+        None, (), [("avg", col("l_quantity"))], {"l_quantity": "f"}
+    )
+    seg_pad, rows = 1 << 18, 1 << 23
+    kernel = _build_grouped_kernel(pred, proj, aggs, seg_pad, runs=True)
+    text = kernel.lower(
+        {"l_quantity": _sds(one_chip, jnp.float32, rows)},
+        _sds(one_chip, jnp.int32, rows), _sds(one_chip, jnp.bool_, rows),
+        _lits(lits), _sds(one_chip, jnp.int32, seg_pad),
+    ).compile().as_text()
+    assert " scatter(" not in text
+    assert " gather(" in text  # each group read at its run's last row
+
+
 # the order-detail lookup at SF10: one bucket of l_orderkey padded to 2^23
 _SELECT_ROWS = 1 << 23
 

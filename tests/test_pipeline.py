@@ -502,7 +502,7 @@ class TestGroupingCache:
             assert (work.builds, work.hits) == (1, 0)
         assert not {
             k[1][0] for k in cached_keys() - keys
-        } & {"grouping", "gids"}
+        } & {"grouping", "gids", "last_rows"}
         monkeypatch.delenv(budget)
         assert _bits(answers[0]) == _bits(answers[1])
         assert _bits(answers[1]) == _bits(work.run(session, table, q))
@@ -533,7 +533,8 @@ class TestGroupingCache:
             want_ids, want_n, want_first = factorize_group_keys(
                 [ColumnBatch.concat(parts).column("k")]
             )
-            num_groups, seg_pad, first_keys, gids_d = got
+            num_groups, seg_pad, first_keys, gids_d, last_rows_d = got
+            assert last_rows_d is None  # group 0 (key 3) is two runs
             assert num_groups == want_n
             assert seg_pad == 16
             want_keys = ColumnBatch.concat(parts).column("k").take(want_first)
